@@ -32,6 +32,8 @@ def main(argv=None) -> int:
                     help="wrap the sweep in jax.profiler.trace "
                          "(requires --trace-dir)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import init_compile_cache
+    init_compile_cache()
 
     chosen = (args.only.split(",") if args.only else SUITES)
     if args.trace_dir:

@@ -8,7 +8,7 @@ paper-faithful ``rounds`` (-> reference) sequential exchanges, the
 collectives).
 
 Raw-speed rows (DESIGN.md §12): ``tthf_fused_interval`` times the flat
-(R, P) carrier step with donated buffers, and the ``trainer_straight``
+(R, rows, 128) carrier step with donated buffers, and the ``trainer_straight``
 vs ``trainer_fast`` pair times the full ScaleTrainer loop with every
 speed knob off vs on (donation + fused interval + prefetch) — the
 trajectories are bitwise identical, only the clock moves.
@@ -118,7 +118,7 @@ def run(scale: str = "ci", seed: int = 0) -> list[Row]:
         rows.append(Row(f"scale_sync/{name}", us,
                         f"loss0={losses[0]:.4f};lossN={losses[-1]:.4f}"))
 
-    # the §12 fast path: flat (R, P) carrier + donated param buffer
+    # the §12 fast path: flat (R, rows, 128) carrier + donated param buffer
     # (bitwise the tthf_fused trajectory — asserted in claims below)
     scale_cfg = TTHFScaleConfig(replicas=R, cluster_size=s, tau=tau,
                                 consensus_every=2, gamma_d2d=2, lr=0.05,

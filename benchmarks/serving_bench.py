@@ -307,12 +307,21 @@ def _run_sharded_child(scale: str, seed: int):
 
 def run_sharded(scale: str = "ci", seed: int = 0):
     """Parent entry for ``--sharded``: fork a child with the forced
-    host device count, parse its JSON, append rows to BENCH_serving."""
+    host device count, parse its JSON, append rows to BENCH_serving.
+
+    Forced host devices are CPU devices: on a TPU host the child would
+    time the CPU under the serving benchmark's name, so this refuses to
+    run there (the sharded check of record on the chip is
+    ``chip_smoke.py --chips 4``)."""
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "serving_bench --sharded simulates devices on the host CPU; "
+            "on a TPU host run `python chip_smoke.py --chips 4` instead")
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count="
                         + str(SHARDED_NDEV))
-    env.setdefault("JAX_PLATFORMS", "cpu")
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.serving_bench",
          "--child-sharded", "--scale", scale, "--seed", str(seed)],

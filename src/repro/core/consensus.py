@@ -27,6 +27,7 @@ from repro.core import mixing
 def mix_once(z: jax.Array, V: jax.Array) -> jax.Array:
     """One consensus round. z: (N, s, M); V: (N, s, s)."""
     return jnp.einsum("nij,njm->nim", V, z,
+                      precision=jax.lax.Precision.HIGHEST,
                       preferred_element_type=z.dtype)
 
 

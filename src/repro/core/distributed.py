@@ -46,7 +46,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import TopologyConfig
-from repro.core.mixing import MixingPlan, build_mixing_plan
+from repro.core.mixing import MixingPlan, build_mixing_plan, mix_blocks
 from repro.core.topology import Network, build_network
 from repro.dist.sharding import drop_hint_axes
 from repro.hierarchy.aggregate import apply_device_matrix_pytree
@@ -100,23 +100,39 @@ def consensus_event(params, net: Network, gamma, mode: str = "fused"):
     return plan.apply_pytree(params)
 
 
+def replica_blocks(leaf: jax.Array, num_clusters: int) -> jax.Array:
+    """(R, ...) -> (N, s, ...): splits only the leading replica axis,
+    so no parameter is ever viewed as one long row (see
+    :mod:`repro.core.mixing` for why that matters on a TPU)."""
+    R = leaf.shape[0]
+    return leaf.reshape((num_clusters, R // num_clusters)
+                        + (leaf.shape[1:] or (1,)))
+
+
+def _broadcast_replicas(w_hat: jax.Array, leaf: jax.Array) -> jax.Array:
+    return jnp.broadcast_to(w_hat[None], (leaf.shape[0],) + w_hat.shape
+                            ).reshape(leaf.shape)
+
+
 def sampled_aggregation(params, net: Network, picks: jax.Array):
     """eq. (7): w_hat = sum_c varrho_c w_{n_c}; broadcast to all replicas.
 
     The static-topology path. Under netsim dynamics the aggregation is
     :func:`weighted_aggregation` instead — availability-renormalized
-    per-device weights rather than one pick per cluster."""
+    per-device weights rather than one pick per cluster. Leaves are
+    any (R, ...) arrays, the fused interval's flat carrier included."""
     varrho = jnp.asarray(net.varrho, jnp.float32)
-    N, s = net.num_clusters, net.cluster_size
+    picks = picks.astype(jnp.int32)
 
     def one(leaf):
-        R = leaf.shape[0]
-        z = leaf.reshape(N, s, -1)
-        chosen = jnp.take_along_axis(
-            z, picks[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-        w_hat = jnp.einsum("c,cm->m", varrho.astype(leaf.dtype), chosen)
-        return jnp.broadcast_to(w_hat[None], (R,) + w_hat.shape
-                                ).reshape(leaf.shape)
+        z = replica_blocks(leaf, net.num_clusters)
+        v = varrho.astype(leaf.dtype)
+        w_hat = v[0] * jax.lax.dynamic_index_in_dim(z[0], picks[0],
+                                                    keepdims=False)
+        for c in range(1, z.shape[0]):
+            w_hat = w_hat + v[c] * jax.lax.dynamic_index_in_dim(
+                z[c], picks[c], keepdims=False)
+        return _broadcast_replicas(w_hat, leaf)
 
     return jax.tree.map(one, params)
 
@@ -145,14 +161,11 @@ def weighted_aggregation(params, net: Network, weights: jax.Array):
 def full_aggregation(params, net: Network):
     """Star/FedAvg baseline: full-participation weighted mean."""
     varrho = jnp.asarray(net.varrho, jnp.float32)
-    N, s = net.num_clusters, net.cluster_size
 
     def one(leaf):
-        R = leaf.shape[0]
-        z = leaf.reshape(N, s, -1).mean(axis=1)
-        w_hat = jnp.einsum("c,cm->m", varrho.astype(leaf.dtype), z)
-        return jnp.broadcast_to(w_hat[None], (R,) + w_hat.shape
-                                ).reshape(leaf.shape)
+        z = replica_blocks(leaf, net.num_clusters).mean(axis=1)
+        v = varrho.astype(leaf.dtype).reshape((-1,) + (1,) * (z.ndim - 1))
+        return _broadcast_replicas((v * z).sum(axis=0), leaf)
 
     return jax.tree.map(one, params)
 
@@ -161,33 +174,40 @@ def full_aggregation(params, net: Network):
 # the flattened replica buffer of the fused interval (DESIGN.md §12)
 # ---------------------------------------------------------------------------
 
-LANE = 128      # TPU lane width — the flat buffer is lane-padded once
+LANE = 128      # TPU lane width: the carrier's minor dim
+ROW_ALIGN = 128     # carrier rows pad to this, so a model axis divides
 
 
 @dataclass(frozen=True)
 class FlatParamSpec:
-    """Layout of the lane-padded flat ``(R, P)`` replica buffer.
+    """Layout of the flat ``(R, rows, LANE)`` replica buffer.
 
     The fused interval (``make_tthf_train_step(fused_interval=True)``)
-    carries every replica's parameters as ONE ``(R, P)`` array: leaves
-    packed back-to-back along P (per-replica layout — shapes here
-    exclude the leading replica axis), P padded up to a lane multiple
-    exactly once at build time. SGD updates and consensus mixing then
+    carries every replica's parameters as ONE ``(R, rows, LANE)`` array:
+    each leaf fills whole LANE-wide rows (zero-padded to a LANE multiple;
+    per-replica layout — shapes here exclude the leading replica axis),
+    leaves packed back-to-back along the rows, and the row count padded
+    to a ``ROW_ALIGN`` multiple. SGD updates and consensus mixing then
     run as single whole-buffer ops instead of per-leaf launches;
-    :meth:`unflatten` is only needed at aggregation/eval boundaries and
-    is a pure view (slice + reshape, no copy).
+    :meth:`unflatten` is only needed at aggregation/eval boundaries.
+
+    Why rows and not one ``(R, P)`` row per replica: on a TPU the
+    replica axis of an ``(R, P)`` array sits in the tiled second-minor
+    dim, so every ``(N, s, P)`` cluster view is a relayout whose
+    compile time grows with P — far past any budget at 368M parameters.
+    Leading-axis splits of ``(R, rows, LANE)`` are free.
 
     Mixing/aggregation correctness under padding: every interval op is
-    per-column linear over the replica axis, so the zero pad columns
-    stay zero and real columns are untouched by the packing.
+    elementwise linear over the replica axis, so zero pad entries stay
+    zero and real entries are untouched by the packing.
     """
     treedef: Any
     shapes: tuple[tuple[int, ...], ...]
-    offsets: tuple[int, ...]
-    sizes: tuple[int, ...]
+    offsets: tuple[int, ...]    # first row of each leaf
+    sizes: tuple[int, ...]      # elements of each leaf
     dtype: Any
-    total: int          # packed length (sum of leaf sizes)
-    padded: int         # lane-padded P
+    total: int          # real elements (sum of leaf sizes)
+    rows: int           # carrier rows per replica
 
     @classmethod
     def for_tree(cls, tree) -> "FlatParamSpec":
@@ -200,88 +220,67 @@ class FlatParamSpec:
             f"flat buffer needs a uniform param dtype, got {dtypes}"
         shapes = tuple(tuple(int(d) for d in l.shape) for l in leaves)
         sizes = tuple(int(np.prod(s, dtype=np.int64)) for s in shapes)
+        leaf_rows = [-(-n // LANE) for n in sizes]
         offsets = tuple(int(o) for o in
-                        np.concatenate([[0], np.cumsum(sizes)[:-1]]))
-        total = int(sum(sizes))
-        padded = -(-total // LANE) * LANE
+                        np.concatenate([[0], np.cumsum(leaf_rows)[:-1]]))
+        rows = -(-sum(leaf_rows) // ROW_ALIGN) * ROW_ALIGN
         return cls(treedef=treedef, shapes=shapes, offsets=offsets,
-                   sizes=sizes, dtype=dtypes.pop(), total=total,
-                   padded=padded)
+                   sizes=sizes, dtype=dtypes.pop(), total=int(sum(sizes)),
+                   rows=rows)
 
     @classmethod
     def for_model(cls, model: ModelApi, dtype=jnp.float32) -> "FlatParamSpec":
         p_abs, _ = model.abstract_params(dtype=dtype)
         return cls.for_tree(p_abs)
 
+    @property
+    def padded(self) -> int:
+        """Carrier elements per replica (``rows * LANE``)."""
+        return self.rows * LANE
+
     # -- conversions ----------------------------------------------------
     def flatten(self, tree) -> jax.Array:
-        """Replicated pytree (leaves (R, *shape)) -> flat (R, P).
+        """Replicated pytree (leaves (R, *shape)) -> flat (R, rows, LANE).
 
         Leaves are cast to the spec dtype (the reference microstep's
         ``g.astype(w.dtype)`` contract for gradient trees)."""
         leaves = jax.tree.flatten(tree)[0]
         R = leaves[0].shape[0]
-        flat = jnp.concatenate(
-            [l.astype(self.dtype).reshape(R, -1) for l in leaves], axis=1)
-        if self.padded != self.total:
-            flat = jnp.pad(flat, ((0, 0), (0, self.padded - self.total)))
-        return flat
+        parts = []
+        for l, n in zip(leaves, self.sizes):
+            l = l.astype(self.dtype)
+            r = -(-n // LANE)
+            if n == r * LANE:
+                parts.append(l.reshape(R, r, LANE))
+            else:
+                parts.append(jnp.pad(l.reshape(R, n),
+                                     ((0, 0), (0, r * LANE - n))
+                                     ).reshape(R, r, LANE))
+        used = sum(p.shape[1] for p in parts)
+        if used != self.rows:
+            parts.append(jnp.zeros((R, self.rows - used, LANE), self.dtype))
+        return jnp.concatenate(parts, axis=1)
+
+    def _leaves(self, flat: jax.Array, lead: tuple):
+        out = []
+        for o, n, s in zip(self.offsets, self.sizes, self.shapes):
+            r = -(-n // LANE)
+            x = flat[..., o:o + r, :]
+            if n != r * LANE:
+                x = x.reshape(lead + (r * LANE,))[..., :n]
+            out.append(x.reshape(lead + s))
+        return jax.tree.unflatten(self.treedef, out)
 
     def unflatten(self, flat: jax.Array):
-        """Flat (R, P) -> replicated pytree (leaves (R, *shape))."""
-        R = flat.shape[0]
-        leaves = [flat[:, o:o + n].reshape((R,) + s)
-                  for o, n, s in zip(self.offsets, self.sizes, self.shapes)]
-        return jax.tree.unflatten(self.treedef, leaves)
+        """Flat (R, rows, LANE) -> replicated pytree (leaves (R, *shape))."""
+        return self._leaves(flat, flat.shape[:1])
 
     def unflatten_one(self, row: jax.Array):
-        """One replica's row (P,) -> per-replica pytree (leaves shape)."""
-        leaves = [row[o:o + n].reshape(s)
-                  for o, n, s in zip(self.offsets, self.sizes, self.shapes)]
-        return jax.tree.unflatten(self.treedef, leaves)
+        """One replica's (rows, LANE) -> per-replica pytree."""
+        return self._leaves(row, ())
 
     def abstract(self, replicas: int) -> jax.ShapeDtypeStruct:
-        return jax.ShapeDtypeStruct((replicas, self.padded), self.dtype)
-
-
-# flat (R, P) counterparts of the pytree aggregations above — the same
-# per-column linear maps, so fused-interval trajectories are bitwise the
-# reference path's (asserted in tests/test_fused_interval.py)
-
-def sampled_aggregation_flat(flat: jax.Array, net: Network,
-                             picks: jax.Array) -> jax.Array:
-    varrho = jnp.asarray(net.varrho, jnp.float32)
-    N, s = net.num_clusters, net.cluster_size
-    R, P = flat.shape
-    z = flat.reshape(N, s, P)
-    chosen = jnp.take_along_axis(
-        z, picks[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    w_hat = jnp.einsum("c,cm->m", varrho.astype(flat.dtype), chosen)
-    return jnp.broadcast_to(w_hat[None], (R, P))
-
-
-def weighted_aggregation_flat(flat: jax.Array, net: Network,
-                              weights: jax.Array) -> jax.Array:
-    N, s = net.num_clusters, net.cluster_size
-    R, P = flat.shape
-    g = jnp.einsum("cs,csm->m", weights.astype(flat.dtype),
-                   flat.reshape(N, s, P))
-    alive = weights.sum() > 0
-    return jnp.where(alive, jnp.broadcast_to(g[None], (R, P)), flat)
-
-
-def full_aggregation_flat(flat: jax.Array, net: Network) -> jax.Array:
-    varrho = jnp.asarray(net.varrho, jnp.float32)
-    N, s = net.num_clusters, net.cluster_size
-    R, P = flat.shape
-    z = flat.reshape(N, s, P).mean(axis=1)
-    w_hat = jnp.einsum("c,cm->m", varrho.astype(flat.dtype), z)
-    return jnp.broadcast_to(w_hat[None], (R, P))
-
-
-def apply_device_matrix_flat(flat: jax.Array, M: jax.Array) -> jax.Array:
-    return jnp.einsum("ij,jm->im", M.astype(flat.dtype), flat,
-                      preferred_element_type=flat.dtype)
+        return jax.ShapeDtypeStruct((replicas, self.rows, LANE), self.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -299,7 +298,7 @@ def make_tthf_train_step(model: ModelApi, scale: TTHFScaleConfig, *,
     params_R: every leaf has leading replica axis R.
 
     ``fused_interval=True`` builds the flat-buffer variant (DESIGN.md
-    §12): the step carries parameters as ONE lane-padded ``(R, P)``
+    §12): the step carries parameters as ONE ``(R, rows, LANE)``
     array (:class:`FlatParamSpec`; the returned ``step`` exposes it as
     ``step.spec``), SGD updates and consensus mixing run as whole-buffer
     ops instead of per-leaf launches, and each consensus block's last
@@ -451,16 +450,17 @@ def _make_fused_interval_step(model: ModelApi, scale: TTHFScaleConfig, *,
 
     Arithmetic mirrors the reference interval exactly: grads come from
     the identical unflattened tree, the SGD update is the same
-    elementwise expression on the concatenated buffer, and every
-    mixing/aggregation einsum is per-column identical to its per-leaf
-    counterpart — so fused and reference trajectories are bitwise equal
-    in f32 (asserted in tests and in ``benchmarks/scale_sync.py``).
+    elementwise expression on the concatenated buffer, and mixing and
+    aggregation run the very functions of the per-leaf path on the
+    carrier, elementwise identical — so fused and reference trajectories
+    are bitwise equal in f32 (asserted in tests and in
+    ``benchmarks/scale_sync.py``).
     """
     spec = FlatParamSpec.for_model(model, dtype=param_dtype)
-    N, s = net.num_clusters, net.cluster_size
+    N = net.num_clusters
     if fused_kernel is None:
         from repro.kernels.runtime import default_interpret
-        # auto: Mosaic kernel on real TPUs; off-TPU the XLA einsum below
+        # auto: Mosaic kernel on real TPUs; off-TPU the XLA mixing below
         # IS the fused pass after fusion, and skipping pallas interpret
         # overhead keeps the CPU path fast
         fused_kernel = not default_interpret()
@@ -469,7 +469,7 @@ def _make_fused_interval_step(model: ModelApi, scale: TTHFScaleConfig, *,
             fused_consensus_sgd as _fused_kernel_fn)
 
     def grad_flat(flat, mb):
-        """Mean loss + flat (R, P) grads; pad columns stay zero."""
+        """Mean loss + flat (R, rows, LANE) grads; pads stay zero."""
         losses, grads = jax.vmap(
             lambda p, m: jax.value_and_grad(replica_loss)(p, m)
         )(spec.unflatten(flat), mb)
@@ -504,7 +504,7 @@ def _make_fused_interval_step(model: ModelApi, scale: TTHFScaleConfig, *,
                 * g.astype(w.dtype), params, grads)
             return spec.flatten(params), jnp.mean(losses)
 
-        # W available => the block-end collapses to ONE matrix product
+        # W available => the block-end collapses to ONE mixing pass
         W0 = plan.fused_w(mix_refresh) if mix_active else None
         kernel_end = fused_kernel and mix_active and W0 is not None
 
@@ -522,7 +522,7 @@ def _make_fused_interval_step(model: ModelApi, scale: TTHFScaleConfig, *,
                 flat, head_losses = jax.lax.scan(sgd, flat, head)
                 g, last_loss = grad_flat(flat, last)
                 flat = _fused_kernel_fn(
-                    flat.reshape(N, s, -1), g.reshape(N, s, -1),
+                    replica_blocks(flat, N), replica_blocks(g, N),
                     W0, lr).reshape(flat.shape)
                 losses = jnp.concatenate([head_losses, last_loss[None]])
                 return flat, jnp.mean(losses)
@@ -534,30 +534,24 @@ def _make_fused_interval_step(model: ModelApi, scale: TTHFScaleConfig, *,
             # op instead of per-leaf launches
             flat, losses = jax.lax.scan(sgd, flat, block_batch)
             if mix_active:
-                if W0 is not None:
-                    flat = jnp.einsum(
-                        "nij,njm->nim", W0.astype(flat.dtype),
-                        flat.reshape(N, s, -1),
-                        preferred_element_type=flat.dtype
-                    ).reshape(flat.shape)
-                else:
-                    # non-fused_power backend: exact per-event
-                    # semantics on the flat buffer
-                    flat = plan.apply(flat.reshape(N, s, -1),
-                                      refresh=mix_refresh
-                                      ).reshape(flat.shape)
+                z = replica_blocks(flat, N)
+                # a non-fused_power backend keeps its exact per-event
+                # semantics on the flat buffer
+                z = (mix_blocks(W0, z) if W0 is not None
+                     else plan.apply(z, refresh=mix_refresh))
+                flat = z.reshape(flat.shape)
             return flat, jnp.mean(losses)
 
         flat, block_losses = jax.lax.scan(block, flat, batch_b)
         if sync == "tthf":
             if agg_kind == "picks":
-                flat = sampled_aggregation_flat(flat, net, agg)
+                flat = sampled_aggregation(flat, net, agg)
             elif agg_kind == "weights":
-                flat = weighted_aggregation_flat(flat, net, agg)
+                flat = weighted_aggregation(flat, net, agg)
             else:
-                flat = apply_device_matrix_flat(flat, agg)
+                flat = apply_device_matrix_pytree(flat, agg)
         elif sync == "star":
-            flat = full_aggregation_flat(flat, net)
+            flat = full_aggregation(flat, net)
         return flat, jnp.mean(block_losses)
 
     if refreshable:

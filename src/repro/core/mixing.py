@@ -12,7 +12,7 @@ masked_loop    jittable bounded ``fori_loop`` with per-cluster masking —
                works with *traced* gamma (Remark-1 adaptive rounds)
 pallas         fused Gamma-round Pallas TPU kernel
                (``repro.kernels.consensus_mix``; interpret mode on CPU)
-fused_power    ONE einsum against the stacked matrix powers
+fused_power    ONE mixing pass against the stacked matrix powers
                ``W_c = V_c^{Gamma_c}`` — the scale-mode collective
                collapse; W is precomputed at plan-build time
 =============  ============================================================
@@ -31,6 +31,17 @@ and the topology are known at step-build time — the plan precomputes
 ``W`` exactly once (numpy, exact integer powers) instead of re-deriving
 it per call, and pins the dispatch statically so the jitted step closes
 over constants only.
+
+Parameters are mixed by :func:`mix_blocks`, an explicit sum over the
+cluster members that keeps each leaf's trailing dims, never by an
+einsum over an ``(N, s, M)`` view. Two TPU facts force this. A
+DEFAULT-precision f32 matmul is one bf16 MXU pass there, which would
+round every replica's parameters to bf16 at each consensus event and
+erase SGD updates smaller than a bf16 ulp. And an ``(N, s, M)`` view
+of a parameter with M in the millions is a relayout whose compile time
+grows with M: minutes per leaf at mamba2-370m widths. The remaining
+einsums over parameters (small matrix products, the ``pallas`` backend
+aside) run at ``Precision.HIGHEST``; off-TPU that flag changes nothing.
 """
 from __future__ import annotations
 
@@ -107,14 +118,28 @@ def matrix_powers(V: jax.Array, gamma: jax.Array) -> jax.Array:
 
     def body(r, W):
         nxt = jnp.einsum("nij,njk->nik", Vf, W,
+                         precision=jax.lax.Precision.HIGHEST,
                          preferred_element_type=jnp.float32)
         return jnp.where((r < gamma)[:, None, None], nxt, W)
 
     return jax.lax.fori_loop(0, jnp.max(gamma), body, eye)
 
 
+def mix_blocks(W: jax.Array, z: jax.Array) -> jax.Array:
+    """``z_c <- W_c z_c`` for every cluster: W (N, s, s), z (N, s, ...).
+
+    A sum over the s members written out term by term (see the module
+    docstring): exact f32 on every backend, and z keeps its trailing
+    dims, so no parameter is ever viewed as one long row."""
+    Wb = W.astype(z.dtype).reshape(W.shape + (1,) * (z.ndim - 2))
+    out = Wb[:, :, 0] * z[:, :1]
+    for j in range(1, z.shape[1]):
+        out = out + Wb[:, :, j] * z[:, j:j + 1]
+    return out
+
+
 # ---------------------------------------------------------------------------
-# backend implementations — all (N, s, M) x (N, s, s) x (N,) -> (N, s, M)
+# backend implementations — all (N, s, ...) x (N, s, s) x (N,) -> (N, s, ...)
 # ---------------------------------------------------------------------------
 
 def _mix_reference(z, V, gamma):
@@ -130,12 +155,10 @@ def _mix_reference(z, V, gamma):
 
 
 def _mix_masked_loop(z, V, gamma):
-    Vz = V.astype(z.dtype)
+    keep = (slice(None),) + (None,) * (z.ndim - 1)
 
     def body(r, zz):
-        mixed = jnp.einsum("nij,njm->nim", Vz, zz,
-                           preferred_element_type=zz.dtype)
-        return jnp.where((r < gamma)[:, None, None], mixed, zz)
+        return jnp.where((r < gamma)[keep], mix_blocks(V, zz), zz)
 
     return jax.lax.fori_loop(0, jnp.max(gamma), body, z)
 
@@ -143,15 +166,15 @@ def _mix_masked_loop(z, V, gamma):
 def _mix_pallas(z, V, gamma, blk_m=512):
     from repro.kernels import consensus_mix as _cm
     from repro.kernels import ops as kops
-    return _cm.consensus_mix(z, V, gamma, blk_m=blk_m,
-                             interpret=kops.INTERPRET)
+    N, s = z.shape[:2]
+    return _cm.consensus_mix(z.reshape(N, s, -1), V, gamma, blk_m=blk_m,
+                             interpret=kops.INTERPRET).reshape(z.shape)
 
 
 def _mix_fused_power(z, V, gamma, W=None):
     if W is None:
         W = matrix_powers(V, gamma)
-    return jnp.einsum("nij,njm->nim", W.astype(z.dtype), z,
-                      preferred_element_type=z.dtype)
+    return mix_blocks(W, z)
 
 
 def mix(z: jax.Array, V: jax.Array, gamma: Any, *,
@@ -160,7 +183,7 @@ def mix(z: jax.Array, V: jax.Array, gamma: Any, *,
         blk_m: int = 512) -> jax.Array:
     """Apply per-cluster consensus ``z_c <- V_c^{gamma_c} z_c``.
 
-    z: (N, s, M); V: (N, s, s); gamma: scalar or (N,) int32.
+    z: (N, s, ...); V: (N, s, s); gamma: scalar or (N,) int32.
     ``W`` (fused_power only): precomputed stacked powers; derived
     in-graph when omitted.
     ``device_mask`` (N, s): drop devices via
@@ -193,7 +216,7 @@ def mix_pytree(params, V: jax.Array, gamma: Any, num_clusters: int, *,
     """Consensus over a pytree whose leaves have leading axis I = N*s.
 
     Mixing is linear and elementwise across parameters, so each leaf is
-    reshaped (I, ...) -> (N, s, M) and mixed independently.
+    viewed (I, ...) -> (N, s, ...) and mixed independently.
     ``device_mask``: see :func:`mix` — applied once, outside the
     per-leaf loop.
     """
@@ -206,9 +229,8 @@ def mix_pytree(params, V: jax.Array, gamma: Any, num_clusters: int, *,
     def one(leaf):
         I = leaf.shape[0]
         s = I // num_clusters
-        flat = leaf.reshape(num_clusters, s, -1)
-        mixed = mix(flat, V.astype(flat.dtype), gamma,
-                    backend=backend, W=W)
+        z = leaf.reshape((num_clusters, s) + (leaf.shape[1:] or (1,)))
+        mixed = mix(z, V.astype(z.dtype), gamma, backend=backend, W=W)
         return mixed.reshape(leaf.shape).astype(leaf.dtype)
 
     return jax.tree.map(one, params)
@@ -343,4 +365,5 @@ def refresh_matrices(plan: MixingPlan, V: Any,
 
 __all__ = ["BACKENDS", "MixingPlan", "build_mixing_plan",
            "canonical_backend", "masked_consensus_matrix",
-           "matrix_powers", "mix", "mix_pytree", "refresh_matrices"]
+           "matrix_powers", "mix", "mix_blocks", "mix_pytree",
+           "refresh_matrices"]

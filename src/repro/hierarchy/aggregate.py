@@ -33,9 +33,9 @@ from typing import Optional
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from repro.configs.base import HierarchyConfig
+from repro.core.mixing import mix_blocks
 from repro.hierarchy.tree import AggregationTree
 
 
@@ -216,26 +216,22 @@ def build_event(rng: np.random.Generator, tree: AggregationTree,
 # ---------------------------------------------------------------------------
 
 def apply_device_matrix_pytree(params, M: jax.Array):
-    """params leaves (I, ...) -> (I, ...): one einsum per leaf against
-    the composed (I, I) event matrix. Hold-rows (e_i) are built into M,
-    so the application is unconditional — the fixed shape keeps a
-    jitted step compiled once across aggregation depths."""
+    """params leaves (I, ...) -> (I, ...): one mixing pass per leaf
+    against the composed (I, I) event matrix. Hold-rows (e_i) are built
+    into M, so the application is unconditional — the fixed shape keeps
+    a jitted step compiled once across aggregation depths."""
     def one(leaf):
-        I = leaf.shape[0]
-        z = leaf.reshape(I, -1)
-        out = jnp.einsum("ij,jm->im", M.astype(z.dtype), z,
-                         preferred_element_type=z.dtype)
-        return out.reshape(leaf.shape).astype(leaf.dtype)
+        # the (I, I) matrix as one "cluster" of I members: leaves keep
+        # their trailing dims (see repro.core.mixing)
+        return mix_blocks(M[None], leaf[None])[0].astype(leaf.dtype)
     return jax.tree.map(one, params)
 
 
 def global_from_weights(params, gw: jax.Array):
     """Root model from its (I,) source weights: leaves (I, ...) -> (...)."""
     def one(leaf):
-        I = leaf.shape[0]
-        g = jnp.einsum("i,im->m", gw.astype(leaf.dtype),
-                       leaf.reshape(I, -1))
-        return g.reshape(leaf.shape[1:]).astype(leaf.dtype)
+        w = gw.astype(leaf.dtype).reshape((-1,) + (1,) * (leaf.ndim - 1))
+        return (w * leaf).sum(axis=0).astype(leaf.dtype)
     return jax.tree.map(one, params)
 
 

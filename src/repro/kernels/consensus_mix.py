@@ -34,7 +34,8 @@ def _kernel(gamma_ref, z_ref, v_ref, o_ref):
     z0 = z_ref[0].astype(jnp.float32)         # (s, blk_m)
 
     def body(_, z):
-        return jnp.dot(v, z, preferred_element_type=jnp.float32)
+        return jnp.dot(v, z, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
 
     z = jax.lax.fori_loop(0, gamma_n, body, z0)
     o_ref[0] = z.astype(o_ref.dtype)

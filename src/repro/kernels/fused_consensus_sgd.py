@@ -1,25 +1,28 @@
 """Pallas TPU kernel: fused last-microstep SGD + D2D consensus mixing.
 
 One consensus block of the TT-HF interval ends with an SGD update
-followed by the block-diagonal mixing einsum ``z_c <- W_c z_c`` (the
+followed by the block-diagonal mixing ``z_c <- W_c z_c`` (the
 ``fused_power`` backend's precomputed ``W = V^Gamma``). Run separately
 those are two full parameter-stream HBM passes: read w / read g /
 write w, then read w / write w. This kernel fuses them into ONE pass —
-read w, read g, write mixed w — over the lane-padded flat ``(R, P)``
+read w, read g, write mixed w, in place — over the ``(R, rows, LANE)``
 replica buffer of the fused-interval step
 (:func:`repro.core.distributed.make_tthf_train_step` with
-``fused_interval=True``).
+``fused_interval=True``), viewed per cluster as ``(N, s, rows, LANE)``.
 
-Math (bitwise-matching the reference path, asserted in
-``tests/test_fused_interval.py``):
+Math (the same f32 operations as the XLA path,
+:func:`repro.core.mixing.mix_blocks`):
 
-    w' = w - eta * (g + wd * w)          (per replica, f32 accumulate)
-    z_c <- W_c @ w'_c                    (per cluster, s x s MXU matmul)
+    w' = w - eta * (g + wd * w)          (per replica, f32)
+    z_ci <- sum_j W_cij w'_cj            (per cluster, s terms in order)
 
-Grid: (N, M / blk_m). The (s, s) mixing block and an (s, blk_m) tile
-of w and g are pinned in VMEM; each column of the tile mixes
-independently, so lane-padding between pytree leaves is harmless
-(zeros map to zeros).
+The mixing is an explicit sum with W read as scalars from SMEM, not an
+MXU matmul: exact f32 (a DEFAULT-precision f32 matmul is one bf16
+pass) and free of the (s, M) tile a matmul would need.
+
+Grid: (N, rows / blk_rows). An (s, blk_rows, LANE) tile of w and g is
+pinned in VMEM; each entry mixes independently, so the zero padding
+between pytree leaves stays zero.
 """
 from __future__ import annotations
 
@@ -29,63 +32,70 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.runtime import resolve_interpret
 
 LANE = 128
+SUBLANE = 8
 
 
 def _kernel(w_ref, g_ref, mix_ref, eta_ref, o_ref, *,
             weight_decay: float):
-    w = w_ref[0].astype(jnp.float32)          # (s, blk)
+    n = pl.program_id(0)
+    s = w_ref.shape[1]
+    w = w_ref[0].astype(jnp.float32)          # (s, blk_rows, LANE)
     g = g_ref[0].astype(jnp.float32)
     if weight_decay:
         g = g + weight_decay * w
     wp = w - eta_ref[0] * g
-    mixed = jnp.dot(mix_ref[0].astype(jnp.float32), wp,
-                    preferred_element_type=jnp.float32)
-    o_ref[0] = mixed.astype(o_ref.dtype)
+    for i in range(s):
+        acc = mix_ref[n, i, 0] * wp[0]
+        for j in range(1, s):
+            acc = acc + mix_ref[n, i, j] * wp[j]
+        o_ref[0, i] = acc.astype(o_ref.dtype)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("weight_decay", "blk_m", "interpret"))
+    jax.jit, static_argnames=("weight_decay", "blk_rows", "interpret"))
 def fused_consensus_sgd(w: jax.Array, g: jax.Array, W: jax.Array,
                         eta: jax.Array, weight_decay: float = 0.0,
-                        blk_m: Optional[int] = None,
+                        blk_rows: Optional[int] = None,
                         interpret: Optional[bool] = None) -> jax.Array:
-    """w, g: (N, s, M); W: (N, s, s); returns ``W @ (w - eta*g)``.
+    """w, g: (N, s, rows, LANE); W: (N, s, s); returns
+    ``W @ (w - eta*g)`` per cluster, written over w (donate it).
 
     ``interpret=None`` auto-detects (interpret only off-TPU).
-    ``blk_m=None`` picks 4096 lanes compiled (VMEM-sized for small s)
-    and 65536 interpreted (fewer unrolled grid cells).
+    ``blk_rows=None`` picks 512 rows compiled (a 256 KiB tile per
+    member at s=2) and 8192 interpreted (fewer unrolled grid cells).
     """
     interpret = resolve_interpret(interpret)
-    if blk_m is None:
-        blk_m = 65_536 if interpret else 4_096
-    N, s, M = w.shape
-    assert g.shape == (N, s, M) and W.shape == (N, s, s)
+    if blk_rows is None:
+        blk_rows = 8_192 if interpret else 512
+    N, s, rows, lane = w.shape
+    assert lane == LANE and g.shape == w.shape and W.shape == (N, s, s)
 
-    # lane-align once: blk is a LANE multiple, M padded to a blk multiple
-    blk = max(LANE, min(blk_m, -(-M // LANE) * LANE))
-    pad = (-M) % blk
-    if pad:
-        w = jnp.pad(w, ((0, 0), (0, 0), (0, pad)))
-        g = jnp.pad(g, ((0, 0), (0, 0), (0, pad)))
-    Mp = M + pad
+    # a SUBLANE multiple, or all rows; a ragged last block needs no pad
+    # copy of the parameter-sized streams (its out-of-range rows are
+    # never written back, and entries mix independently)
+    blk = rows if rows <= blk_rows else max(SUBLANE,
+                                            blk_rows // SUBLANE * SUBLANE)
     eta_arr = jnp.asarray(eta, jnp.float32).reshape(1)
+    tile = pl.BlockSpec((1, s, blk, LANE), lambda n, r: (n, 0, r, 0))
 
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_kernel, weight_decay=weight_decay),
-        grid=(N, Mp // blk),
+        grid=(N, pl.cdiv(rows, blk)),
         in_specs=[
-            pl.BlockSpec((1, s, blk), lambda n, m: (n, 0, m)),
-            pl.BlockSpec((1, s, blk), lambda n, m: (n, 0, m)),
-            pl.BlockSpec((1, s, s), lambda n, m: (n, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            tile, tile,
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # W, read as scalars
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # scalar eta
         ],
-        out_specs=pl.BlockSpec((1, s, blk), lambda n, m: (n, 0, m)),
-        out_shape=jax.ShapeDtypeStruct((N, s, Mp), w.dtype),
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
+        # each tile is read before its mixed value is written over it:
+        # the update runs in place (no third parameter-sized buffer)
+        input_output_aliases={0: 0},
         interpret=interpret,
         name="fused_consensus_sgd",
-    )(w, g, W, eta_arr)
-    return out[:, :, :M] if pad else out
+    )(w, g, W.astype(jnp.float32), eta_arr)

@@ -18,6 +18,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.runtime import resolve_interpret
 
@@ -60,7 +61,7 @@ def fused_sgd(w: jax.Array, g: jax.Array, eta: jax.Array,
         in_specs=[
             pl.BlockSpec((blk,), lambda i: (i,)),
             pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # scalar eta
         ],
         out_specs=pl.BlockSpec((blk,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((wf.size,), dtype),

@@ -56,7 +56,8 @@ def fused_sgd(w: jax.Array, g: jax.Array, eta, weight_decay: float = 0.0
 
 def fused_consensus_sgd(w: jax.Array, g: jax.Array, W: jax.Array, eta,
                         weight_decay: float = 0.0) -> jax.Array:
-    """Fused last-microstep SGD + W-mixing; w, g: (N, s, M), W: (N, s, s)."""
+    """Fused last-microstep SGD + W-mixing; w, g: (N, s, rows, 128),
+    W: (N, s, s)."""
     return _fcs.fused_consensus_sgd(w, g, W, eta,
                                     weight_decay=weight_decay,
                                     interpret=INTERPRET)

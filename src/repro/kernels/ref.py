@@ -8,7 +8,7 @@ import jax.numpy as jnp
 
 def consensus_mix_ref(z: jax.Array, V: jax.Array,
                       gamma: jax.Array) -> jax.Array:
-    """z: (N, s, M); V: (N, s, s); gamma: (N,) int32 -> V_c^{gamma_c} z_c.
+    """z: (N, s, ...); V: (N, s, s); gamma: (N,) int32 -> V_c^{gamma_c} z_c.
 
     Reference: explicit per-round einsum with per-cluster masking.
     gamma must be CONCRETE (the loop unrolls in Python) — it is read
@@ -22,8 +22,9 @@ def consensus_mix_ref(z: jax.Array, V: jax.Array,
     out = z.astype(jnp.float32)
     Vf = V.astype(jnp.float32)
     for r in range(max_gamma):
-        mixed = jnp.einsum("nij,njm->nim", Vf, out)
-        keep = jnp.asarray((r < gamma)[:, None, None])
+        mixed = jnp.einsum("nij,nj...->ni...", Vf, out,
+                           precision=jax.lax.Precision.HIGHEST)
+        keep = jnp.asarray((r < gamma).reshape((-1,) + (1,) * (z.ndim - 1)))
         out = jnp.where(keep, mixed, out)
     return out.astype(z.dtype)
 
@@ -79,6 +80,7 @@ def fused_consensus_sgd_ref(w: jax.Array, g: jax.Array, W: jax.Array,
                             weight_decay: float = 0.0) -> jax.Array:
     """W_c @ (w_c - eta * (g_c + wd * w_c)); w, g: (N, s, M), W: (N, s, s)."""
     wp = fused_sgd_ref(w, g, eta, weight_decay=weight_decay)
-    return jnp.einsum("nij,njm->nim", W.astype(jnp.float32),
+    return jnp.einsum("nij,nj...->ni...", W.astype(jnp.float32),
                       wp.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST,
                       preferred_element_type=jnp.float32).astype(w.dtype)

@@ -12,7 +12,9 @@ in a VMEM scratch buffer that persists across the chunk axis of the grid
 (minor-most => sequential), so HBM sees each token exactly once in and
 once out — the memory-optimal schedule for a recurrent scan on TPU.
 
-Grid: (BH, T/Q). Block shapes: X (Q, P), B/C (Q, S), decay rows (1, Q);
+Grid: (BH, T/Q). Block shapes: X (Q, P), B/C (Q, S), and the decay
+rows dt/loga as (1, Q) blocks of a (BH, 1, T) view (tile-legal: the
+unit dim equals the array's, Q is a lane multiple);
 defaults Q=256, S=128, P=64 keep the working set ~0.6 MB << 16 MB VMEM
 and all matmul dims MXU-aligned (Q, S multiples of 128; P=64 packs the
 lane dim at half utilization, the native Mamba-2 head size).
@@ -35,34 +37,42 @@ def _kernel(x_ref, dt_ref, loga_ref, b_ref, c_ref, y_ref, hfin_ref, h_scr):
         h_scr[...] = jnp.zeros_like(h_scr)
 
     x = x_ref[0].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[0].astype(jnp.float32)        # (Q,)
-    la = loga_ref[0].astype(jnp.float32)      # (Q,)
+    dt = dt_ref[0].astype(jnp.float32)        # (1, Q) row
+    la = loga_ref[0].astype(jnp.float32)      # (1, Q) row
     b = b_ref[0].astype(jnp.float32)          # (Q, S)
     c = c_ref[0].astype(jnp.float32)          # (Q, S)
 
-    l = jnp.cumsum(la)                        # inclusive cumulative log decay
     q = x.shape[0]
-
-    # intra-chunk: M[t,u] = exp(l_t - l_u) * dt_u  for u <= t
-    g = jnp.dot(c, b.T, preferred_element_type=jnp.float32)   # (Q, Q)
     t_idx = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     u_idx = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     causal = t_idx >= u_idx
+    diag = t_idx == u_idx
+
+    # Mosaic lowers neither cumsum nor a (1, Q) -> (Q, 1) relayout, so
+    # both come from masked (Q, Q) reductions (adding zeros is exact):
+    # the inclusive cumulative log decay l_t = sum_{u <= t} la_u as a
+    # column, then its row twin and dt's column twin off the diagonal
+    l_col = jnp.sum(jnp.where(causal, la, 0.0), axis=1, keepdims=True)
+    l_row = jnp.sum(jnp.where(diag, l_col, 0.0), axis=0, keepdims=True)
+    dt_col = jnp.sum(jnp.where(diag, dt, 0.0), axis=1, keepdims=True)
+    total = jnp.sum(la, axis=1, keepdims=True)                # (1, 1)
+
+    # intra-chunk: M[t,u] = exp(l_t - l_u) * dt_u  for u <= t
+    g = jnp.dot(c, b.T, preferred_element_type=jnp.float32)   # (Q, Q)
     # clamp to <= 0: exact on causal entries (l is non-increasing) and
     # keeps the masked half from overflowing exp (inf * 0 = nan in the
     # backward pass)
-    decay = jnp.exp(jnp.minimum(l[:, None] - l[None, :], 0.0))
-    m = jnp.where(causal, g * decay * dt[None, :], 0.0)
+    decay = jnp.exp(jnp.minimum(l_col - l_row, 0.0))
+    m = jnp.where(causal, g * decay * dt, 0.0)
     y = jnp.dot(m, x, preferred_element_type=jnp.float32)     # (Q, P)
 
     # inter-chunk: contribution of the carried state
     h = h_scr[...]                                            # (S, P)
-    c_decayed = c * jnp.exp(l)[:, None]
+    c_decayed = c * jnp.exp(l_col)
     y = y + jnp.dot(c_decayed, h, preferred_element_type=jnp.float32)
 
     # state update
-    total = l[q - 1]
-    b_decayed = b * (jnp.exp(total - l) * dt)[:, None]        # (Q, S)
+    b_decayed = b * (jnp.exp(total - l_col) * dt_col)         # (Q, S)
     h_new = jnp.exp(total) * h + jnp.dot(
         b_decayed.T, x, preferred_element_type=jnp.float32)
     h_scr[...] = h_new
@@ -92,8 +102,8 @@ def ssd_scan(x: jax.Array, dt: jax.Array, loga: jax.Array, B: jax.Array,
         grid=(BH, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, P), lambda bh, c: (bh, c, 0)),
-            pl.BlockSpec((1, chunk), lambda bh, c: (bh, c)),
-            pl.BlockSpec((1, chunk), lambda bh, c: (bh, c)),
+            pl.BlockSpec((1, 1, chunk), lambda bh, c: (bh, 0, c)),
+            pl.BlockSpec((1, 1, chunk), lambda bh, c: (bh, 0, c)),
             pl.BlockSpec((1, chunk, S), lambda bh, c: (bh, c, 0)),
             pl.BlockSpec((1, chunk, S), lambda bh, c: (bh, c, 0)),
         ],
@@ -108,5 +118,5 @@ def ssd_scan(x: jax.Array, dt: jax.Array, loga: jax.Array, B: jax.Array,
         scratch_shapes=[pltpu.VMEM((S, P), jnp.float32)],
         interpret=interpret,
         name="ssd_scan",
-    )(x, dt, loga, B, C)
+    )(x, dt[:, None], loga[:, None], B, C)
     return y, hfin

@@ -41,7 +41,12 @@ MESH_PODS = {"multipod": 2, "multipod10k": 40}
 
 
 def configure_xla(args) -> None:
-    """Set XLA_FLAGS from the parsed args. Must run before jax init.
+    """Pin the CPU platform and set XLA_FLAGS from the parsed args.
+    Must run before jax init.
+
+    The dry-run compiles against host placeholder devices, so it sets
+    ``JAX_PLATFORMS=cpu`` itself: on a host with a TPU neither it nor
+    its ``--subprocess`` children (which inherit the env) touch the chip.
 
     Device count: 512 for pod/multipod, 10,240 for the scale-out
     lowering check (--mesh multipod10k = 40 pods x 256).
@@ -63,6 +68,7 @@ def configure_xla(args) -> None:
     if is_train:
         flags += " --xla_disable_hlo_passes=while-loop-invariant-code-motion"
     os.environ["XLA_FLAGS"] = flags
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 
 def build_tthf_program(model, shape, mesh, sync: str, consensus_mode: str,
@@ -73,7 +79,7 @@ def build_tthf_program(model, shape, mesh, sync: str, consensus_mode: str,
     production mesh: replicas = pod*data slices, clusters = data-blocks
     (multi-pod: cluster == pod). Used by the §Perf paper-technique
     hillclimb (--sync tthf-fused / tthf-rounds / tthf-fused-interval /
-    star / local). ``fused_interval`` lowers the flat (R, P) carrier
+    star / local). ``fused_interval`` lowers the flat (R, rows, 128) carrier
     step (DESIGN.md §12); ``donate=False`` keeps the param input buffer
     alive, for the donated-vs-undonated memory_analysis delta."""
     import jax
@@ -105,13 +111,14 @@ def build_tthf_program(model, shape, mesh, sync: str, consensus_mode: str,
     p_abs, p_sh, b_sh = tthf_shardings(
         model, scale, mesh, param_dtype=param_dtype_for(model.cfg))
     if fused_interval:
-        # the flat (R, P) carrier: rows over the replica axes, columns
-        # over model ranks (P is a LANE multiple, so 16 always divides)
+        # the flat (R, rows, LANE) carrier: replicas over the replica
+        # axes, rows over model ranks (rows pad to ROW_ALIGN = 128, so
+        # 16 always divides)
         spec = step.spec
         p_abs = spec.abstract(R)
         rows = (("pod",) if pod_granular
                 else ("pod", "data") if "pod" in sizes else ("data",))
-        p_sh = NamedSharding(mesh, P(rows, "model"))
+        p_sh = NamedSharding(mesh, P(rows, "model", None))
     b = max(1, shape.global_batch // R)
     if pod_granular:
         # giant-model TT-HF: per-replica microbatch reduced 4x (the
@@ -313,8 +320,8 @@ def main(argv=None):
                              "tthf-fused-interval"],
                     help="lower the TT-HF interval step instead of the "
                          "standard train/serve step (train_4k only); "
-                         "tthf-fused-interval = the flat (R, P) carrier "
-                         "step (DESIGN.md §12)")
+                         "tthf-fused-interval = the flat (R, rows, 128) "
+                         "carrier step (DESIGN.md §12)")
     ap.add_argument("--tau", type=int, default=8)
     ap.add_argument("--consensus-every", type=int, default=4)
     ap.add_argument("--donation-check", action="store_true",

@@ -6,6 +6,15 @@ state; `dryrun.py` sets XLA_FLAGS *before* importing anything.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the in-model
+    ``hint`` pins (``with_sharding_constraint``) may only name Auto
+    axes, and ``make_mesh`` otherwise makes them Explicit."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, pods: int = 2):
@@ -15,7 +24,7 @@ def make_production_mesh(*, multi_pod: bool = False, pods: int = 2):
     10,240-chip scale-out lowering check (``--mesh multipod10k``)."""
     shape = (pods, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def chips_in(mesh) -> int:
@@ -53,4 +62,4 @@ def make_serve_mesh(spec: str = "host"):
             raise ValueError(
                 f"mesh spec {spec!r} wants {d * m} devices, have {n}")
         shape = (d, m)
-    return jax.make_mesh(shape, ("data", "model"))
+    return auto_mesh(shape, ("data", "model"))

@@ -177,9 +177,11 @@ def main(argv=None):
     import jax
     import jax.numpy as jnp
     from repro.configs import get_arch
+    from repro.launch.compile_cache import init_compile_cache
     from repro.models import build_model
     from repro.serving import sample_tokens, serve_shardings, shard_params
 
+    init_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

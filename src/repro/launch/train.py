@@ -190,6 +190,8 @@ def main(argv=None):
     ap.add_argument("--consensus-mode", choices=["fused", "rounds"],
                     default="fused")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import init_compile_cache
+    init_compile_cache()
     return run_sim(args) if args.mode == "sim" else run_scale(args)
 
 

@@ -25,7 +25,6 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 
 # ---------------------------------------------------------------------------
@@ -116,11 +115,12 @@ def weighted_global_pytree(params, weights: jax.Array, num_clusters: int):
     w_hat = sum_{c,i} w[c,i] z[c,i].
     """
     def one(leaf):
-        I = leaf.shape[0]
-        s = I // num_clusters
-        z = leaf.reshape(num_clusters, s, -1)
-        g = jnp.einsum("cs,csm->m", weights.astype(z.dtype), z)
-        return g.reshape(leaf.shape[1:])
+        # (N, s, ...) leading-axis view: trailing dims stay put (see
+        # repro.core.mixing on why a parameter is never one long row)
+        z = leaf.reshape((num_clusters, -1) + leaf.shape[1:])
+        w = weights.astype(z.dtype).reshape(
+            weights.shape + (1,) * (z.ndim - 2))
+        return (w * z).sum(axis=(0, 1))
     return jax.tree.map(one, params)
 
 

@@ -99,12 +99,11 @@ class Observability:
             extra={"run": run_name, **(extra or {})})
         self._profiling = False
         if cfg.profile:
-            try:
-                import jax
-                jax.profiler.start_trace(str(self.dir / "jax_profile"))
-                self._profiling = True
-            except Exception:  # noqa: BLE001 — profiling is best-effort
-                self._profiling = False
+            # a --profile run without its device trace is a failed run:
+            # let start_trace raise instead of running on untraced
+            import jax
+            jax.profiler.start_trace(str(self.dir / "jax_profile"))
+            self._profiling = True
         self._closed = False
 
     # -- tracer passthrough -------------------------------------------------
@@ -136,12 +135,9 @@ class Observability:
         self._closed = True
         self.flush()
         if self._profiling:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:  # noqa: BLE001
-                pass
+            import jax
             self._profiling = False
+            jax.profiler.stop_trace()
         self.metrics.close()
 
     def __enter__(self) -> "Observability":

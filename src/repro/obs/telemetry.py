@@ -34,7 +34,7 @@ def make_divergence_probe(num_clusters: int, cluster_size: int,
                           varrho) -> Callable:
     """Jitted probe over a params pytree whose leaves carry a leading
     device axis I = N*s (simulation fleet, scale-mode replica stack, or
-    the §12 flat (R, P) carrier — an array is a one-leaf pytree).
+    the §12 flat (R, rows, 128) carrier — an array is a one-leaf pytree).
 
     Returns ``{upsilon (N,), consensus_err (N,), mix_residual (N,),
     dispersion (), param_norm ()}``; everything is computed on device

@@ -156,15 +156,14 @@ def validate_chrome_trace(doc: dict) -> list[str]:
 
 
 def profiler_trace(trace_dir: Optional[str]):
-    """Best-effort ``jax.profiler.trace`` context (no-op fallback)."""
+    """``jax.profiler.trace`` context under ``<trace_dir>/jax_profile``
+    (a no-op without a trace dir). A profiler that fails to start
+    raises: a profiled run never goes on without its device trace."""
     from contextlib import nullcontext
     if not trace_dir:
         return nullcontext()
-    try:
-        import jax
-        return jax.profiler.trace(str(Path(trace_dir) / "jax_profile"))
-    except Exception:  # noqa: BLE001 — profiling must never kill a run
-        return nullcontext()
+    import jax
+    return jax.profiler.trace(str(Path(trace_dir) / "jax_profile"))
 
 
 __all__ = ["Tracer", "validate_chrome_trace", "profiler_trace"]

@@ -54,6 +54,11 @@ class MetricLogger:
         vals = self._recent.get(key)
         return sum(vals) / len(vals) if vals else float("nan")
 
+    def last(self, key: str) -> float:
+        """The newest logged value of ``key`` (nan if never logged)."""
+        vals = self._recent.get(key)
+        return vals[-1] if vals else float("nan")
+
     def close(self) -> None:
         if self._fh:
             self._fh.close()

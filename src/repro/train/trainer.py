@@ -58,8 +58,8 @@ class TrainerConfig:
     # bitwise; flip off to A/B against the straight-line path
     donate: bool = True             # donate params+batch buffers to the
                                     # jitted step (halves peak param HBM)
-    fused_interval: bool = False    # flat (R, P) param carrier + fused
-                                    # SGD+consensus block-ends
+    fused_interval: bool = False    # flat (R, rows, 128) param carrier
+                                    # + fused SGD+consensus block-ends
     prefetch: bool = True           # build/transfer interval k+1's
                                     # batch while interval k computes
     # observability (repro.obs, DESIGN.md §13): a trace dir turns on
@@ -120,7 +120,8 @@ class ScaleTrainer:
             refreshable=refreshable, hierarchy=program.hierarchy,
             fused_interval=tcfg.fused_interval)
         # fused-interval runs carry self.params as the step's flat
-        # (R, P) buffer; the spec unflattens at eval/checkpoint/serving
+        # (R, rows, 128) buffer; the spec unflattens at eval/checkpoint/
+        # serving
         # boundaries (checkpoints stay in the pytree format either way)
         self._spec = getattr(step, "spec", None)
         self._plan = None
@@ -196,7 +197,9 @@ class ScaleTrainer:
         self.params = stack_replicas(init_params, self.scale.replicas)
         if self._spec is not None:
             self.params = self._spec.flatten(self.params)
-        self._global = init_params
+        # only hierarchical runs serve a root snapshot; a flat run would
+        # hold a whole extra model on the device for nothing
+        self._global = init_params if self.tree is not None else None
         return self
 
     def _build_interval_batch(self):

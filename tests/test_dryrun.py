@@ -1,6 +1,7 @@
 """Dry-run smoke: one real 512-placeholder-device lowering in a
 subprocess (the in-process test session is pinned to 1 CPU device)."""
 import json
+import os
 import subprocess
 import sys
 
@@ -15,7 +16,8 @@ def test_dryrun_subprocess_decode():
          "--mesh", "pod", "--out", "-"],
         capture_output=True, text=True, timeout=1200,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root"})
+             "HOME": os.environ.get("HOME", "/root"),
+             "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-2000:]
     rec = json.loads(out.stdout.splitlines()[-1])
     assert rec["status"] == "ok"

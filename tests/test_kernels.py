@@ -136,20 +136,21 @@ def test_fused_sgd_block_is_lane_aligned():
 # fused_consensus_sgd: last-microstep SGD + W-mixing in one pass
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("N,s,M", [(2, 4, 64), (4, 2, 937), (1, 8, 128)])
+@pytest.mark.parametrize("N,s,rows", [(2, 4, 1), (4, 2, 37), (1, 8, 8)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("wd", [0.0, 0.1])
-def test_fused_consensus_sgd(N, s, M, dtype, wd):
+def test_fused_consensus_sgd(N, s, rows, dtype, wd):
     rng = np.random.default_rng(3)
-    w = jnp.asarray(rng.normal(size=(N, s, M)), dtype)
-    g = jnp.asarray(rng.normal(size=(N, s, M)), dtype)
+    shape = (N, s, rows, 128)
+    w = jnp.asarray(rng.normal(size=shape), dtype)
+    g = jnp.asarray(rng.normal(size=shape), dtype)
     V = _V(N, s)
     W = jnp.asarray(np.stack([np.linalg.matrix_power(
         np.asarray(V[c], np.float64), 2) for c in range(N)]), jnp.float32)
     out = ops.fused_consensus_sgd(w, g, W, 0.01, weight_decay=wd)
     expect = ref.fused_consensus_sgd_ref(w, g, W, jnp.asarray(0.01),
                                          weight_decay=wd)
-    assert out.shape == (N, s, M) and out.dtype == dtype
+    assert out.shape == shape and out.dtype == dtype
     # bf16: the ref rounds to bf16 between the SGD update and the mix,
     # the kernel keeps f32 throughout — up to ~2 bf16 ulp apart, so the
     # bound must scale with magnitude (rtol), not be purely absolute
@@ -161,12 +162,14 @@ def test_fused_consensus_sgd(N, s, M, dtype, wd):
 
 def test_fused_consensus_sgd_matches_jitted_two_pass():
     """vs the jitted unfused two-pass graph (SGD then mix) — the jit-to-
-    jit comparison the fused-interval step's bitwise contract rests on."""
+    jit comparison the fused-interval step's bitwise contract rests on.
+    37 rows in blocks of 16 also cover the ragged last block."""
+    from repro.core.mixing import mix_blocks
     from repro.kernels.fused_consensus_sgd import fused_consensus_sgd
-    N, s, M = 2, 4, 384
+    N, s, rows = 2, 4, 37
     rng = np.random.default_rng(5)
-    w = jnp.asarray(rng.normal(size=(N, s, M)), jnp.float32)
-    g = jnp.asarray(rng.normal(size=(N, s, M)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(N, s, rows, 128)), jnp.float32)
+    g = jnp.asarray(rng.normal(size=(N, s, rows, 128)), jnp.float32)
     V = _V(N, s)
     W = jnp.asarray(np.stack([np.linalg.matrix_power(
         np.asarray(V[c], np.float64), 3) for c in range(N)]), jnp.float32)
@@ -174,10 +177,9 @@ def test_fused_consensus_sgd_matches_jitted_two_pass():
     @jax.jit
     def two_pass(w, g, W):
         wp = w - jnp.float32(0.01) * g
-        return jnp.einsum("nij,njm->nim", W, wp,
-                          preferred_element_type=jnp.float32)
+        return mix_blocks(W, wp)
 
-    fused = fused_consensus_sgd(w, g, W, jnp.float32(0.01))
+    fused = fused_consensus_sgd(w, g, W, jnp.float32(0.01), blk_rows=16)
     assert np.array_equal(np.asarray(fused), np.asarray(two_pass(w, g, W)))
 
 

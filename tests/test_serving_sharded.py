@@ -34,8 +34,8 @@ ALL_ARCHS = sorted(ARCHS)
 
 # production multi-pod geometry (sizes only — AbstractMesh never
 # touches devices, so the 1-CPU test session can resolve 512-chip specs)
-MULTIPOD = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
-HOST8 = AbstractMesh((("data", 2), ("model", 4)))
+MULTIPOD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+HOST8 = AbstractMesh((2, 4), ("data", "model"))
 
 
 def _entries(spec, ndim):
@@ -160,7 +160,8 @@ def test_scheduler_mesh_threading_parity_one_device(kind):
     single test device must reproduce the no-mesh token streams
     exactly (and exercises sharded init_cache/write_cache_slot/jit
     out_shardings without needing forced host devices)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((1, 1), ("data", "model"))
     model = build_model(_reduced())
     params = model.init(jax.random.PRNGKey(0))
     base = _run_tokens(model, params, None, kind=kind)

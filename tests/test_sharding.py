@@ -10,6 +10,7 @@ from jax.sharding import PartitionSpec as P
 from repro.dist.sharding import (
     ShardingRules, drop_hint_axes, hint, resolve_hint_spec,
 )
+from repro.launch.mesh import auto_mesh
 
 RULES = ShardingRules((
     ("batch", ("pod", "data")),
@@ -24,12 +25,12 @@ RULES = ShardingRules((
 @pytest.fixture(scope="module")
 def mesh3():
     """(pod=1, data=1, model=1) — axis names matter, sizes don't."""
-    return jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    return auto_mesh((1, 1, 1), ("pod", "data", "model"))
 
 
 @pytest.fixture(scope="module")
 def mesh_dm():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def test_spec_basic(mesh3):
