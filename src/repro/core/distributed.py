@@ -19,6 +19,13 @@ consensus events static in the HLO (aperiodicity via the *fixed* event
 calendar; the Remark-1 adaptive round count is a simulation-mode
 feature — scale mode takes Gamma from config).
 
+The step's three parts carry ``jax.named_scope``s, so every op of the
+compiled step names its part in its ``op_name`` metadata (and in a
+device profile): ``local_sgd`` (the microsteps), ``d2d_mix`` (consensus
+mixing), ``global_agg`` (the global aggregation), and ``local_sgd_mix``
+for the Pallas kernel that fuses a block's last SGD update with its
+mixing. Scopes change metadata only, never the computation.
+
 Consensus execution dispatches through the unified engine
 (:mod:`repro.core.mixing`, DESIGN.md §5).  ``consensus_mode`` is a
 backend name; the legacy aliases remain the §Perf comparison axis:
@@ -395,12 +402,24 @@ def make_tthf_train_step(model: ModelApi, scale: TTHFScaleConfig, *,
                                    scale.sample_per_cluster > 1)
                 else "picks")
 
+    def aggregate(params, agg):
+        """The interval's closing aggregation (any carrier)."""
+        if sync == "tthf":
+            if agg_kind == "picks":
+                return sampled_aggregation(params, net, agg)
+            if agg_kind == "weights":
+                return weighted_aggregation(params, net, agg)
+            return apply_device_matrix_pytree(params, agg)
+        if sync == "star":
+            return full_aggregation(params, net)
+        return params
+
     if fused_interval:
         return _make_fused_interval_step(
-            model, scale, net=net, plan=plan, sync=sync,
-            refreshable=refreshable, agg_kind=agg_kind,
-            n_blocks=n_blocks, replica_loss=replica_loss,
-            fused_kernel=fused_kernel, param_dtype=param_dtype)
+            model, scale, net=net, plan=plan, aggregate=aggregate,
+            refreshable=refreshable, n_blocks=n_blocks,
+            replica_loss=replica_loss, fused_kernel=fused_kernel,
+            param_dtype=param_dtype)
 
     def interval(params, batch, agg, mix_refresh):
         lr = jnp.asarray(scale.lr, jnp.float32)
@@ -411,23 +430,18 @@ def make_tthf_train_step(model: ModelApi, scale: TTHFScaleConfig, *,
 
         def block(params, block_batch):
             def inner(params, mb):
-                params, loss = microstep(params, mb, lr)
+                with jax.named_scope("local_sgd"):
+                    params, loss = microstep(params, mb, lr)
                 return params, loss
             params, losses = jax.lax.scan(inner, params, block_batch)
             if plan is not None:
-                params = plan.apply_pytree(params, refresh=mix_refresh)
+                with jax.named_scope("d2d_mix"):
+                    params = plan.apply_pytree(params, refresh=mix_refresh)
             return params, jnp.mean(losses)
 
         params, block_losses = jax.lax.scan(block, params, batch_b)
-        if sync == "tthf":
-            if agg_kind == "picks":
-                params = sampled_aggregation(params, net, agg)
-            elif agg_kind == "weights":
-                params = weighted_aggregation(params, net, agg)
-            else:
-                params = apply_device_matrix_pytree(params, agg)
-        elif sync == "star":
-            params = full_aggregation(params, net)
+        with jax.named_scope("global_agg"):
+            params = aggregate(params, agg)
         return params, jnp.mean(block_losses)
 
     if refreshable:
@@ -442,9 +456,8 @@ def make_tthf_train_step(model: ModelApi, scale: TTHFScaleConfig, *,
 
 def _make_fused_interval_step(model: ModelApi, scale: TTHFScaleConfig, *,
                               net: Network, plan: Optional[MixingPlan],
-                              sync: str, refreshable: bool, agg_kind: str,
-                              n_blocks: int, replica_loss,
-                              fused_kernel: Optional[bool],
+                              aggregate, refreshable: bool, n_blocks: int,
+                              replica_loss, fused_kernel: Optional[bool],
                               param_dtype) -> tuple[Any, Network]:
     """The ``fused_interval=True`` build — see ``make_tthf_train_step``.
 
@@ -495,14 +508,15 @@ def _make_fused_interval_step(model: ModelApi, scale: TTHFScaleConfig, *,
             re-vectorizes it — a 1-ulp drift vs the reference step on
             non-lane-aligned models.
             """
-            params = spec.unflatten(flat)
-            losses, grads = jax.vmap(
-                lambda p, m: jax.value_and_grad(replica_loss)(p, m)
-            )(params, mb)
-            params = jax.tree.map(
-                lambda w, g: w - jnp.asarray(lr, w.dtype)
-                * g.astype(w.dtype), params, grads)
-            return spec.flatten(params), jnp.mean(losses)
+            with jax.named_scope("local_sgd"):
+                params = spec.unflatten(flat)
+                losses, grads = jax.vmap(
+                    lambda p, m: jax.value_and_grad(replica_loss)(p, m)
+                )(params, mb)
+                params = jax.tree.map(
+                    lambda w, g: w - jnp.asarray(lr, w.dtype)
+                    * g.astype(w.dtype), params, grads)
+                return spec.flatten(params), jnp.mean(losses)
 
         # W available => the block-end collapses to ONE mixing pass
         W0 = plan.fused_w(mix_refresh) if mix_active else None
@@ -520,10 +534,12 @@ def _make_fused_interval_step(model: ModelApi, scale: TTHFScaleConfig, *,
                 head = jax.tree.map(lambda x: x[:-1], block_batch)
                 last = jax.tree.map(lambda x: x[-1], block_batch)
                 flat, head_losses = jax.lax.scan(sgd, flat, head)
-                g, last_loss = grad_flat(flat, last)
-                flat = _fused_kernel_fn(
-                    replica_blocks(flat, N), replica_blocks(g, N),
-                    W0, lr).reshape(flat.shape)
+                with jax.named_scope("local_sgd"):
+                    g, last_loss = grad_flat(flat, last)
+                with jax.named_scope("local_sgd_mix"):
+                    flat = _fused_kernel_fn(
+                        replica_blocks(flat, N), replica_blocks(g, N),
+                        W0, lr).reshape(flat.shape)
                 losses = jnp.concatenate([head_losses, last_loss[None]])
                 return flat, jnp.mean(losses)
             # XLA path — bitwise contract: the microstep scan matches
@@ -534,24 +550,18 @@ def _make_fused_interval_step(model: ModelApi, scale: TTHFScaleConfig, *,
             # op instead of per-leaf launches
             flat, losses = jax.lax.scan(sgd, flat, block_batch)
             if mix_active:
-                z = replica_blocks(flat, N)
-                # a non-fused_power backend keeps its exact per-event
-                # semantics on the flat buffer
-                z = (mix_blocks(W0, z) if W0 is not None
-                     else plan.apply(z, refresh=mix_refresh))
-                flat = z.reshape(flat.shape)
+                with jax.named_scope("d2d_mix"):
+                    z = replica_blocks(flat, N)
+                    # a non-fused_power backend keeps its exact per-event
+                    # semantics on the flat buffer
+                    z = (mix_blocks(W0, z) if W0 is not None
+                         else plan.apply(z, refresh=mix_refresh))
+                    flat = z.reshape(flat.shape)
             return flat, jnp.mean(losses)
 
         flat, block_losses = jax.lax.scan(block, flat, batch_b)
-        if sync == "tthf":
-            if agg_kind == "picks":
-                flat = sampled_aggregation(flat, net, agg)
-            elif agg_kind == "weights":
-                flat = weighted_aggregation(flat, net, agg)
-            else:
-                flat = apply_device_matrix_pytree(flat, agg)
-        elif sync == "star":
-            flat = full_aggregation(flat, net)
+        with jax.named_scope("global_agg"):
+            flat = aggregate(flat, agg)
         return flat, jnp.mean(block_losses)
 
     if refreshable:

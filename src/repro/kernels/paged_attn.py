@@ -108,7 +108,7 @@ def paged_decode(q, k_pages, v_pages, page_map, pos, *, window: int = 0,
     fn = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), jnp.float32),
-        interpret=interpret)
+        interpret=interpret, name="paged_decode")
     return fn(page_map.astype(jnp.int32), pos.astype(jnp.int32),
               q.astype(jnp.float32), k_pages, v_pages)
 

@@ -3,10 +3,10 @@
 Three pieces, one run directory:
 
 * :mod:`~repro.obs.trace` — a span tracer whose hierarchy mirrors the
-  paper's timescales (``run > round > {interval, consensus_event,
-  aggregation}`` for training; ``run > {prefill, decode_step,
-  admission}`` for serving), exported as zero-dep Chrome-trace JSON
-  with an optional ``jax.profiler`` passthrough.
+  paper's timescales (``run > round > interval`` for training; ``run >
+  step > {admission, prefill_chunk, decode_step}`` for serving),
+  exported as zero-dep Chrome-trace JSON. Every span, from every sink,
+  is also a ``repro.<name>`` annotation on the profiler's clock.
 * :mod:`~repro.obs.telemetry` — jit-safe aux-metric probes (per-cluster
   consensus divergence, post-mixing residual, dispersion, grad norms)
   plus host-side ``core/theory.py`` bound gauges (``sigma_t``,
@@ -15,7 +15,8 @@ Three pieces, one run directory:
   mesh, backend) written next to every JSONL/trace.
 
 ``make_obs(trace_dir)`` builds the whole sink; ``NULL_OBS`` is the
-free disabled default every instrumented call site holds.
+disabled default every instrumented call site holds: it keeps nothing,
+and its spans reach only a running profiler.
 """
 from repro.obs.sink import NULL_OBS, Observability, ObsConfig, make_obs
 from repro.obs.trace import Tracer, profiler_trace, validate_chrome_trace

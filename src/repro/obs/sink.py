@@ -10,22 +10,25 @@ One :class:`Observability` object per run directory. It owns
   a round is a single grep,
 * a ``manifest.json`` (config hash, git SHA, mesh, backend) written at
   construction,
-* an optional ``jax.profiler`` trace in ``jax_profile/`` so the device
-  timeline lines up with the host spans.
+* with ``profile``, a ``jax.profiler`` session writing ``jax_profile/``.
 
-Instrumented call sites hold ``NULL_OBS`` by default — every method is
-a no-op costing one attribute lookup — and are handed a real sink via
-``make_obs(trace_dir, ...)``.
+Spans, instants and counters enter the profiler's clock as
+``repro.<name>`` annotations from every sink, ``NULL_OBS`` included
+(``repro.obs.trace``): under any profiler session — this sink's, or one
+an outside harness started — the program's host spans lie beside the
+device ops. Instrumented call sites hold ``NULL_OBS`` by default — it
+keeps nothing, writes no file, runs no probe and adds no device sync;
+a span costs about a microsecond when no profiler session runs — and
+are handed a recording sink via ``make_obs(trace_dir, ...)``.
 """
 from __future__ import annotations
 
-from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, Optional
 
+from repro.obs import trace
 from repro.obs.manifest import write_manifest
-from repro.obs.trace import Tracer
 
 # NOTE: repro.train.metrics is imported lazily inside
 # Observability.__init__ — a top-level import would cycle
@@ -36,25 +39,26 @@ from repro.obs.trace import Tracer
 @dataclass
 class ObsConfig:
     trace_dir: Optional[str] = None     # None = observability off
-    profile: bool = False               # jax.profiler passthrough
+    profile: bool = False               # start a jax.profiler session
     window: int = 100                   # MetricLogger smoothing window
     console_every: int = 0              # 0 = JSONL only, no console
 
 
 class _NullObs:
-    """The disabled sink — safe to call everywhere, records nothing."""
+    """The disabled sink — safe to call everywhere, keeps nothing; its
+    spans, instants and counters reach only a running profiler."""
     enabled = False
     tracer = None
     metrics = None
 
-    def span(self, name: str, **args: Any):
-        return nullcontext(self)
+    def span(self, name: str, counters=None, **args: Any):
+        return trace.span(name, self, counters, **args)
 
     def instant(self, name: str, **args: Any) -> None:
-        pass
+        trace.mark(name, **args)
 
     def counter(self, name: str, **values: Any) -> None:
-        pass
+        trace.mark("counter." + name, **values)
 
     def emit(self, kind: str, step: int, **fields: Any) -> None:
         pass
@@ -89,7 +93,7 @@ class Observability:
         self.cfg = cfg
         self.dir = Path(cfg.trace_dir)
         self.dir.mkdir(parents=True, exist_ok=True)
-        self.tracer = Tracer(annotate=cfg.profile)
+        self.tracer = trace.Tracer()
         from repro.train.metrics import MetricLogger
         self.metrics = MetricLogger(str(self.dir / "metrics.jsonl"),
                                     console_every=cfg.console_every,
@@ -107,10 +111,8 @@ class Observability:
         self._closed = False
 
     # -- tracer passthrough -------------------------------------------------
-    @contextmanager
-    def span(self, name: str, **args: Any):
-        with self.tracer.span(name, **args):
-            yield self
+    def span(self, name: str, counters=None, **args: Any):
+        return self.tracer.open(name, args, counters, self)
 
     def instant(self, name: str, **args: Any) -> None:
         self.tracer.instant(name, **args)

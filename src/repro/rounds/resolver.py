@@ -297,7 +297,7 @@ class RoundResolver:
         aggregation argument, the optional consensus-matrix refresh,
         and the interval's full bill (local steps × τ, the interval's
         ``τ // consensus_every`` consensus events, the uplinks)."""
-        with self.obs.span("resolve", interval=interval):
+        with self.obs.span("train.resolve", interval=interval):
             ev = self._resolve_interval(interval, kp)
         self.obs.counter(
             "resolver",

@@ -105,6 +105,14 @@ class SchedulerStats:
         return self.live_slot_steps / max(self.slot_steps, 1)
 
 
+def _serve_decode(model: ModelApi):
+    """The ring-cache decode step as the jitted program
+    ``serve_decode``."""
+    def serve_decode(p, t, c, s):
+        return model.decode_step(p, t, c, s, dtype=jnp.float32)
+    return serve_decode
+
+
 class _SchedulerBase:
     """Shared request plumbing: queue, slots, padding, sampling.
 
@@ -148,6 +156,15 @@ class _SchedulerBase:
         # the step clock: one tick per step() call (admission attempts
         # and decode steps alike) — all Request stamps use this clock
         self.clock = 0
+        # occupied slots, kept up to date at admission and free (the
+        # step span's counter; no per-tick scan)
+        self.live_slots = 0
+        temperature = self.temperature
+
+        def serve_sample(logits, key):
+            return sample_tokens(logits, temperature=temperature, key=key)
+
+        self._sample_jit = jax.jit(serve_sample)
 
     def _mesh_ctx(self):
         """Ambient-mesh context for jit tracing/execution: the in-model
@@ -157,13 +174,14 @@ class _SchedulerBase:
 
     def submit(self, req: Request) -> None:
         assert 1 <= len(req.prompt) <= self.max_prompt
+        self.obs.instant("sched.submit", rid=req.rid)
         if req.submit_clock < 0:
             req.submit_clock = self.clock
         self.queue.append(req)
 
     @property
     def outstanding(self) -> bool:
-        return bool(self.queue) or any(r is not None for r in self.active)
+        return bool(self.queue) or self.live_slots > 0
 
     def _budget(self, req: Request) -> int:
         # the cache holds prompt + generated tokens: never decode past it
@@ -187,9 +205,18 @@ class _SchedulerBase:
         ready while their chunked prefill is still streaming in.)"""
         return True
 
+    def _occupy(self, i: int, req: Request, prefix_pages: int = 0,
+                fresh_pages: int = 0) -> None:
+        """Admit ``req`` into slot ``i``."""
+        self.active[i] = req
+        self.live_slots += 1
+        self.obs.instant("sched.admitted", rid=req.rid, slot=i,
+                         prefix_pages=prefix_pages, fresh_pages=fresh_pages)
+
     def _free_slot(self, i: int) -> None:
         """Release slot ``i``'s resources after retirement."""
         self.active[i] = None
+        self.live_slots -= 1
 
     def _work_pending(self) -> bool:
         """Non-queue work in flight (e.g. unfinished chunked prefills)
@@ -213,7 +240,7 @@ class _SchedulerBase:
         k = None
         if self.temperature > 0:
             self.key, k = jax.random.split(self.key)
-        return sample_tokens(logits, temperature=self.temperature, key=k)
+        return self._sample_jit(logits, k)
 
     def _emit(self, tok_np) -> int:
         """Append sampled tokens to live requests; retire exhausted ones."""
@@ -235,11 +262,16 @@ class _SchedulerBase:
         """Sample from the held logits, emit/retire, then decode the
         batch one step (skipped when every lane just retired — the
         final tokens need no decode)."""
-        tok = self._sample(self._last_logits)
-        emitted = self._emit(np.asarray(tok)[:, 0])
-        if not any(r is not None for r in self.active):
+        obs = self.obs
+        with obs.span("sched.sample"):
+            tok = self._sample(self._last_logits)
+        with obs.span("sched.readback"):
+            tok_np = np.asarray(tok)[:, 0]
+        with obs.span("sched.emit"):
+            emitted = self._emit(tok_np)
+        if not self.live_slots:
             return emitted
-        with self.obs.span("decode_step", step=self.clock):
+        with obs.span("sched.decode_step", step=self.clock):
             with self._mesh_ctx():
                 self._last_logits, self._cache = self._decode(
                     params, tok, self._cache, self._pos)
@@ -251,19 +283,27 @@ class _SchedulerBase:
             for i, r in enumerate(self.active))
         return emitted
 
-    def _tick(self) -> None:
-        """Advance the step clock + record the slot/queue gauges."""
-        self.clock += 1
-        if self.obs.enabled:
-            self.obs.counter(
-                "scheduler",
-                live_slots=sum(r is not None for r in self.active),
-                queue_depth=len(self.queue),
-                tokens=self.stats.tokens_generated)
+    def counters(self) -> dict:
+        """The ``sched.step`` span's counters, read as the step ends."""
+        return {"queue_depth": len(self.queue),
+                "live_slots": self.live_slots,
+                "decode_steps": self.stats.decode_steps,
+                "prefills": self.stats.prefills}
+
+    def step(self, params) -> int:
+        """One scheduler tick (admission, prefill, one decode step for
+        the live lanes, as the subclass schedules them); returns #tokens
+        emitted."""
+        with self.obs.span("sched.step", counters=self.counters):
+            self.clock += 1
+            return self._step(params)
+
+    def _step(self, params) -> int:
+        raise NotImplementedError
 
     def run(self, params, max_steps: int = 1000) -> SchedulerStats:
         steps = 0
-        with self.obs.span("run", scheduler=type(self).__name__,
+        with self.obs.span("sched.run", scheduler=type(self).__name__,
                            slots=self.slots):
             while self.outstanding and steps < max_steps:
                 if self.step(params) == 0 and not self.queue \
@@ -292,11 +332,13 @@ class BatchScheduler(_SchedulerBase):
             "out_shardings": (sh.logits, sh.cache, sh.pos)}
         jit_kw_dec = {} if sh is None else {
             "out_shardings": (sh.logits, sh.cache)}
-        self._prefill = jax.jit(lambda p, b, l: model.prefill(
-            p, b, dtype=jnp.float32, cache_dtype=cache_dtype,
-            cache_len=max_total, lengths=l), **jit_kw_pf)
-        self._decode = jax.jit(lambda p, t, c, s: model.decode_step(
-            p, t, c, s, dtype=jnp.float32), **jit_kw_dec)
+        def serve_prefill(p, b, l):
+            return model.prefill(p, b, dtype=jnp.float32,
+                                 cache_dtype=cache_dtype,
+                                 cache_len=max_total, lengths=l)
+
+        self._prefill = jax.jit(serve_prefill, **jit_kw_pf)
+        self._decode = jax.jit(_serve_decode(model), **jit_kw_dec)
         self._cache = None
         self._pos = None            # (slots,) per-slot absolute position
         self._last_logits = None
@@ -315,8 +357,8 @@ class BatchScheduler(_SchedulerBase):
             req = self._take_next()
             if req is None:
                 break
-            self.active[i] = req
-        if not any(r is not None for r in self.active):
+            self._occupy(i, req)
+        if not self.live_slots:
             return False
         toks = np.zeros((self.slots, self.max_prompt), np.int32)
         lens = np.zeros((self.slots,), np.int32)
@@ -324,7 +366,7 @@ class BatchScheduler(_SchedulerBase):
             if r is not None:
                 toks[i, : len(r.prompt)] = r.prompt
                 lens[i] = len(r.prompt)
-        with self.obs.span("prefill", wave=self.stats.prefills,
+        with self.obs.span("sched.prefill", wave=self.stats.prefills,
                            requests=int((lens > 0).sum())):
             with self._mesh_ctx():
                 logits, cache, pos = self._prefill(
@@ -336,16 +378,15 @@ class BatchScheduler(_SchedulerBase):
         self.stats.prefills += 1
         return True
 
-    def step(self, params) -> int:
-        """One decode step for all live slots; returns #tokens emitted."""
-        self._tick()
+    def _step(self, params) -> int:
+        """One decode step for all live slots."""
         if self._cache is None:
-            with self.obs.span("admission", step=self.clock):
+            with self.obs.span("sched.admission", step=self.clock):
                 admitted = self._admit(params)
             if not admitted:
                 return 0
         emitted = self._decode_tick(params)
-        if not any(r is not None for r in self.active):
+        if not self.live_slots:
             self._cache = None  # drained -> allow the next admission wave
         return emitted
 
@@ -376,7 +417,7 @@ class ContinuousScheduler(_SchedulerBase):
             self._last_logits = jax.device_put(self._last_logits,
                                                sh.logits)
 
-        def _admit_fn(params, cache, pos, logits, tokens, length, slot):
+        def serve_admit(params, cache, pos, logits, tokens, length, slot):
             lg1, c1, p1 = model.prefill(
                 params, {"tokens": tokens}, dtype=jnp.float32,
                 cache_dtype=cache_dtype, cache_len=max_total,
@@ -391,9 +432,8 @@ class ContinuousScheduler(_SchedulerBase):
             "out_shardings": (sh.cache, sh.pos, sh.logits)}
         jit_kw_dec = {} if sh is None else {
             "out_shardings": (sh.logits, sh.cache)}
-        self._admit_one = jax.jit(_admit_fn, **jit_kw_adm)
-        self._decode = jax.jit(lambda p, t, c, s: model.decode_step(
-            p, t, c, s, dtype=jnp.float32), **jit_kw_dec)
+        self._admit_one = jax.jit(serve_admit, **jit_kw_adm)
+        self._decode = jax.jit(_serve_decode(model), **jit_kw_dec)
 
     # ------------------------------------------------------------------
     def _admit(self, params) -> int:
@@ -406,10 +446,10 @@ class ContinuousScheduler(_SchedulerBase):
             req = self._take_next()
             if req is None:
                 break
-            self.active[i] = req
+            self._occupy(i, req)
             toks = np.zeros((1, self.max_prompt), np.int32)
             toks[0, : len(req.prompt)] = req.prompt
-            with self.obs.span("prefill", slot=i, rid=req.rid):
+            with self.obs.span("sched.prefill", slot=i, rid=req.rid):
                 with self._mesh_ctx():
                     self._cache, self._pos, self._last_logits = \
                         self._admit_one(
@@ -421,12 +461,11 @@ class ContinuousScheduler(_SchedulerBase):
             admitted += 1
         return admitted
 
-    def step(self, params) -> int:
+    def _step(self, params) -> int:
         """Admit into free slots, then one decode step for the batch."""
-        self._tick()
-        with self.obs.span("admission", step=self.clock):
+        with self.obs.span("sched.admission", step=self.clock):
             self._admit(params)
-        if not any(r is not None for r in self.active):
+        if not self.live_slots:
             return 0
         return self._decode_tick(params)
 
@@ -499,11 +538,12 @@ class PagedContinuousScheduler(_SchedulerBase):
         self._dummy = DUMMY_PAGE
         self.table = PageTable(cache_pages, page_size)
         self.trie = PrefixTrie(page_size)
-        # memory-pressure / prefix-sharing counters (benchmarks read
-        # these; obs gauges mirror them per tick)
+        # memory-pressure / prefix-sharing / prefill counters
+        # (benchmarks read these; the step span carries them)
         self.page_deferrals = 0
         self.prefix_pages_hit = 0
         self.prefix_pages_possible = 0
+        self.prefill_chunks = 0
 
         self._page_map = np.full((slots, self.pages_slot), DUMMY_PAGE,
                                  np.int32)
@@ -524,8 +564,8 @@ class PagedContinuousScheduler(_SchedulerBase):
             self._last_logits = jax.device_put(self._last_logits,
                                                sh.logits)
 
-        def _chunk_fn(params, cache, logits, tokens, start, valid, row,
-                      slot):
+        def serve_prefill_chunk(params, cache, logits, tokens, start, valid,
+                                row, slot):
             c1, lg = model.prefill_chunk(
                 params, cache, tokens, start, valid, row, slot,
                 dtype=jnp.float32)
@@ -538,11 +578,13 @@ class PagedContinuousScheduler(_SchedulerBase):
             "out_shardings": (sh.paged_cache, sh.logits)}
         jit_kw_dec = {} if sh is None else {
             "out_shardings": (sh.logits, sh.paged_cache)}
-        self._chunk_jit = jax.jit(_chunk_fn, **jit_kw_ch)
-        self._decode_jit = jax.jit(
-            lambda p, t, c, s, pm, lv: model.decode_step_paged(
-                p, t, c, s, pm, lv, dtype=jnp.float32,
-                use_kernel=use_kernel), **jit_kw_dec)
+        def serve_decode(p, t, c, s, pm, lv):
+            return model.decode_step_paged(p, t, c, s, pm, lv,
+                                           dtype=jnp.float32,
+                                           use_kernel=use_kernel)
+
+        self._chunk_jit = jax.jit(serve_prefill_chunk, **jit_kw_ch)
+        self._decode_jit = jax.jit(serve_decode, **jit_kw_dec)
 
     # -- page planning --------------------------------------------------
     def _plan_pages(self, req: Request, budget: int):
@@ -590,7 +632,7 @@ class PagedContinuousScheduler(_SchedulerBase):
         self._page_map[i] = self._dummy
         self._live[i] = False
         self._jobs.pop(i, None)
-        self.active[i] = None
+        super()._free_slot(i)
 
     def _work_pending(self) -> bool:
         return bool(self._jobs)
@@ -621,7 +663,7 @@ class PagedContinuousScheduler(_SchedulerBase):
             req.budget = budget
             req.admit_clock = self.clock
             req.prefix_pages_reused = len(shared)
-            self.active[i] = req
+            self._occupy(i, req, len(shared), len(fresh))
             pages = shared + fresh
             self._slot_pages[i] = pages
             self._jobs[i] = {
@@ -646,8 +688,8 @@ class PagedContinuousScheduler(_SchedulerBase):
                 valid = min(C, plen - start)
                 toks = np.zeros((1, C), np.int32)
                 toks[0, :valid] = req.prompt[start:start + valid]
-                with self.obs.span("prefill_chunk", slot=slot,
-                                   rid=req.rid, start=start):
+                with self.obs.span("sched.prefill_chunk", slot=slot,
+                                   rid=req.rid, start=start, valid=valid):
                     with self._mesh_ctx():
                         self._cache, self._last_logits = self._chunk_jit(
                             params, self._cache, self._last_logits,
@@ -657,6 +699,7 @@ class PagedContinuousScheduler(_SchedulerBase):
                             jnp.asarray(row),
                             jnp.asarray(slot, jnp.int32))
                 req.prefill_chunks += 1
+                self.prefill_chunks += 1
                 job["start"] = start + valid
                 if job["start"] >= plen:
                     self._page_map[slot] = row
@@ -676,20 +719,20 @@ class PagedContinuousScheduler(_SchedulerBase):
                                 jnp.asarray(self._page_map),
                                 jnp.asarray(self._live))
 
-    def _tick(self) -> None:
-        super()._tick()
-        if self.obs.enabled:
-            self.obs.counter(
-                "pages", free=self.table.num_free,
-                occupancy=self.table.occupancy,
-                prefix_hit_rate=self.prefix_hit_rate,
-                deferrals=self.page_deferrals)
+    def counters(self) -> dict:
+        return {"queue_depth": len(self.queue),
+                "live_slots": self.live_slots,
+                "free_pages": self.table.num_free,
+                "prefix_pages_hit": self.prefix_pages_hit,
+                "prefix_pages_possible": self.prefix_pages_possible,
+                "page_deferrals": self.page_deferrals,
+                "decode_steps": self.stats.decode_steps,
+                "prefill_chunks": self.prefill_chunks}
 
-    def step(self, params) -> int:
+    def _step(self, params) -> int:
         """Admit + advance chunked prefills, then one decode step for
-        the live lanes; returns #tokens emitted."""
-        self._tick()
-        with self.obs.span("admission", step=self.clock):
+        the live lanes."""
+        with self.obs.span("sched.admission", step=self.clock):
             self._admit()
         self._advance_prefills(params)
         if not self._live.any():
@@ -722,7 +765,7 @@ def run_trace(sched, params, arrivals, max_steps: int = 10_000):
     pending = sorted(arrivals, key=lambda a: a[0])
     i = 0
     steps = 0
-    with sched.obs.span("run", scheduler=type(sched).__name__,
+    with sched.obs.span("sched.run", scheduler=type(sched).__name__,
                         driver="trace", requests=len(pending)):
         while (i < len(pending) or sched.outstanding) and \
                 steps < max_steps:

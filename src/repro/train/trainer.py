@@ -63,8 +63,9 @@ class TrainerConfig:
     prefetch: bool = True           # build/transfer interval k+1's
                                     # batch while interval k computes
     # observability (repro.obs, DESIGN.md §13): a trace dir turns on
-    # the span tracer + theory-bound telemetry stream + run manifest;
-    # profile additionally wraps the run in jax.profiler.trace
+    # the Chrome span trace + theory-bound telemetry stream + run
+    # manifest; profile also starts a jax.profiler session (the spans
+    # are annotated for any profiler session, trace dir or not)
     trace_dir: Optional[str] = None
     profile: bool = False
 
@@ -216,6 +217,17 @@ class ScaleTrainer:
         self._train_draws += self.scale.tau
         return batch
 
+    def _prefetch_batch(self):
+        """The prefetch worker's build, as a span on its own thread."""
+        with self.obs.span("train.prefetch"):
+            return self._build_interval_batch()
+
+    def _ledger_counts(self) -> dict:
+        """The ``train.interval`` span's counters."""
+        return {"uplinks": self.ledger.uplinks,
+                "d2d_msgs": self.ledger.d2d_msgs,
+                "local_steps": self.ledger.local_steps}
+
     def _replica0(self):
         """Replica 0's per-replica param pytree (either carrier)."""
         if self._spec is not None:
@@ -315,9 +327,6 @@ class ScaleTrainer:
                      uplinks_by_level=up_lv, d2d_msgs=msgs,
                      d2d_rounds=rounds, d2d_msgs_by_cluster=d2d_cl,
                      event=self.ledger._event_idx)
-        obs.counter("ledger", uplinks=self.ledger.uplinks,
-                    d2d_msgs=self.ledger.d2d_msgs,
-                    local_steps=self.ledger.local_steps)
 
     def _interval(self, batch, kp):
         """ONE interval for every scenario: the resolver supplies the
@@ -329,14 +338,11 @@ class ScaleTrainer:
         ledger_mark = len(self.ledger.events)
         ev = self._resolver.resolve_interval(self.interval, kp)
         args = (self.params, batch, ev.agg, jnp.asarray(self.interval))
-        with obs.span("interval", interval=self.interval,
-                      tau=self.scale.tau):
+        with obs.span("train.dispatch", tau=self.scale.tau):
             if ev.refresh is not None:
                 self.params, loss = self._step(*args, ev.refresh)
             else:
                 self.params, loss = self._step(*args)
-            if obs.enabled:
-                jax.block_until_ready(loss)
         if ev.root_served:
             # a live root event just broadcast the root model to every
             # replica — snapshot it as the served global model
@@ -423,26 +429,34 @@ class ScaleTrainer:
             # interval k+1's batch builds/transfers while k computes;
             # draws are counted HERE per consumed batch, so a mid-run
             # checkpoint never includes the in-flight prefetched batch
-            loader = PrefetchLoader(self._build_interval_batch, depth=1)
+            loader = PrefetchLoader(self._prefetch_batch, depth=1)
         try:
-            with obs.span("run", intervals=n, tau=self.scale.tau,
+            with obs.span("train.run", intervals=n, tau=self.scale.tau,
                           replicas=self.scale.replicas):
                 for _ in range(n):
-                    with obs.span("round", interval=self.interval):
-                        if loader is not None:
-                            batch = loader.get()
-                            self._train_draws += self.scale.tau
-                        else:
-                            batch = self._interval_batch()
-                        self.key, kp = jax.random.split(self.key)
-                        loss = self._interval(batch, kp)
-                        self.interval += 1
-                        logs = {"train_loss": float(loss),
+                    # round = the interval's host work, then eval and
+                    # checkpoint when they fall due
+                    with obs.span("train.round", interval=self.interval):
+                        with obs.span("train.interval",
+                                      counters=self._ledger_counts,
+                                      interval=self.interval):
+                            with obs.span("train.batch"):
+                                if loader is not None:
+                                    batch = loader.get()
+                                    self._train_draws += self.scale.tau
+                                else:
+                                    batch = self._interval_batch()
+                            self.key, kp = jax.random.split(self.key)
+                            loss = self._interval(batch, kp)
+                            self.interval += 1
+                            with obs.span("train.loss_read"):
+                                loss = float(loss)
+                        logs = {"train_loss": loss,
                                 "uplinks": self.ledger.uplinks,
                                 "d2d_msgs": self.ledger.d2d_msgs}
                         if self.tcfg.eval_every and \
                                 self.interval % self.tcfg.eval_every == 0:
-                            with obs.span("eval", interval=self.interval):
+                            with obs.span("train.eval", interval=self.interval):
                                 logs["eval_loss"] = self.evaluate()
                             if obs.enabled:
                                 b = {k: jnp.asarray(v) for k, v in
