@@ -598,7 +598,7 @@ def _paged_scatter(kv, k_new, v_new, flat):
 
 
 def paged_decode_attention(p, cfg, x, cache, pos, page_map, *, window=0,
-                           use_kernel=False, interpret=None):
+                           live=None, use_kernel=False, interpret=None):
     """One-token attention step against a PAGED cache.
 
     x: (B, 1, d); cache: {'k','v'} (num_pages, page_size, K, hd);
@@ -609,7 +609,10 @@ def paged_decode_attention(p, cfg, x, cache, pos, page_map, *, window=0,
     match the ring outputs without wraparound arithmetic.
 
     Returns (out, new_cache). With ``use_kernel`` the gather+softmax
-    runs in the Pallas paged-decode kernel (interpret mode off-TPU).
+    runs in the Pallas paged-decode kernel (interpret mode off-TPU),
+    which walks no page for a lane that ``live`` (B,) marks dead; the
+    gather path ignores ``live``. A dead lane's output is meant to be
+    discarded either way.
     """
     B = x.shape[0]
     q = _project_q(p, cfg, x)
@@ -639,7 +642,7 @@ def paged_decode_attention(p, cfg, x, cache, pos, page_map, *, window=0,
     if use_kernel:
         from repro.kernels.paged_attn import paged_decode
         out = paged_decode(q[:, 0], k_pages, v_pages, page_map, pos,
-                           window=window, interpret=interpret)
+                           window=window, live=live, interpret=interpret)
         out = out[:, None].astype(x.dtype)           # (B, 1, K, G, hd)
     else:
         kg = k_pages[page_map].reshape(B, P * ps, *k_pages.shape[2:])

@@ -1081,7 +1081,7 @@ def decode_step_paged(p, cfg, token, cache, pos, page_map, live, *,
     def attn_dec(lp, xx, c):
         h = apply_norm(cfg, lp["ln_attn"], xx)
         out, c_new = attn.paged_decode_attention(
-            lp["attn"], cfg, h, c, pos, page_map, window=w,
+            lp["attn"], cfg, h, c, pos, page_map, window=w, live=live,
             use_kernel=use_kernel)
         xx = xx + out
         h = apply_norm(cfg, lp["ln_mlp"], xx)

@@ -544,10 +544,17 @@ class PagedContinuousScheduler(_SchedulerBase):
         self.prefix_pages_hit = 0
         self.prefix_pages_possible = 0
         self.prefill_chunks = 0
+        # the paged-decode kernel's walk, one layer's worth, counted from
+        # the host's copy of the positions: blocks fetched, and what a
+        # walk of every block of every slot would fetch
+        self.kv_blocks_walked = 0
+        self.kv_blocks_full = 0
+        self._walk = self._kernel_walk(cfg) if self._has_pages else None
 
         self._page_map = np.full((slots, self.pages_slot), DUMMY_PAGE,
                                  np.int32)
         self._live = np.zeros((slots,), bool)
+        self._pos_host = np.zeros((slots,), np.int64)
         self._slot_pages: list[Optional[list]] = [None] * slots
         self._jobs: dict[int, dict] = {}
 
@@ -585,6 +592,22 @@ class PagedContinuousScheduler(_SchedulerBase):
 
         self._chunk_jit = jax.jit(serve_prefill_chunk, **jit_kw_ch)
         self._decode_jit = jax.jit(serve_decode, **jit_kw_dec)
+
+    def _kernel_walk(self, cfg):
+        """``(window, block_tokens, num_blocks, banded)`` of the kernel's
+        walk (``kernels/paged_attn``), or None without the kernel."""
+        if not self.paged_kernel:
+            return None
+        from repro.kernels import paged_attn, runtime
+        from repro.serving.engine import effective_window
+        P = self.pages_slot
+        if paged_attn.page_grid(cfg.head_dim, runtime.default_interpret()):
+            return 0, self.page_size, P, False
+        w = effective_window(cfg)
+        ppb = paged_attn.block_pages(
+            self.page_size, P, cfg.num_kv_heads, cfg.head_dim,
+            jnp.dtype(self.cache_dtype).itemsize, w)
+        return w, ppb * self.page_size, -(-P // ppb), True
 
     # -- page planning --------------------------------------------------
     def _plan_pages(self, req: Request, budget: int):
@@ -705,6 +728,7 @@ class PagedContinuousScheduler(_SchedulerBase):
                     self._page_map[slot] = row
                     self._live[slot] = True
                     self._pos = self._pos.at[slot].set(plen)
+                    self._pos_host[slot] = plen
                     if self._shareable:
                         self.trie.register(
                             np.asarray(req.prompt),
@@ -715,9 +739,21 @@ class PagedContinuousScheduler(_SchedulerBase):
 
     # -- decode ---------------------------------------------------------
     def _decode(self, params, tok, cache, pos):
-        return self._decode_jit(params, tok, cache, pos,
-                                jnp.asarray(self._page_map),
-                                jnp.asarray(self._live))
+        out = self._decode_jit(params, tok, cache, pos,
+                               jnp.asarray(self._page_map),
+                               jnp.asarray(self._live))
+        if self._walk is not None:
+            from repro.kernels.paged_attn import block_band
+            window, bt, nblk, banded = self._walk
+            walked = self.slots * nblk
+            if banded:
+                walked = int(block_band(
+                    self._pos_host, self._live, window=window,
+                    block_tokens=bt, num_blocks=nblk)[1].sum())
+            self.kv_blocks_walked += walked
+            self.kv_blocks_full += self.slots * nblk
+        self._pos_host += 1
+        return out
 
     def counters(self) -> dict:
         return {"queue_depth": len(self.queue),
@@ -727,7 +763,9 @@ class PagedContinuousScheduler(_SchedulerBase):
                 "prefix_pages_possible": self.prefix_pages_possible,
                 "page_deferrals": self.page_deferrals,
                 "decode_steps": self.stats.decode_steps,
-                "prefill_chunks": self.prefill_chunks}
+                "prefill_chunks": self.prefill_chunks,
+                "kv_blocks_walked": self.kv_blocks_walked,
+                "kv_blocks_full": self.kv_blocks_full}
 
     def _step(self, params) -> int:
         """Admit + advance chunked prefills, then one decode step for

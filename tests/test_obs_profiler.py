@@ -251,6 +251,54 @@ def test_scheduler_step_spans_carry_counters(tmp_path):
     assert sched.live_slots == 0
 
 
+def test_scheduler_step_span_counts_kv_blocks(tmp_path):
+    """kv_blocks_walked / kv_blocks_full on the step span equal a count
+    by hand from the requests' lengths: the kernel (interpret mode) walks
+    the 16-token blocks of each live slot's band, a 16-token window."""
+    import dataclasses
+    from repro.configs import get_arch
+    from repro.kernels.paged_attn import block_pages
+    from repro.models import build_model
+    from repro.serving import PagedContinuousScheduler, Request
+
+    cfg = dataclasses.replace(
+        get_arch("starcoder2-3b").reduced(num_layers=1, d_model=64,
+                                          d_ff=128, vocab_size=128),
+        sliding_window=16)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    ps, max_total, slots = 4, 64, 2
+    sched = PagedContinuousScheduler(
+        model, slots=slots, max_prompt=40, max_total=max_total,
+        page_size=ps, prefill_chunk=8, temperature=0.0, seed=0,
+        paged_kernel=True)
+    P = max_total // ps
+    bt = block_pages(ps, P, cfg.num_kv_heads, cfg.head_dim, 4, 16) * ps
+    assert bt == 16 and P * ps // bt == 4
+    rng = np.random.default_rng(0)
+    reqs = [Request(rid=i, prompt=rng.integers(1, 120, size=n).astype(
+        np.int32), max_new=m) for i, (n, m) in enumerate(
+            [(5, 6), (30, 9), (17, 4), (40, 3)])]
+
+    def body():
+        for r in reqs:
+            sched.submit(r)
+        while sched.outstanding:
+            sched.step(params)
+
+    evs = _profile(tmp_path, body)
+    last = [s for s, _ in evs["repro.sched.step"]][-1]
+    # a request of n tokens out decodes at plen .. plen + n - 2, and its
+    # band [pos - 15, pos] covers blocks (pos - 15) // 16 .. pos // 16
+    want = sum(pos // bt - max(pos - 15, 0) // bt + 1
+               for r in reqs for pos in range(len(r.prompt),
+                                              len(r.prompt) + r.max_new - 1))
+    assert all(r.done and len(r.out_tokens) == r.max_new for r in reqs)
+    assert last["kv_blocks_walked"] == want == sched.kv_blocks_walked
+    assert last["kv_blocks_full"] == slots * 4 * sched.stats.decode_steps
+    assert last["kv_blocks_walked"] <= last["kv_blocks_full"]
+
+
 # ---------------------------------------------------------------------------
 # the trainer loop
 # ---------------------------------------------------------------------------

@@ -188,29 +188,100 @@ def test_chunked_prefill_matches_one_shot():
     assert err <= 1e-5
 
 
-def test_paged_kernel_matches_jnp_gather():
-    from repro.kernels.paged_attn import paged_decode
+def _gather_attention(q, k_pages, v_pages, page_map, pos, window):
+    """The jnp gather path's math: gather + masked softmax."""
+    B, K, _, hd = q.shape
+    P, ps = page_map.shape[1], k_pages.shape[1]
+    kk = k_pages[page_map].reshape(B, P * ps, K, hd)
+    vv = v_pages[page_map].reshape(B, P * ps, K, hd)
+    k_pos = jnp.arange(P * ps)[None, :]
+    ok = k_pos <= pos[:, None]
+    if window:
+        ok &= k_pos > pos[:, None] - window
+    s = jnp.einsum("bkgh,btkh->bkgt", q, kk) / np.sqrt(hd)
+    s = jnp.where(ok[:, None, None, :], s, -1e30)
+    return jnp.einsum("bkgt,btkh->bkgh", jax.nn.softmax(s, -1), vv)
+
+
+# page size 4; pages_per_block 2 (8-token blocks) unless a case says
+# otherwise; page_map None: a shuffled map over pages 1.. of the pool
+KERNEL_CASES = {
+    "seed_no_window": dict(P=3, pos=[5, 9], window=0,
+                           page_map=[[1, 2, 3], [3, 1, 2]]),
+    "seed_window_4": dict(P=3, pos=[5, 9], window=4,
+                          page_map=[[1, 2, 3], [3, 1, 2]]),
+    "pos_0": dict(P=4, pos=[0, 0, 9]),
+    "pos_on_page_edge": dict(P=4, pos=[3, 4, 11, 12]),
+    "pos_on_block_edge": dict(P=6, pos=[7, 8, 15, 16, 23]),
+    "ragged_last_block": dict(P=7, ppb=3, pos=[27, 12, 24, 11]),
+    "window_under_block": dict(P=6, ppb=4, pos=[2, 17, 23, 16], window=3),
+    "window_not_page_multiple": dict(P=6, pos=[5, 13, 22, 23], window=6),
+    "shuffled_page_map": dict(P=8, pos=[31, 0, 17, 25], window=10),
+    "dead_lanes_beside_live": dict(P=5, pos=[13, 19, 7, 4],
+                                   live=[True, False, True, False]),
+    "page_grid": dict(P=3, pos=[5, 9], window=4, grid=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_paged_kernel_matches_jnp_gather(case):
+    """The kernel (interpret mode) against the gather path, walking
+    several blocks per slot."""
+    from repro.kernels.paged_attn import _page_grid_decode, paged_decode
+    c = KERNEL_CASES[case]
+    P, pos = c["P"], c["pos"]
+    B, ps, K, G, hd = len(pos), 4, 2, 2, 8
     rng = np.random.default_rng(2)
-    B, P, ps, K, G, hd = 2, 3, 4, 2, 2, 8
+    num_pages = B * P + 1
     q = jnp.asarray(rng.normal(size=(B, K, G, hd)), jnp.float32)
-    k_pages = jnp.asarray(rng.normal(size=(P + 1, ps, K, hd)), jnp.float32)
-    v_pages = jnp.asarray(rng.normal(size=(P + 1, ps, K, hd)), jnp.float32)
-    page_map = jnp.asarray([[1, 2, 3], [3, 1, 2]], jnp.int32)
-    pos = jnp.asarray([5, 9], jnp.int32)
-    for window in (0, 4):
+    k_pages = jnp.asarray(rng.normal(size=(num_pages, ps, K, hd)),
+                          jnp.float32)
+    v_pages = jnp.asarray(rng.normal(size=(num_pages, ps, K, hd)),
+                          jnp.float32)
+    page_map = c.get("page_map")
+    if page_map is None:
+        page_map = rng.permutation(num_pages - 1).reshape(B, P) + 1
+    live = np.asarray(c.get("live", [True] * B))
+    page_map = np.where(live[:, None], page_map, DUMMY_PAGE)
+    page_map = jnp.asarray(page_map, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    window = c.get("window", 0)
+    if c.get("grid"):
+        out = _page_grid_decode(q, k_pages, v_pages, page_map, pos, window,
+                                interpret=True)
+    else:
         out = paged_decode(q, k_pages, v_pages, page_map, pos,
-                           window=window, interpret=True)
-        # reference: gather + masked softmax
-        kk = k_pages[page_map].reshape(B, P * ps, K, hd)
-        vv = v_pages[page_map].reshape(B, P * ps, K, hd)
-        k_pos = jnp.arange(P * ps)[None, :]
-        ok = k_pos <= pos[:, None]
-        if window:
-            ok &= k_pos > pos[:, None] - window
-        s = jnp.einsum("bkgh,btkh->bkgt", q, kk) / np.sqrt(hd)
-        s = jnp.where(ok[:, None, None, :], s, -1e30)
-        ref = jnp.einsum("bkgt,btkh->bkgh", jax.nn.softmax(s, -1), vv)
-        assert float(jnp.abs(out - ref).max()) <= 1e-5
+                           window=window, live=jnp.asarray(live),
+                           pages_per_block=c.get("ppb", 2), interpret=True)
+    ref = _gather_attention(q, k_pages, v_pages, page_map, pos, window)
+    assert float(jnp.abs(out - ref)[live].max()) <= 1e-5
+    assert bool(jnp.isfinite(out).all())
+    if not live.all():
+        # live lanes come out bit for bit as in a walk where all are live
+        every = paged_decode(q, k_pages, v_pages, page_map, pos,
+                             window=window, pages_per_block=c.get("ppb", 2),
+                             interpret=True)
+        assert np.array_equal(np.asarray(out)[live],
+                              np.asarray(every)[live])
+
+
+def test_block_band_bounds_the_walk():
+    from repro.kernels.paged_attn import block_band, block_pages
+    pos = np.asarray([0, 15, 16, 100, 4095, 4095])
+    live = np.asarray([True, True, True, True, True, False])
+    first, count = block_band(pos, live, window=0, block_tokens=16,
+                              num_blocks=256)
+    assert first.tolist() == [0] * 6
+    assert count.tolist() == [1, 1, 2, 7, 256, 0]
+    first, count = block_band(pos, live, window=20, block_tokens=16,
+                              num_blocks=256)
+    assert first.tolist() == [0, 0, 0, 5, 254, 254]
+    assert count.tolist() == [1, 1, 2, 2, 2, 0]
+    # the code cell's widths: 32 pages of 16 tokens (512) per block
+    assert block_pages(16, 256, 2, 128, 4, 4096) == 32
+    assert block_pages(16, 256, 2, 128, 4, 100) == 7     # the band
+    assert block_pages(16, 3, 2, 128, 4) == 3            # the slot
+    assert block_pages(16, 256, 8, 128, 4) == 16         # VMEM
 
 
 # --------------------------------------------------- cache-dtype plumb
@@ -270,6 +341,25 @@ def test_paged_scheduler_matches_continuous():
     assert len(sched.trie) == 0
     # chunk=8 over up-to-14-token prompts -> some prompts take 2 chunks
     assert any(r.prefill_chunks >= 2 for r in s_paged.records)
+
+
+@pytest.mark.parametrize("family", ["dense", "sliding"])
+def test_paged_scheduler_kernel_matches_gather(family):
+    """The scheduler with the paged-decode kernel (interpret mode, lanes
+    mid-prefill beside decoding ones) emits the gather path's tokens."""
+    arch, _, over = FAMILIES[family]
+    cfg, model, params = _tiny(arch, **over)
+    kw = dict(slots=3, max_prompt=14, max_total=20, temperature=0.0,
+              page_size=4, prefill_chunk=4)
+    out = {}
+    for kernel in (False, True):
+        arrivals = _trace(cfg, np.random.default_rng(5), 6)
+        sched = PagedContinuousScheduler(model, paged_kernel=kernel, **kw)
+        assert run_trace(sched, params, arrivals).requests_done == 6
+        out[kernel] = [r.out_tokens for _, r in arrivals]
+        if kernel:
+            assert 0 < sched.kv_blocks_walked <= sched.kv_blocks_full
+    assert out[True] == out[False]
 
 
 def test_paged_scheduler_prefix_reuse_and_deferral():

@@ -24,6 +24,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 MAMBA = get_arch("mamba2-370m")
 QWEN = get_arch("qwen1.5-0.5b")
+STARCODER = get_arch("starcoder2-3b")
 REPLICAS, CLUSTER = 4, 2        # chip_smoke's TT-HF layout
 
 
@@ -70,6 +71,7 @@ def _compile(fn, sharding, *shapes, donate=()):
     text = jax.jit(fn, donate_argnums=donate).lower(*args).compile() \
         .as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 def test_consensus_mix_compiles(one_chip, flat_len):
@@ -113,14 +115,34 @@ def test_ssd_scan_compiles(one_chip):
         ((BH, T, S), jnp.float32))
 
 
-def test_paged_attn_compiles(one_chip):
+PAGED_ATTN_CASES = {
+    # qwen1.5-0.5b: head dim 64, lane-padded pool rows (page-grid walk)
+    "qwen": dict(B=4, K=QWEN.num_kv_heads,
+                 G=QWEN.num_heads // QWEN.num_kv_heads, hd=64, ps=16,
+                 pages_per_slot=16, window=0),
+    # the code cell (starcoder2-3b): 16 slots of 4,096 tokens, 4k window
+    "code_cell": dict(B=16, K=STARCODER.num_kv_heads,
+                      G=STARCODER.num_heads // STARCODER.num_kv_heads,
+                      hd=STARCODER.head_dim, ps=16, pages_per_slot=256,
+                      window=STARCODER.sliding_window),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_ATTN_CASES))
+def test_paged_attn_compiles(one_chip, case):
     from repro.kernels.paged_attn import paged_decode
-    B, K, hd, ps, pages_per_slot = 4, QWEN.num_kv_heads, 64, 16, 16
-    G = QWEN.num_heads // K
-    num_pages = B * pages_per_slot + 1
-    _compile(lambda q, k, v, pm, pos: paged_decode(
-        q, k, v, pm, pos, interpret=False), one_chip,
-        ((B, K, G, hd), jnp.float32),
-        ((num_pages, ps, K, hd), jnp.float32),
-        ((num_pages, ps, K, hd), jnp.float32),
-        ((B, pages_per_slot), jnp.int32), ((B,), jnp.int32))
+    c = PAGED_ATTN_CASES[case]
+    B, K, G, hd, ps = c["B"], c["K"], c["G"], c["hd"], c["ps"]
+    num_pages = B * c["pages_per_slot"] + 1
+    pool = (num_pages, ps, K, hd)
+    text = _compile(lambda q, k, v, pm, pos: paged_decode(
+        q, k, v, pm, pos, window=c["window"], interpret=False), one_chip,
+        ((B, K, G, hd), jnp.float32), (pool, jnp.float32),
+        (pool, jnp.float32), ((B, c["pages_per_slot"]), jnp.int32),
+        ((B,), jnp.int32))
+    if hd % 128 == 0:
+        # the pools reach the kernel as they are: no copy or relayout
+        shape = "f32[" + ",".join(map(str, pool)) + "]"
+        made = [ln for ln in text.splitlines()
+                if f"= {shape}" in ln and " parameter(" not in ln]
+        assert not made, made
