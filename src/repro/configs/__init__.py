@@ -20,6 +20,7 @@ from repro.configs.recurrentgemma_9b import CONFIG as _recurrentgemma_9b
 from repro.configs.llama4_maverick_400b_a17b import CONFIG as _maverick
 from repro.configs.paligemma_3b import CONFIG as _paligemma_3b
 from repro.configs.granite_3_8b import CONFIG as _granite_3_8b
+from repro.configs.granite_4_0_h_small import CONFIG as _granite_4_0_h_small
 from repro.configs.mamba2_370m import CONFIG as _mamba2_370m
 from repro.configs.starcoder2_3b import CONFIG as _starcoder2_3b
 from repro.configs.qwen15_05b import CONFIG as _qwen15_05b
@@ -34,6 +35,7 @@ ARCHS: dict[str, ModelConfig] = {
         _maverick,
         _paligemma_3b,
         _granite_3_8b,
+        _granite_4_0_h_small,
         _mamba2_370m,
         _starcoder2_3b,
         _qwen15_05b,

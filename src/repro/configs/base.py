@@ -25,7 +25,11 @@ ARCH_KINDS = (
     "dense",      # decoder-only dense transformer
     "moe",        # decoder-only with MoE FFN layers
     "ssm",        # attention-free state space model (Mamba-2 / SSD)
-    "hybrid",     # RG-LRU recurrent blocks + local attention (RecurrentGemma)
+    "hybrid",     # RG-LRU recurrent blocks + local (windowed) attention,
+                  # 2 recurrent : 1 attention (RecurrentGemma)
+    "mamba_hybrid",  # Mamba-2 and global GQA mixers in a published
+                     # ``layer_types`` order, each followed by a routed +
+                     # shared expert FFN (Granite 4.0-H)
     "encdec",     # encoder-decoder (Whisper)
     "vlm",        # vision-language: stub vision frontend + dense decoder
     "audio",      # audio: stub conv frontend + encoder-decoder backbone
@@ -70,6 +74,10 @@ class ModelConfig:
     moe_every: int = 1              # MoE FFN on every k-th layer
     moe_aux_loss_weight: float = 0.01
     moe_capacity_factor: float = 1.25
+    moe_shared_d_ff: int = 0        # shared SwiGLU expert width (0 = none)
+    moe_experts_held: int = 0       # routed experts held here, the first
+                                    # of the router's moe_num_experts
+                                    # (0 = all; mamba_hybrid only)
     # --- SSM (Mamba-2 / SSD) ---
     ssm_state_dim: int = 0
     ssm_num_heads: int = 0          # SSD heads (v-heads)
@@ -77,10 +85,23 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_chunk: int = 256
     ssm_conv_width: int = 4
+    ssm_conv_bias: bool = False     # bias on the depthwise convolutions
+    ssm_gated_norm: bool = False    # rms(y * silu(z)) before out_proj
     # --- hybrid (RG-LRU) ---
     rglru_width: int = 0            # recurrent block width (RG: d_model)
     rglru_conv_width: int = 4
     attention_window: int = 2048    # local attention window for hybrid
+    # --- mamba_hybrid: the mixer of each layer, "mamba" | "attention" ---
+    layer_types: Tuple[str, ...] = ()
+    # --- scales (Granite): x0 = embed * embedding_multiplier; every
+    # residual branch is multiplied by residual_multiplier; logits are
+    # divided by logits_scaling; attention_multiplier is the softmax
+    # scale (0 = head_dim ** -0.5) ---
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: float = 0.0
+    norm_eps: float = 1e-6
     # --- encoder (enc-dec / vlm / audio) ---
     enc_num_layers: int = 0
     enc_seq_len: int = 0            # fixed encoder context (1500 whisper,
@@ -96,6 +117,18 @@ class ModelConfig:
         assert self.kind in ARCH_KINDS, f"unknown kind {self.kind}"
         if self.head_dim == 0 and self.num_heads > 0:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        # a list from a JSON file; kept a tuple so the config hashes
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.kind == "mamba_hybrid":
+            if len(self.layer_types) != self.num_layers or not set(
+                    self.layer_types) <= {"mamba", "attention"}:
+                raise ValueError(
+                    f"{self.name}: layer_types must name 'mamba' or "
+                    f"'attention' for each of the {self.num_layers} layers")
+            if not 0 <= self.moe_experts_held <= self.moe_num_experts:
+                raise ValueError(
+                    f"{self.name}: moe_experts_held must lie in "
+                    f"[0, moe_num_experts={self.moe_num_experts}]")
 
     # -- derived quantities -------------------------------------------------
     @property
@@ -110,6 +143,8 @@ class ModelConfig:
 
     def param_count(self) -> int:
         """Approximate parameter count (used for roofline MODEL_FLOPS)."""
+        if self.kind == "mamba_hybrid":
+            return self._mamba_hybrid_param_count()
         d, f, v, L = self.d_model, self.d_ff, self.vocab_size, self.num_layers
         emb = v * d if self.tie_embeddings else 2 * v * d
         n = emb
@@ -138,6 +173,24 @@ class ModelConfig:
             # stub frontend: encoder layers still counted (backbone spec)
             n += self.enc_num_layers * (attn + gates * d * f)
         return n
+
+    def _mamba_hybrid_param_count(self) -> int:
+        """Parameters held: the routed experts counted are the held ones."""
+        d, S, H = self.d_model, self.ssm_state_dim, self.ssm_num_heads
+        din = self.ssm_expand * d
+        conv = din + 2 * S
+        mamba = d * (2 * din + 2 * S + H) + din * d \
+            + (self.ssm_conv_width + self.ssm_conv_bias) * conv + 3 * H \
+            + din * self.ssm_gated_norm
+        qd = self.num_heads * self.head_dim
+        kd = self.num_kv_heads * self.head_dim
+        attention = 2 * d * qd + 2 * d * kd
+        held = self.moe_experts_held or self.moe_num_experts
+        ffn = d * self.moe_num_experts + 3 * d * (
+            held * self.d_ff + self.moe_shared_d_ff)
+        emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
+        return emb + sum((mamba if t == "mamba" else attention) + ffn
+                         for t in self.layer_types)
 
     def active_param_count(self) -> int:
         """Params active per token (MoE: top_k experts only)."""
@@ -177,6 +230,19 @@ class ModelConfig:
         )
         if self.moe_num_experts:
             changes["moe_num_experts"] = min(num_experts, 4)
+        if self.kind == "mamba_hybrid":
+            # the published ratio of the pattern cannot shrink to a few
+            # layers: one attention layer among Mamba layers
+            types = ["mamba"] * num_layers
+            types[num_layers // 2] = "attention"
+            d_in = self.ssm_expand * d_model
+            changes.update(
+                layer_types=tuple(types), num_kv_heads=max(1, heads // 2),
+                moe_num_experts=num_experts,
+                moe_experts_held=max(1, num_experts // 2),
+                moe_top_k=max(1, min(self.moe_top_k, num_experts // 2)),
+                moe_shared_d_ff=2 * d_ff, ssm_state_dim=32, ssm_head_dim=32,
+                ssm_num_heads=d_in // 32, ssm_chunk=32)
         if self.kind == "ssm":
             d_in = self.ssm_expand * d_model
             changes.update(ssm_state_dim=32, ssm_head_dim=32,

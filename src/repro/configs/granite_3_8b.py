@@ -1,4 +1,4 @@
-"""granite-3-8b [dense]: GQA. [hf:ibm-granite/granite-3.0-2b-base]"""
+"""granite-3-8b [dense]: GQA. [hf:ibm-granite/granite-3.0-8b-base]"""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -14,5 +14,5 @@ CONFIG = ModelConfig(
     rope=True,
     norm="rmsnorm",
     tie_embeddings=True,
-    source="hf:ibm-granite/granite-3.0-2b-base",
+    source="hf:ibm-granite/granite-3.0-8b-base",
 )

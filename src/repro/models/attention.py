@@ -483,6 +483,11 @@ def _project_q(p, cfg, x):
     q = x @ p["wq"].astype(x.dtype)
     if "bq" in p:
         q = q + p["bq"].astype(x.dtype)
+    if cfg.attention_multiplier:
+        # a softmax scale other than head_dim ** -0.5, folded into q so
+        # that every attention path and the paged kernel stay as they are
+        q = q * jnp.asarray(cfg.attention_multiplier * cfg.head_dim ** 0.5,
+                            x.dtype)
     return q.reshape(B, T, cfg.num_kv_heads, cfg.q_per_kv, cfg.head_dim)
 
 

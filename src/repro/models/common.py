@@ -87,7 +87,7 @@ def norm_init(key, cfg, dim: int) -> dict:
 
 def apply_norm(cfg, p: dict, x: jax.Array) -> jax.Array:
     if cfg.norm == "rmsnorm":
-        return rmsnorm(x, p["scale"])
+        return rmsnorm(x, p["scale"], cfg.norm_eps)
     return layernorm(x, p["scale"], p["bias"])
 
 

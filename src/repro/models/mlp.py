@@ -7,9 +7,10 @@ import jax.numpy as jnp
 from repro.models.common import Px, dense_init, zeros_init
 
 
-def init_mlp(key, cfg, d_model: int | None = None) -> dict:
+def init_mlp(key, cfg, d_model: int | None = None,
+             d_ff: int | None = None) -> dict:
     d = d_model or cfg.d_model
-    f = cfg.d_ff
+    f = d_ff or cfg.d_ff
     ks = jax.random.split(key, 3)
     if cfg.mlp_variant in ("swiglu", "geglu"):
         return {

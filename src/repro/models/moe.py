@@ -1,4 +1,10 @@
-"""Top-1 (Switch-style) Mixture-of-Experts FFN.
+"""Mixture-of-Experts FFNs.
+
+``apply_held_moe`` (end of the module) is the dropless top-k layer with
+a shared expert of the ``mamba_hybrid`` kind (Granite 4.0-H): it is
+told which experts it holds, routes over all of them, and computes the
+held experts' part of the result. ``apply_moe``, the ``moe`` kind's
+top-1 (Switch-style) capacity layer:
 
 Dispatch/combine are one-hot EINSUMS over token groups (scatter-free —
 see apply_moe's docstring), giving the *active*-FLOPs formulation
@@ -16,6 +22,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.models import mlp as mlpm
 from repro.models.common import Px, dense_init
 
 
@@ -140,3 +147,63 @@ def apply_moe(p, cfg, x: jax.Array, capacity_factor: float | None = None,
     aux = {"load_balance": lb_loss, "router_z": z_loss,
            "drop_frac": drop_frac}
     return y.reshape(B, T, D), aux
+
+
+# ---------------------------------------------------------------------------
+# held experts, dropless top-k, shared expert (mamba_hybrid)
+# ---------------------------------------------------------------------------
+
+def init_held_moe(key, cfg) -> dict:
+    """The router over all ``moe_num_experts``, the SwiGLU weights of the
+    held experts (``moe_experts_held``, 0 = all) and the shared expert."""
+    d, f, E = cfg.d_model, cfg.d_ff, cfg.moe_num_experts
+    n = cfg.moe_experts_held or E
+    ks = jax.random.split(key, 5)
+    ax = ("experts", "embed_fsdp", "expert_ffn")
+    p = {
+        "router": dense_init(ks[0], (d, E), ("embed", "experts_router")),
+        "w_gate": dense_init(ks[1], (n, d, f), ax, fan_in=d),
+        "w_up": dense_init(ks[2], (n, d, f), ax, fan_in=d),
+        "w_down": dense_init(ks[3], (n, f, d),
+                             ("experts", "expert_ffn", "embed_fsdp"),
+                             fan_in=f),
+    }
+    if cfg.moe_shared_d_ff:
+        p["shared"] = mlpm.init_mlp(ks[4], cfg, d_ff=cfg.moe_shared_d_ff)
+    return p
+
+
+def held_gates(logits, top_k: int, first: int, held: int):
+    """Gates of experts ``first .. first + held - 1`` for router logits
+    (..., E): a softmax over each row's ``top_k`` largest logits, 0 for
+    an expert the row did not select. Each row on its own."""
+    vals, idx = jax.lax.top_k(logits, top_k)
+    w = jax.nn.softmax(vals, axis=-1)                      # (..., k)
+    mine = idx[..., None] == first + jnp.arange(held)      # (..., k, n)
+    return jnp.sum(jnp.where(mine, w[..., None], 0.0), axis=-2)
+
+
+def apply_held_moe(p, cfg, x: jax.Array, first: int = 0) -> jax.Array:
+    """x: (B, T, d) -> (B, T, d): the part of the routed mixture that
+    the held experts (``first`` onward, as many as ``p`` holds) give,
+    plus the shared expert where ``p`` has one.
+
+    Dropless and row-independent: every held expert runs on every token
+    and is weighted by its gate, 0 where the token did not select it,
+    so no token routed to a held expert is dropped at any batch
+    composition and a row's result does not depend on the other rows."""
+    dt = x.dtype
+    with jax.named_scope("moe_router"):
+        logits = jnp.einsum("btd,de->bte", x, p["router"].astype(dt)
+                            ).astype(jnp.float32)
+        gates = held_gates(logits, cfg.moe_top_k, first,
+                           p["w_up"].shape[0]).astype(dt)
+    with jax.named_scope("moe_experts"):
+        g = jnp.einsum("btd,edf->btef", x, p["w_gate"].astype(dt))
+        u = jnp.einsum("btd,edf->btef", x, p["w_up"].astype(dt))
+        h = jax.nn.silu(g) * u * gates[..., None]
+        y = jnp.einsum("btef,efd->btd", h, p["w_down"].astype(dt))
+    if "shared" in p:
+        with jax.named_scope("shared_expert"):
+            y = y + mlpm.apply_mlp(p["shared"], cfg, x)
+    return y

@@ -1,8 +1,9 @@
 """Mamba-2 block (SSD — state-space duality, arXiv:2405.21060).
 
-Layer: in_proj -> [z | x | B | C | dt] ; short causal conv on (x,B,C);
-SSD scan  h_t = exp(dt*A) h_{t-1} + dt * B_t (x) x_t,  y_t = C_t h_t
-+ D*x_t ; gate by silu(z); out_proj.
+Layer: in_proj -> [z | x | B | C | dt] ; short causal conv on (x,B,C)
+(optionally with a bias); SSD scan  h_t = exp(dt*A) h_{t-1} + dt * B_t
+(x) x_t,  y_t = C_t h_t + D*x_t ; gate by silu(z) (optionally followed
+by an RMSNorm over the inner width, Mamba-2's gated norm); out_proj.
 
 Two SSD execution paths:
 * ``chunked jnp`` (default in models): lax.scan over chunks carrying the
@@ -18,7 +19,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import Px, dense_init, ones_init, zeros_init
+from repro.models.common import (Px, dense_init, ones_init, rmsnorm,
+                                 zeros_init)
 
 
 def _dims(cfg):
@@ -35,7 +37,7 @@ def init_ssm(key, cfg) -> dict:
     d_in, H, P, S = _dims(cfg)
     ks = jax.random.split(key, 8)
     conv_k = cfg.ssm_conv_width
-    return {
+    p = {
         "w_in": dense_init(ks[0], (d, 2 * d_in + 2 * S + H),
                            ("embed", "ssm_in")),
         "conv_x": Px(jax.random.normal(ks[1], (conv_k, d_in)) * 0.1,
@@ -51,6 +53,13 @@ def init_ssm(key, cfg) -> dict:
         "w_out": dense_init(ks[4], (d_in, d), ("ssm_in", "embed"),
                             fan_in=d_in),
     }
+    if cfg.ssm_conv_bias:
+        p["conv_x_bias"] = zeros_init((d_in,), ("ssm_in",))
+        p["conv_B_bias"] = zeros_init((S,), ("ssm_state",))
+        p["conv_C_bias"] = zeros_init((S,), ("ssm_state",))
+    if cfg.ssm_gated_norm:
+        p["inner_norm"] = zeros_init((d_in,), ("ssm_in",))
+    return p
 
 
 def _split_proj(cfg, proj):
@@ -60,8 +69,8 @@ def _split_proj(cfg, proj):
     return z, xs, B, C, dt
 
 
-def _causal_conv(x, w, state=None):
-    """Depthwise causal conv. x: (B, T, D); w: (K, D).
+def _causal_conv(x, w, state=None, bias=None):
+    """Depthwise causal conv. x: (B, T, D); w: (K, D); bias: (D,) or None.
 
     state: (B, K-1, D) trailing context for decode; returns (y, new_state).
     """
@@ -73,8 +82,27 @@ def _causal_conv(x, w, state=None):
     xp = jnp.concatenate([pad, x], axis=1)          # (B, T+K-1, D)
     y = sum(xp[:, i:i + x.shape[1]] * w[i].astype(x.dtype)
             for i in range(K))
+    if bias is not None:
+        y = y + bias.astype(x.dtype)
     new_state = xp[:, -(K - 1):] if K > 1 else jnp.zeros_like(x[:, :0])
     return y, new_state
+
+
+def conv3(p, xs, Bm, Cm, states=(None, None, None)):
+    """The depthwise causal convolutions of x, B and C (with their
+    biases where the block has them); returns ((xs, Bm, Cm), states)."""
+    outs = [_causal_conv(v, p[f"conv_{n}"], st, p.get(f"conv_{n}_bias"))
+            for v, n, st in zip((xs, Bm, Cm), "xBC", states)]
+    return tuple(o[0] for o in outs), tuple(o[1] for o in outs)
+
+
+def gate(p, cfg, y, z):
+    """y * silu(z); with the gated inner norm, RMSNorm of that over the
+    inner width (one group), computed in float32."""
+    if "inner_norm" not in p:
+        return y * jax.nn.silu(z)
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return rmsnorm(g, p["inner_norm"], cfg.norm_eps).astype(y.dtype)
 
 
 def ssd_chunked(x, dt, loga, B, C, h0=None, chunk: int = 256):
@@ -152,9 +180,7 @@ def apply_ssm(p, cfg, x, *, use_pallas: bool = False):
 
     proj = x @ p["w_in"].astype(dt_model)
     z, xs, Bm, Cm, dt_raw = _split_proj(cfg, proj)
-    xs, _ = _causal_conv(xs, p["conv_x"])
-    Bm, _ = _causal_conv(Bm, p["conv_B"])
-    Cm, _ = _causal_conv(Cm, p["conv_C"])
+    (xs, Bm, Cm), _ = conv3(p, xs, Bm, Cm)
     xs = jax.nn.silu(xs)
     Bm = jax.nn.silu(Bm)
     Cm = jax.nn.silu(Cm)
@@ -179,7 +205,7 @@ def apply_ssm(p, cfg, x, *, use_pallas: bool = False):
 
     y = y + xh * p["D"].astype(dt_model)[None, None, :, None]
     y = y.reshape(b, T, d_in)
-    y = y * jax.nn.silu(z)
+    y = gate(p, cfg, y, z)
     return y @ p["w_out"].astype(dt_model)
 
 
@@ -215,9 +241,8 @@ def decode_ssm(p, cfg, x, cache):
 
     proj = x @ p["w_in"].astype(dt_model)
     z, xs, Bm, Cm, dt_raw = _split_proj(cfg, proj)
-    xs, cx = _causal_conv(xs, p["conv_x"], cache["conv_x"])
-    Bm, cB = _causal_conv(Bm, p["conv_B"], cache["conv_B"])
-    Cm, cC = _causal_conv(Cm, p["conv_C"], cache["conv_C"])
+    (xs, Bm, Cm), (cx, cB, cC) = conv3(
+        p, xs, Bm, Cm, (cache["conv_x"], cache["conv_B"], cache["conv_C"]))
     xs = jax.nn.silu(xs)[:, 0]                    # (b, d_in)
     Bm = jax.nn.silu(Bm)[:, 0]                    # (b, S)
     Cm = jax.nn.silu(Cm)[:, 0]
@@ -239,7 +264,7 @@ def decode_ssm(p, cfg, x, cache):
     y = jnp.einsum("bs,bhsp->bhp", Cm, h)                      # (b, H, P)
     y = y + xh * p["D"].astype(jnp.float32)[None, :, None]
     y = y.reshape(b, 1, d_in).astype(dt_model)
-    y = y * jax.nn.silu(z)
+    y = gate(p, cfg, y, z)
     new_cache = {"h": h, "conv_x": cx.astype(cache["conv_x"].dtype),
                  "conv_B": cB.astype(cache["conv_B"].dtype),
                  "conv_C": cC.astype(cache["conv_C"].dtype)}

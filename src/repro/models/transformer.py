@@ -11,6 +11,9 @@ Design notes
   repeating unit — so no parameter space is wasted on union layouts.
 * **Caches** are pytrees with the same leading ``layers``/``groups``
   axis, threaded through the scan during decode.
+* **Published layer orders** (``mamba_hybrid``: Mamba-2 and attention
+  mixers as ``cfg.layer_types`` lists them) are a list of per-layer
+  trees, unrolled: the mixers differ in kind, so no stack holds them.
 
 Every init function returns `Px(value, logical_axes)` leaves; the
 registry splits them (`split_tree`) and captures the axes tree during an
@@ -105,6 +108,45 @@ def apply_rec_layer(p, cfg, x):
     return x + mlpm.apply_mlp(p["mlp"], cfg, apply_norm(cfg, p["ln_mlp"], x))
 
 
+def init_mamba_hybrid_layer(key, cfg, layer_type: str) -> dict:
+    """One layer: a Mamba-2 or attention mixer, then the expert FFN."""
+    ks = jax.random.split(key, 3)
+    if layer_type == "mamba":
+        p = {"ln": norm_init(ks[0], cfg, cfg.d_model),
+             "ssm": ssmm.init_ssm(ks[0], cfg)}
+    else:
+        p = {"ln_attn": norm_init(ks[0], cfg, cfg.d_model),
+             "attn": attn.init_attention(ks[0], cfg)}
+    p["ln_mlp"] = norm_init(ks[1], cfg, cfg.d_model)
+    p["moe"] = moem.init_held_moe(ks[2], cfg)
+    return p
+
+
+def hybrid_residual(cfg, x, y):
+    """x + residual_multiplier * y."""
+    return x + y * jnp.asarray(cfg.residual_multiplier, x.dtype)
+
+
+def hybrid_ffn(lp, cfg, x):
+    """The layer's second half: x + r * (experts + shared)(rms(x))."""
+    h = apply_norm(cfg, lp["ln_mlp"], x)
+    return hybrid_residual(cfg, x, moem.apply_held_moe(lp["moe"], cfg, h))
+
+
+def apply_mamba_hybrid_layer(lp, cfg, x, use_pallas=False):
+    """One layer over a whole sequence (no cache)."""
+    if "ssm" in lp:
+        with jax.named_scope("ssm_mixer"):
+            y = ssmm.apply_ssm(lp["ssm"], cfg, apply_norm(cfg, lp["ln"], x),
+                               use_pallas=use_pallas)
+    else:
+        with jax.named_scope("attn_mixer"):
+            y = attn.attention_block(lp["attn"], cfg,
+                                     apply_norm(cfg, lp["ln_attn"], x),
+                                     mode="causal")
+    return hybrid_ffn(lp, cfg, hybrid_residual(cfg, x, y))
+
+
 # ---------------------------------------------------------------------------
 # stack init
 # ---------------------------------------------------------------------------
@@ -196,6 +238,10 @@ def init_model(key, cfg, dtype=jnp.float32) -> dict:
             p["groups"] = _stack(group, ks[3], n_groups)
         if rem:
             p["tail"] = _stack(lambda k: init_rec_layer(k, cfg), ks[4], rem)
+    elif kind == "mamba_hybrid":
+        keys = jax.random.split(ks[3], cfg.num_layers)
+        p["layers"] = [init_mamba_hybrid_layer(k, cfg, t)
+                       for k, t in zip(keys, cfg.layer_types)]
     elif kind in ("encdec", "audio"):
         p["enc_layers"] = _stack(lambda k: init_dense_layer(k, cfg),
                                  ks[3], cfg.enc_num_layers)
@@ -217,6 +263,8 @@ def _embed_tokens(p, cfg, tokens, dtype):
     x = jnp.take(p["embed"].astype(dtype), tokens, axis=0)
     if cfg.scale_embed:
         x = x * jnp.asarray(cfg.d_model ** 0.5, dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, dtype)
     return hint(x, ("pod", "data"), None, None)
 
 
@@ -224,6 +272,8 @@ def _unembed(p, cfg, x):
     from repro.dist.sharding import hint
     w = p["unembed"] if "unembed" in p else p["embed"]
     logits = jnp.einsum("btd,vd->btv", x, w.astype(x.dtype))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     if cfg.logit_softcap:
         c = cfg.logit_softcap
         logits = jnp.tanh(logits / c) * c
@@ -301,6 +351,9 @@ def forward(p, cfg, batch, *, dtype=jnp.bfloat16, remat: bool = True,
             def tail_body(lp, xx):
                 return apply_rec_layer(lp, cfg, xx), None
             x, _ = _scan_layers(tail_body, x, p["tail"], remat)
+    elif kind == "mamba_hybrid":
+        for lp in p["layers"]:
+            x = apply_mamba_hybrid_layer(lp, cfg, x, use_pallas=use_pallas)
     elif kind in ("encdec", "audio"):
         def body(lp, xx):
             return apply_dense_layer(lp, cfg, xx, mode="causal",

@@ -26,7 +26,8 @@ from repro.models import moe as moem
 from repro.models import rglru as rgm
 from repro.models import ssm as ssmm
 from repro.models.common import apply_norm, sinusoidal_positions
-from repro.models.transformer import _embed_tokens, _unembed
+from repro.models.transformer import (_embed_tokens, _unembed, hybrid_ffn,
+                                      hybrid_residual)
 
 
 # ---------------------------------------------------------------------------
@@ -104,6 +105,10 @@ def _init_cache_tree(cfg, batch: int, seq_len: int, dtype=jnp.bfloat16,
     if kind == "ssm":
         return {"layers": stack(
             lambda: ssmm.init_ssm_cache(cfg, batch, dtype), cfg.num_layers)}
+    if kind == "mamba_hybrid":
+        return {"layers": [
+            ssmm.init_ssm_cache(cfg, batch, dtype) if t == "mamba"
+            else _attn_cache(cfg, batch, S, dtype) for t in cfg.layer_types]}
     if kind == "hybrid":
         period = cfg.local_attn_every or 3
         n_groups = cfg.num_layers // period
@@ -150,6 +155,10 @@ def cache_logical_axes_tree(cfg, long_context: bool = False):
         return {"groups": with_layers(g)}
     if kind == "ssm":
         return {"layers": with_layers(ssmm.ssm_cache_logical_axes(cfg))}
+    if kind == "mamba_hybrid":
+        return {"layers": [
+            ssmm.ssm_cache_logical_axes(cfg) if t == "mamba"
+            else attn.cache_logical_axes() for t in cfg.layer_types]}
     if kind == "hybrid":
         period = cfg.local_attn_every or 3
         rem = cfg.num_layers - (cfg.num_layers // period) * period
@@ -225,13 +234,46 @@ def _conv_state_at(x_pre, lengths, K):
 def _prefill_attn_layer(lp, cfg, x, *, mode, window, S, cache_dtype,
                         enc_out=None, prefix_len=None, lengths=None):
     """Dense-family layer forward that also emits its KV cache slice."""
+    T = x.shape[1]
+    out, cache = _prefill_attn_mixer(
+        lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], x), mode=mode,
+        window=window, S=S, cache_dtype=cache_dtype, prefix_len=prefix_len,
+        lengths=lengths)
+    x = x + out
+
+    if enc_out is not None and "cross" in lp:
+        h = apply_norm(cfg, lp["ln_cross"], x)
+        h = attn.attention_block(lp["cross"], cfg, h, mode="full",
+                                 kv_source=enc_out)
+        x = x + h
+
+    h = apply_norm(cfg, lp["ln_mlp"], x)
+    if "moe" in lp:
+        # pad tokens must not consume expert capacity or skew routing
+        tmask = None if lengths is None else \
+            jnp.arange(T)[None, :] < lengths[:, None]
+        h, _ = moem.apply_moe(lp["moe"], cfg, h, token_mask=tmask)
+    else:
+        h = mlpm.apply_mlp(lp["mlp"], cfg, h)
+    x = x + h
+
+    if enc_out is not None and "cross" in lp:
+        ek, ev = attn._project_kv(lp["cross"], cfg, enc_out)
+        cache["cross_k"] = ek.astype(cache_dtype)
+        cache["cross_v"] = ev.astype(cache_dtype)
+    return x, cache
+
+
+def _prefill_attn_mixer(pa, cfg, h, *, mode, window, S, cache_dtype,
+                        prefix_len=None, lengths=None):
+    """The self-attention of ``_prefill_attn_layer`` on its normed input
+    h: returns (output projection, ring-cache K/V)."""
     from repro.models.common import rope as rope_fn
-    B, T, _ = x.shape
-    h = apply_norm(cfg, lp["ln_attn"], x)
+    B, T, _ = h.shape
     # projections (duplicated from attention_block to capture K/V)
     from repro.dist.sharding import hint
-    q = attn._project_q(lp["attn"], cfg, h)
-    k, v = attn._project_kv(lp["attn"], cfg, h)
+    q = attn._project_q(pa, cfg, h)
+    k, v = attn._project_kv(pa, cfg, h)
     q = hint(q, ("pod", "data"), None, "model", None, None)
     k = hint(k, ("pod", "data"), None, "model", None)
     v = hint(v, ("pod", "data"), None, "model", None)
@@ -264,46 +306,27 @@ def _prefill_attn_layer(lp, cfg, x, *, mode, window, S, cache_dtype,
         out = attn.simple_attention(q, k, v, mode=mode, window=window,
                                     prefix_len=prefix_len)
     out = out.reshape(B, T, cfg.num_heads * cfg.head_dim)
-    x = x + out @ lp["attn"]["wo"].astype(x.dtype)
-
-    if enc_out is not None and "cross" in lp:
-        h = apply_norm(cfg, lp["ln_cross"], x)
-        h = attn.attention_block(lp["cross"], cfg, h, mode="full",
-                                 kv_source=enc_out)
-        x = x + h
-
-    h = apply_norm(cfg, lp["ln_mlp"], x)
-    if "moe" in lp:
-        # pad tokens must not consume expert capacity or skew routing
-        tmask = None if lengths is None else \
-            jnp.arange(T)[None, :] < lengths[:, None]
-        h, _ = moem.apply_moe(lp["moe"], cfg, h, token_mask=tmask)
-    else:
-        h = mlpm.apply_mlp(lp["mlp"], cfg, h)
-    x = x + h
-
     ck, cv = _ring_fill(k, v, S, cache_dtype, lengths)
-    cache = {"k": ck, "v": cv}
-    if enc_out is not None and "cross" in lp:
-        ek, ev = attn._project_kv(lp["cross"], cfg, enc_out)
-        cache["cross_k"] = ek.astype(cache_dtype)
-        cache["cross_v"] = ev.astype(cache_dtype)
-    return x, cache
+    return out @ pa["wo"].astype(h.dtype), {"k": ck, "v": cv}
 
 
 def _prefill_ssm_layer(lp, cfg, x, lengths=None):
-    h = apply_norm(cfg, lp["ln"], x)
+    return _prefill_ssm_mixer(lp["ssm"], cfg, apply_norm(cfg, lp["ln"], x),
+                              lengths, residual=lambda y: x + y)
+
+
+def _prefill_ssm_mixer(ps, cfg, h, lengths, *, residual):
+    """The Mamba-2 block of ``_prefill_ssm_layer`` on its normed input h:
+    returns (``residual(output projection)``, its per-row state)."""
     b, T, d = h.shape
     d_in, H, P, S = ssmm._dims(cfg)
-    proj = h @ lp["ssm"]["w_in"].astype(h.dtype)
+    proj = h @ ps["w_in"].astype(h.dtype)
     z, xs, Bm, Cm, dt_raw = ssmm._split_proj(cfg, proj)
     xs_pre, Bm_pre, Cm_pre = xs, Bm, Cm
-    xs, cx = ssmm._causal_conv(xs, lp["ssm"]["conv_x"])
-    Bm, cB = ssmm._causal_conv(Bm, lp["ssm"]["conv_B"])
-    Cm, cC = ssmm._causal_conv(Cm, lp["ssm"]["conv_C"])
+    (xs, Bm, Cm), (cx, cB, cC) = ssmm.conv3(ps, xs, Bm, Cm)
     xs, Bm, Cm = jax.nn.silu(xs), jax.nn.silu(Bm), jax.nn.silu(Cm)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
-                         + lp["ssm"]["dt_bias"].astype(jnp.float32))
+                         + ps["dt_bias"].astype(jnp.float32))
     if lengths is not None:
         # dt = 0 on padded steps freezes the recurrence (decay exp(0)=1,
         # input contribution dt·B·x = 0) so h_fin is each row's state at
@@ -315,13 +338,13 @@ def _prefill_ssm_layer(lp, cfg, x, lengths=None):
         cx = _conv_state_at(xs_pre, lengths, K).astype(cx.dtype)
         cB = _conv_state_at(Bm_pre, lengths, K).astype(cB.dtype)
         cC = _conv_state_at(Cm_pre, lengths, K).astype(cC.dtype)
-    A = -jnp.exp(lp["ssm"]["A_log"].astype(jnp.float32))
+    A = -jnp.exp(ps["A_log"].astype(jnp.float32))
     y, h_fin = ssmm.ssd_chunked(xs.reshape(b, T, H, P), dt, dt * A, Bm, Cm,
                                 chunk=cfg.ssm_chunk)
-    y = y + xs.reshape(b, T, H, P) * lp["ssm"]["D"].astype(
+    y = y + xs.reshape(b, T, H, P) * ps["D"].astype(
         h.dtype)[None, None, :, None]
-    y = y.reshape(b, T, d_in) * jax.nn.silu(z)
-    x = x + y @ lp["ssm"]["w_out"].astype(h.dtype)
+    y = ssmm.gate(ps, cfg, y.reshape(b, T, d_in), z)
+    x = residual(y @ ps["w_out"].astype(h.dtype))
     # conv caches hold the last (K-1) *pre-activation* inputs
     cache = {"h": h_fin, "conv_x": cx, "conv_B": cB, "conv_C": cC}
     return x, cache
@@ -478,6 +501,25 @@ def prefill(p, cfg, batch, *, dtype=jnp.bfloat16, cache_dtype=jnp.bfloat16,
                 return _prefill_rec_layer(lp, cfg, xx, lens_x)
             x, tail_cache = run_stack(x, p["tail"], tail_body)
             cache["tail"] = tail_cache
+    elif kind == "mamba_hybrid":
+        layers = []
+        for lp in p["layers"]:
+            residual = functools.partial(hybrid_residual, cfg, x)
+            if "ssm" in lp:
+                with jax.named_scope("ssm_mixer"):
+                    x, c = _prefill_ssm_mixer(
+                        lp["ssm"], cfg, apply_norm(cfg, lp["ln"], x), lens_x,
+                        residual=residual)
+            else:
+                with jax.named_scope("attn_mixer"):
+                    y, c = _prefill_attn_mixer(
+                        lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], x),
+                        mode=mode, window=window, S=S,
+                        cache_dtype=cache_dtype, lengths=lens_x)
+                x = residual(y)
+            x = hybrid_ffn(lp, cfg, x)
+            layers.append(c)
+        cache = {"layers": layers}
     elif kind in ("encdec", "audio"):
         def body(lp, xx):
             return _prefill_attn_layer(lp, cfg, xx, mode="causal", window=0,
@@ -621,6 +663,22 @@ def decode_step(p, cfg, token, cache, pos, *, dtype=jnp.bfloat16,
                 lambda c, s: tail_body(c, s), x,
                 (p["tail"], cache["tail"]))
             new_cache["tail"] = tail_new
+    elif kind == "mamba_hybrid":
+        layers = []
+        for lp, c in zip(p["layers"], cache["layers"]):
+            if "ssm" in lp:
+                with jax.named_scope("ssm_mixer"):
+                    y, c = ssmm.decode_ssm(
+                        lp["ssm"], cfg, apply_norm(cfg, lp["ln"], x), c)
+            else:
+                with jax.named_scope("attn_mixer"):
+                    ring = w if (c["k"].shape[1] == w and w) else 0
+                    y, c = attn.decode_attention(
+                        lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], x),
+                        c, pos, window=ring)
+            x = hybrid_ffn(lp, cfg, hybrid_residual(cfg, x, y))
+            layers.append(c)
+        new_cache = {"layers": layers}
     elif kind in ("encdec", "audio"):
         def body(xx, scanned):
             lp, c = scanned
@@ -696,7 +754,12 @@ def write_cache_slot(cfg, cache, one_cache, slot, *, pos=None,
 # recurrent state per-slot; chunked prefill + page-map decode
 # ---------------------------------------------------------------------------
 
-PAGED_KINDS = ("dense", "moe", "ssm", "hybrid")
+PAGED_KINDS = ("dense", "moe", "ssm", "hybrid", "mamba_hybrid")
+# kinds whose attention layers keep K/V in the page pool
+PAGE_POOL_KINDS = ("dense", "moe", "hybrid", "mamba_hybrid")
+# kinds whose whole per-request state is K/V, so a prompt prefix's pages
+# can be shared; a recurrent layer's state cannot be borrowed
+PREFIX_SHARING_KINDS = ("dense", "moe")
 
 
 def init_paged_cache_tree(cfg, slots: int, num_pages: int, page_size: int,
@@ -749,6 +812,12 @@ def _init_paged_cache_tree(cfg, slots, num_pages, page_size, dtype):
     if kind == "ssm":
         return {"layers": stack(
             lambda: ssmm.init_ssm_cache(cfg, slots, dtype), cfg.num_layers)}
+    if kind == "mamba_hybrid":
+        # per-slot SSM state for the Mamba layers, a page pool for each
+        # attention layer; one entry per layer, in the published order
+        return {"layers": [
+            ssmm.init_ssm_cache(cfg, slots, dtype) if t == "mamba"
+            else pool() for t in cfg.layer_types]}
     if kind == "hybrid":
         period = cfg.local_attn_every or 3
         n_groups = cfg.num_layers // period
@@ -784,6 +853,10 @@ def paged_cache_logical_axes_tree(cfg):
         return {"groups": with_layers(g)}
     if kind == "ssm":
         return {"layers": with_layers(ssmm.ssm_cache_logical_axes(cfg))}
+    if kind == "mamba_hybrid":
+        return {"layers": [
+            ssmm.ssm_cache_logical_axes(cfg) if t == "mamba" else pool()
+            for t in cfg.layer_types]}
     if kind == "hybrid":
         period = cfg.local_attn_every or 3
         rem = cfg.num_layers - (cfg.num_layers // period) * period
@@ -818,12 +891,30 @@ def _chunk_attn_layer(lp, cfg, x, kv, *, mode, window, start, valid,
     their writes go to the dummy page, their queries never feed the
     cache or the logits, and MoE routing masks them out.
     """
+    h = apply_norm(cfg, lp["ln_attn"], x)
+    out, new_kv = _chunk_attn_mixer(lp["attn"], cfg, h, kv, mode=mode,
+                                    window=window, start=start, valid=valid,
+                                    page_row=page_row)
+    x = x + out
+
+    h = apply_norm(cfg, lp["ln_mlp"], x)
+    if "moe" in lp:
+        tmask = (jnp.arange(x.shape[1]) < valid)[None, :]
+        h, _ = moem.apply_moe(lp["moe"], cfg, h, token_mask=tmask)
+    else:
+        h = mlpm.apply_mlp(lp["mlp"], cfg, h)
+    return x + h, new_kv
+
+
+def _chunk_attn_mixer(pa, cfg, h, kv, *, mode, window, start, valid,
+                      page_row):
+    """The attention of ``_chunk_attn_layer`` on its normed input h:
+    returns (output projection, updated page pools)."""
     from repro.dist.sharding import hint
     from repro.models.common import rope as rope_fn
-    B, C, _ = x.shape
-    h = apply_norm(cfg, lp["ln_attn"], x)
-    q = attn._project_q(lp["attn"], cfg, h)
-    k, v = attn._project_kv(lp["attn"], cfg, h)
+    B, C, _ = h.shape
+    q = attn._project_q(pa, cfg, h)
+    k, v = attn._project_kv(pa, cfg, h)
     q = hint(q, ("pod", "data"), None, "model", None, None)
     k = hint(k, ("pod", "data"), None, "model", None)
     v = hint(v, ("pod", "data"), None, "model", None)
@@ -850,22 +941,23 @@ def _chunk_attn_layer(lp, cfg, x, kv, *, mode, window, start, valid,
                                 mode=mode, window=window, q_offset=start,
                                 k_len=start + valid)
     out = out.reshape(B, C, cfg.num_heads * cfg.head_dim)
-    x = x + out @ lp["attn"]["wo"].astype(x.dtype)
-
-    h = apply_norm(cfg, lp["ln_mlp"], x)
-    if "moe" in lp:
-        h, _ = moem.apply_moe(lp["moe"], cfg, h,
-                              token_mask=(j < valid)[None, :])
-    else:
-        h = mlpm.apply_mlp(lp["mlp"], cfg, h)
-    return x + h, {"k": k_pages, "v": v_pages}
+    return out @ pa["wo"].astype(h.dtype), {"k": k_pages, "v": v_pages}
 
 
 def _chunk_ssm_layer(lp, cfg, x, c, *, slot, start, valid):
     """One SSM layer over a prefill chunk, carrying slot state across
     chunks: conv context + SSD ``h0`` are read from (and written back
     to) the per-slot cache leaves; ``start == 0`` starts fresh."""
-    h = apply_norm(cfg, lp["ln"], x)
+    return _chunk_ssm_mixer(lp["ssm"], cfg, apply_norm(cfg, lp["ln"], x),
+                            c, slot=slot, start=start, valid=valid,
+                            residual=lambda y: x + y)
+
+
+def _chunk_ssm_mixer(ps, cfg, h, c, *, slot, start, valid, residual):
+    """The Mamba-2 block of ``_chunk_ssm_layer`` on its normed input h:
+    returns (``residual(output projection)``, updated per-slot state).
+    The residual add stays before the state writes, where the ``ssm``
+    kind's program has always had it, so that program is unchanged."""
     b, C, _ = h.shape
     d_in, H, P, S = ssmm._dims(cfg)
     K = cfg.ssm_conv_width
@@ -875,26 +967,24 @@ def _chunk_ssm_layer(lp, cfg, x, c, *, slot, start, valid):
     cB0 = jnp.where(fresh, 0.0, _slot_slice(c["conv_B"], slot))
     cC0 = jnp.where(fresh, 0.0, _slot_slice(c["conv_C"], slot))
 
-    proj = h @ lp["ssm"]["w_in"].astype(h.dtype)
+    proj = h @ ps["w_in"].astype(h.dtype)
     z, xs, Bm, Cm, dt_raw = ssmm._split_proj(cfg, proj)
     xs_pre, Bm_pre, Cm_pre = xs, Bm, Cm
-    xs, _ = ssmm._causal_conv(xs, lp["ssm"]["conv_x"], cx0)
-    Bm, _ = ssmm._causal_conv(Bm, lp["ssm"]["conv_B"], cB0)
-    Cm, _ = ssmm._causal_conv(Cm, lp["ssm"]["conv_C"], cC0)
+    (xs, Bm, Cm), _ = ssmm.conv3(ps, xs, Bm, Cm, (cx0, cB0, cC0))
     xs, Bm, Cm = jax.nn.silu(xs), jax.nn.silu(Bm), jax.nn.silu(Cm)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
-                         + lp["ssm"]["dt_bias"].astype(jnp.float32))
+                         + ps["dt_bias"].astype(jnp.float32))
     # dt = 0 freezes the recurrence on pad rows (same trick as the
     # mixed-length one-shot prefill), so h_fin is the state at valid-1
     keep = (jnp.arange(C)[None, :] < valid)[..., None]
     dt = jnp.where(keep, dt, 0.0)
-    A = -jnp.exp(lp["ssm"]["A_log"].astype(jnp.float32))
+    A = -jnp.exp(ps["A_log"].astype(jnp.float32))
     y, h_fin = ssmm.ssd_chunked(xs.reshape(b, C, H, P), dt, dt * A,
                                 Bm, Cm, h0=h0, chunk=cfg.ssm_chunk)
-    y = y + xs.reshape(b, C, H, P) * lp["ssm"]["D"].astype(
+    y = y + xs.reshape(b, C, H, P) * ps["D"].astype(
         h.dtype)[None, None, :, None]
-    y = y.reshape(b, C, d_in) * jax.nn.silu(z)
-    x = x + y @ lp["ssm"]["w_out"].astype(h.dtype)
+    y = ssmm.gate(ps, cfg, y.reshape(b, C, d_in), z)
+    x = residual(y @ ps["w_out"].astype(h.dtype))
 
     def conv_next(state0, pre):
         if K <= 1:
@@ -1039,6 +1129,28 @@ def prefill_chunk(p, cfg, cache, tokens, start, valid, page_row, slot,
                                         start=start, valid=valid)
             x, tnew = scan(x, p["tail"], cache["tail"], tail_body)
             new_cache["tail"] = tnew
+    elif kind == "mamba_hybrid":
+        # pad rows (j >= valid) run through the experts like the others:
+        # the expert layer is row-independent, so they touch no real row
+        layers = []
+        for lp, c in zip(p["layers"], cache["layers"]):
+            residual = functools.partial(hybrid_residual, cfg, x)
+            if "ssm" in lp:
+                with jax.named_scope("ssm_mixer"):
+                    x, c = _chunk_ssm_mixer(
+                        lp["ssm"], cfg, apply_norm(cfg, lp["ln"], x), c,
+                        slot=slot, start=start, valid=valid,
+                        residual=residual)
+            else:
+                with jax.named_scope("attn_mixer"):
+                    y, c = _chunk_attn_mixer(
+                        lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], x),
+                        c, mode=mode, window=window, start=start,
+                        valid=valid, page_row=page_row)
+                x = residual(y)
+            x = hybrid_ffn(lp, cfg, x)
+            layers.append(c)
+        new_cache = {"layers": layers}
     else:
         raise ValueError(kind)
 
@@ -1151,6 +1263,24 @@ def decode_step_paged(p, cfg, token, cache, pos, page_map, live, *,
             x, tnew = jax.lax.scan(tail_body, x,
                                    (p["tail"], cache["tail"]))
             new_cache["tail"] = tnew
+    elif kind == "mamba_hybrid":
+        layers = []
+        for lp, c in zip(p["layers"], cache["layers"]):
+            if "ssm" in lp:
+                with jax.named_scope("ssm_mixer"):
+                    y, c_new = ssmm.decode_ssm(
+                        lp["ssm"], cfg, apply_norm(cfg, lp["ln"], x), c)
+                    c_new = jax.tree.map(
+                        lambda n, o: _gate_live(n, o, live), c_new, c)
+            else:
+                with jax.named_scope("attn_mixer"):
+                    y, c_new = attn.paged_decode_attention(
+                        lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], x),
+                        c, pos, page_map, window=w, live=live,
+                        use_kernel=use_kernel)
+            x = hybrid_ffn(lp, cfg, hybrid_residual(cfg, x, y))
+            layers.append(c_new)
+        new_cache = {"layers": layers}
     else:
         raise ValueError(kind)
 

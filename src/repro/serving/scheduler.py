@@ -533,8 +533,10 @@ class PagedContinuousScheduler(_SchedulerBase):
         self.paged_kernel = paged_kernel
         # page pools only exist for attention-bearing families; pure-SSM
         # archs carry O(1) per-slot state and need zero pages
-        self._has_pages = cfg.kind != "ssm"
-        self._shareable = cfg.kind in ("dense", "moe")
+        from repro.serving.engine import (PAGE_POOL_KINDS,
+                                          PREFIX_SHARING_KINDS)
+        self._has_pages = cfg.kind in PAGE_POOL_KINDS
+        self._shareable = cfg.kind in PREFIX_SHARING_KINDS
         self._dummy = DUMMY_PAGE
         self.table = PageTable(cache_pages, page_size)
         self.trie = PrefixTrie(page_size)
@@ -739,9 +741,12 @@ class PagedContinuousScheduler(_SchedulerBase):
 
     # -- decode ---------------------------------------------------------
     def _decode(self, params, tok, cache, pos):
+        # copies: the host rewrites both arrays in place while the step
+        # may still be queued, and a device array made from a numpy
+        # array can alias it (zero-copy on the CPU)
         out = self._decode_jit(params, tok, cache, pos,
-                               jnp.asarray(self._page_map),
-                               jnp.asarray(self._live))
+                               jnp.asarray(self._page_map.copy()),
+                               jnp.asarray(self._live.copy()))
         if self._walk is not None:
             from repro.kernels.paged_attn import block_band
             window, bt, nblk, banded = self._walk
