@@ -762,6 +762,63 @@ PAGE_POOL_KINDS = ("dense", "moe", "hybrid", "mamba_hybrid")
 PREFIX_SHARING_KINDS = ("dense", "moe")
 
 
+# ---------------------------------------------------------------------------
+# matmul operands: a bf16 copy of the stacked layers' matmul weights
+# ---------------------------------------------------------------------------
+
+# the stacked layer trees, which lax.scan consumes (a list under "layers"
+# holds unrolled per-layer trees: mamba_hybrid)
+STACKED_TREES = ("layers", "groups", "tail")
+# leaves that the layer code uses only as a matmul's right operand,
+# ``x @ w.astype(x.dtype)`` (or the experts' einsums)
+OPERAND_WEIGHTS = frozenset({"wq", "wk", "wv", "wo", "w_up", "w_down",
+                             "w_gate", "w_in", "w_out", "w_gelu", "w_rec"})
+
+
+def map_operands(fn, params):
+    """``params`` with ``fn`` applied to the matmul weights of the
+    stacked layer trees; every other leaf is the same object."""
+    def walk(tree):
+        return {k: fn(v) if k in OPERAND_WEIGHTS and not isinstance(v, dict)
+                else walk(v) if isinstance(v, dict) else v
+                for k, v in tree.items()}
+    return {k: walk(v) if k in STACKED_TREES and isinstance(v, dict) else v
+            for k, v in params.items()}
+
+
+def bf16_operands_exact(params) -> bool:
+    """Do the params' matmuls take bf16 operands already? On a TPU at
+    DEFAULT precision an f32 dot rounds both operands to bf16, so a bf16
+    copy of a weight computes exactly what the f32 weight does; on a CPU,
+    or at a higher precision, it would not."""
+    prec = jax.config.jax_default_matmul_precision
+    if prec is not None and str(prec).lower() not in ("default",
+                                                      "bfloat16"):
+        return False
+    return _on_tpu(params)
+
+
+def _on_tpu(params) -> bool:
+    leaf = jax.tree.leaves(params)[0]
+    devices = leaf.devices() if isinstance(leaf, jax.Array) else ()
+    return bool(devices) and all(d.platform == "tpu" for d in devices)
+
+
+def serve_operands(params):
+    """The tree the serving programs compute with: ``params`` whose
+    stacked matmul weights are cast to bf16 once, where that is exact
+    (:func:`bf16_operands_exact`), else ``params`` itself (also where no
+    layer tree is stacked). A scan over f32 stacks would convert each
+    whole stack on every call.
+    """
+    stacks = [params[k] for k in STACKED_TREES
+              if isinstance(params.get(k), dict)]
+    if not stacks or not bf16_operands_exact(stacks):
+        return params
+    return map_operands(lambda w: w.astype(jnp.bfloat16)
+                        if w.dtype == jnp.float32 else w, params)
+
+
 def init_paged_cache_tree(cfg, slots: int, num_pages: int, page_size: int,
                           dtype=jnp.bfloat16, mesh=None, cache_rules=None):
     """Paged cache pytree: attention K/V leaves become a page pool

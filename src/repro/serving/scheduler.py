@@ -22,6 +22,7 @@ host sync.
 """
 from __future__ import annotations
 
+import weakref
 from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Optional
@@ -159,6 +160,13 @@ class _SchedulerBase:
         # occupied slots, kept up to date at admission and free (the
         # step span's counter; no per-tick scan)
         self.live_slots = 0
+        # the bf16 operand copy step() hands the programs
+        # (engine.serve_operands): the copy, the precision and treedef it
+        # was made for, and weak references to the params' arrays it was
+        # made from
+        self._held = {}
+        self.operand_casts = 0
+        self.operand_bytes = 0
         temperature = self.temperature
 
         def serve_sample(logits, key):
@@ -288,7 +296,40 @@ class _SchedulerBase:
         return {"queue_depth": len(self.queue),
                 "live_slots": self.live_slots,
                 "decode_steps": self.stats.decode_steps,
-                "prefills": self.stats.prefills}
+                "prefills": self.stats.prefills,
+                "operand_casts": self.operand_casts,
+                "operand_bytes": self.operand_bytes}
+
+    def _operands(self, params):
+        """The tree the programs compute with: ``params`` with its
+        stacked matmul weights cast to bf16 where that is exact. The copy
+        is built once for the params' arrays (and matmul precision) and
+        held only while they live: a caller that frees its params frees
+        the copy too, with no reference to them left here."""
+        prec = jax.config.jax_default_matmul_precision
+        held = self._held
+        if held:
+            leaves, tdef = jax.tree.flatten(params)
+            if held["key"] == (prec, tdef) and all(
+                    r() is x for r, x in zip(held["refs"], leaves)):
+                return held["ops"]
+            held.clear()                    # free the old copy first
+        from repro.serving.engine import serve_operands
+        ops = serve_operands(params)
+        self.operand_bytes = 0
+        if ops is params:
+            return params
+        leaves, tdef = jax.tree.flatten(params)
+        copies = [o for o, x in zip(jax.tree.leaves(ops), leaves)
+                  if o is not x]
+        if not copies:
+            return params
+        self.operand_bytes = sum(o.nbytes for o in copies)
+        self.operand_casts += 1
+        held = self._held = {"key": (prec, tdef), "ops": ops}
+        drop = lambda _: held.clear()       # noqa: E731
+        held["refs"] = [weakref.ref(x, drop) for x in leaves]
+        return ops
 
     def step(self, params) -> int:
         """One scheduler tick (admission, prefill, one decode step for
@@ -296,7 +337,7 @@ class _SchedulerBase:
         emitted."""
         with self.obs.span("sched.step", counters=self.counters):
             self.clock += 1
-            return self._step(params)
+            return self._step(self._operands(params))
 
     def _step(self, params) -> int:
         raise NotImplementedError
@@ -770,7 +811,9 @@ class PagedContinuousScheduler(_SchedulerBase):
                 "decode_steps": self.stats.decode_steps,
                 "prefill_chunks": self.prefill_chunks,
                 "kv_blocks_walked": self.kv_blocks_walked,
-                "kv_blocks_full": self.kv_blocks_full}
+                "kv_blocks_full": self.kv_blocks_full,
+                "operand_casts": self.operand_casts,
+                "operand_bytes": self.operand_bytes}
 
     def _step(self, params) -> int:
         """Admit + advance chunked prefills, then one decode step for

@@ -251,6 +251,32 @@ def test_scheduler_step_spans_carry_counters(tmp_path):
     assert sched.live_slots == 0
 
 
+def test_scheduler_step_span_carries_operand_counters(tmp_path,
+                                                      monkeypatch):
+    """The bf16 operand copy (forced by patching the platform check: on a
+    CPU it is exact only when the weights are rounded already) is built
+    once and its bytes are held."""
+    from repro.serving import Request, engine
+    monkeypatch.setattr(engine, "_on_tpu", lambda p: True)
+    sched, params = _paged()
+    rng = np.random.default_rng(1)
+
+    def body():
+        for i in range(2):
+            sched.submit(Request(rid=i, prompt=rng.integers(
+                1, 120, size=10).astype(np.int32), max_new=3))
+        while sched.outstanding:
+            sched.step(params)
+
+    steps = [s for s, _ in _profile(tmp_path, body)["repro.sched.step"]]
+    assert len(steps) > 2
+    want = sum(2 * w.size for k, w in params["layers"]["attn"].items()
+               if k.startswith("w")) + \
+        sum(2 * w.size for w in params["layers"]["mlp"].values())
+    for s in steps:
+        assert (s["operand_casts"], s["operand_bytes"]) == (1, want)
+
+
 def test_scheduler_step_span_counts_kv_blocks(tmp_path):
     """kv_blocks_walked / kv_blocks_full on the step span equal a count
     by hand from the requests' lengths: the kernel (interpret mode) walks

@@ -3,6 +3,7 @@ invariants, paged-vs-ring decode parity for every family, chunked
 prefill == one-shot, Pallas kernel parity, cache-dtype plumbing, and
 scheduler-level equivalence with prefix reuse and zero page leaks."""
 import dataclasses
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -376,3 +377,135 @@ def test_paged_scheduler_prefix_reuse_and_deferral():
     assert reused > 0                    # shared template actually hit
     assert sched.prefix_hit_rate > 0
     assert sched.table.num_free == sched.cache_pages - 1   # no leaks
+
+
+# ------------------------------------------------- bf16 matmul operands
+
+
+def _leaves_with_path(tree):
+    return jax.tree_util.tree_flatten_with_path(tree)[0]
+
+
+def _is_operand(path) -> bool:
+    keys = [getattr(k, "key", None) for k in path]
+    return (keys[0] in engine.STACKED_TREES and all(
+        isinstance(getattr(k, "key", None), str) for k in path)
+        and keys[-1] in engine.OPERAND_WEIGHTS)
+
+
+def _bf16_rounded(params):
+    """``params`` whose stacked matmul weights went to bf16 and back."""
+    return engine.map_operands(
+        lambda w: w.astype(jnp.bfloat16).astype(jnp.float32), params)
+
+
+def _granite_tiny():
+    cfg = get_arch("granite-4.0-h-small").reduced(
+        num_layers=4, d_model=128, d_ff=64, vocab_size=256, num_experts=8)
+    return cfg, build_model(cfg).init(jax.random.PRNGKey(0))
+
+
+def test_operand_tree_is_params_on_cpu_and_at_highest(monkeypatch):
+    _, _, params = _tiny("qwen1.5-0.5b")
+    assert engine.serve_operands(params) is params          # CPU
+    monkeypatch.setattr(engine, "_on_tpu", lambda p: True)
+    assert engine.serve_operands(params) is not params      # TPU, DEFAULT
+    for prec, exact in (("bfloat16", True), ("highest", False),
+                        ("float32", False), ("tensorfloat32", False)):
+        with jax.default_matmul_precision(prec):
+            assert engine.bf16_operands_exact(params) is exact, prec
+            assert (engine.serve_operands(params) is params) is not exact
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES) + ["mamba_hybrid"])
+def test_forced_operands_cast_exactly_the_stacked_matmul_weights(
+        family, monkeypatch):
+    monkeypatch.setattr(engine, "_on_tpu", lambda p: True)
+    if family == "mamba_hybrid":
+        cfg, params = _granite_tiny()
+    else:
+        arch, _, over = FAMILIES[family]
+        cfg, _, params = _tiny(arch, **over)
+    ops = engine.serve_operands(params)
+    assert (jax.tree_util.tree_structure(ops)
+            == jax.tree_util.tree_structure(params))
+    cast = 0
+    for (path, o), (_, p) in zip(_leaves_with_path(ops),
+                                 _leaves_with_path(params)):
+        if _is_operand(path):
+            cast += 1
+            assert o.dtype == jnp.bfloat16 and o.shape == p.shape
+            assert o.shape[0] == jax.tree.leaves(params[path[0].key])[0] \
+                .shape[0]                          # the scanned layer axis
+            assert np.array_equal(np.asarray(o),
+                                  np.asarray(p.astype(jnp.bfloat16)))
+        else:
+            assert o is p, jax.tree_util.keystr(path)
+    # granite's layers are a list of unrolled trees: nothing is stacked
+    assert (cast == 0) == (family == "mamba_hybrid")
+
+
+@pytest.mark.parametrize("tpu", [False, True])
+def test_scheduler_builds_operands_once_per_params(tpu, monkeypatch):
+    # on a CPU the copy is made only where the platform check is patched
+    monkeypatch.setattr(engine, "_on_tpu", lambda p: tpu)
+    cfg, model, params = _tiny("qwen1.5-0.5b")
+    sched = PagedContinuousScheduler(
+        model, slots=2, max_prompt=14, max_total=20, page_size=4,
+        prefill_chunk=8, temperature=0.0)
+    arrivals = _trace(cfg, np.random.default_rng(3), 4)
+    assert run_trace(sched, params, arrivals).requests_done == 4
+    assert sched.stats.decode_steps > 4
+    with monkeypatch.context() as m:
+        m.setattr(engine, "_on_tpu", lambda p: True)
+        held = engine.serve_operands(params)
+    nbytes = sum(o.nbytes for o, p in zip(jax.tree.leaves(held),
+                                          jax.tree.leaves(params))
+                 if o is not p)
+    assert nbytes > 0
+    c = sched.counters()
+    assert (c["operand_casts"], c["operand_bytes"]) == \
+        ((1, nbytes) if tpu else (0, 0))
+    new = jax.tree.map(jnp.copy, params)          # new params arrays
+    sched.submit(Request(rid=9, prompt=np.arange(1, 6, dtype=np.int32),
+                         max_new=3))
+    run_trace(sched, new, [])
+    assert sched.counters()["operand_casts"] == (2 if tpu else 0)
+    assert sched.stats.requests_done == 5
+    # nothing here keeps the caller's params alive: freeing them frees
+    # the copy made from them
+    probe = weakref.ref(new["layers"]["attn"]["wq"])
+    del new
+    assert probe() is None and not sched._held
+
+
+@pytest.mark.parametrize("family", ["dense", "ssm"])
+def test_forced_operands_serve_like_bf16_rounded_weights(family,
+                                                          monkeypatch):
+    """On a CPU an f32 dot is f32, so the operand tree must serve what
+    f32 weights rounded to bf16 beforehand serve, bit for bit: on a TPU
+    the DEFAULT dot rounds the f32 weights the same way. (Two or more
+    slots: XLA's CPU matrix-vector emitter sums a one-row dot in another
+    order when the convert is fused into it.)"""
+    arch, _, over = FAMILIES[family]
+    cfg, model, params = _tiny(arch, **over)
+    runs = {}
+    for force, p in ((True, params), (False, _bf16_rounded(params))):
+        monkeypatch.setattr(engine, "_on_tpu", lambda _, on=force: on)
+        sched = PagedContinuousScheduler(
+            model, slots=3, max_prompt=14, max_total=20, page_size=4,
+            prefill_chunk=8, temperature=0.0)
+        reqs = [r for _, r in _trace(cfg, np.random.default_rng(6), 5)]
+        for r in reqs:
+            sched.submit(r)
+        logits = []
+        while sched.outstanding:
+            sched.step(p)
+            logits.append(np.asarray(sched._last_logits))
+        assert sched.stats.requests_done == 5
+        assert sched.operand_casts == int(force)
+        runs[force] = ([r.out_tokens for r in reqs], logits)
+    assert runs[True][0] == runs[False][0]
+    assert len(runs[True][1]) == len(runs[False][1]) > 5
+    for a, b in zip(runs[True][1], runs[False][1]):
+        assert np.array_equal(a, b)

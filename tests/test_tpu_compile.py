@@ -11,14 +11,19 @@ The topology is described inside a module-scoped fixture (never at
 import): only one process may load the TPU compiler library, and test
 collection must be identical across xdist workers.
 """
+import json
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_arch
+from repro.configs.base import ModelConfig
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -146,3 +151,98 @@ def test_paged_attn_compiles(one_chip, case):
         made = [ln for ln in text.splitlines()
                 if f"= {shape}" in ln and " parameter(" not in ln]
         assert not made, made
+
+
+# --------------------------------------------- serving programs at size
+
+BENCH_CONFIGS = Path(__file__).resolve().parents[1] / "bench" / "configs"
+# the serving cells: configuration, slots, max_total (page 16, chunk 256)
+SERVE_CELLS = {"code": ("starcoder2-3b-18l", 16, 4096),
+               "chat": ("mamba2-370m", 32, 768)}
+
+
+def _cell_model(name):
+    from repro.models import build_model
+    model = json.loads((BENCH_CONFIGS / f"{name}.json").read_text())["model"]
+    return build_model(ModelConfig(**model))
+
+
+def _serve_program(model, program, slots, max_total, sharding):
+    """(fn, abstract non-param args) of the paged scheduler's jitted
+    ``serve_decode`` or ``serve_prefill_chunk`` at a cell's shapes."""
+    from repro.serving import pages_per_slot
+    ps, C = 16, 256
+    P = pages_per_slot(max_total, ps)
+    cache = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        model.abstract_paged_cache(slots, slots * P + 1, ps, jnp.float32))
+    arg = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sharding)  # noqa: E731
+    if program == "decode":
+        def fn(p, t, c, s, pm, lv):
+            return model.decode_step_paged(p, t, c, s, pm, lv,
+                                           dtype=jnp.float32,
+                                           use_kernel=True)
+        return fn, (arg(jnp.int32, slots, 1), cache, arg(jnp.int32, slots),
+                    arg(jnp.int32, slots, P), arg(bool, slots))
+
+    def fn(p, c, lg, tokens, start, valid, row, slot):
+        c1, lg1 = model.prefill_chunk(p, c, tokens, start, valid, row, slot,
+                                      dtype=jnp.float32)
+        return c1, jax.lax.dynamic_update_slice(lg, lg1, (slot, 0, 0))
+    return fn, (cache, arg(jnp.float32, slots, 1, model.cfg.padded_vocab),
+                arg(jnp.int32, 1, C), arg(jnp.int32), arg(jnp.int32),
+                arg(jnp.int32, P), arg(jnp.int32))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("cell", sorted(SERVE_CELLS))
+def test_serving_operands_take_the_weight_converts_out(one_chip, cell,
+                                                       program):
+    """With the stacked matmul weights handed in as bf16 (the serving
+    operand tree), no program converts a weight stack on each call, and
+    the temporary that held the converted stacks goes."""
+    from repro.serving import engine
+    name, slots, max_total = SERVE_CELLS[cell]
+    model = _cell_model(name)
+    f32 = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        model.abstract_params()[0])
+    ops = engine.map_operands(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip), f32)
+    stacks = [a.shape for a, b in zip(jax.tree.leaves(ops),
+                                      jax.tree.leaves(f32)) if a is not b]
+    copy_bytes = sum(2 * int(np.prod(s)) for s in stacks)
+    # a convert producing a whole stack, or one layer of it
+    shapes = {s[i:] for s in stacks for i in (0, 1)} | \
+        {(1,) + s[1:] for s in stacks}
+    pat = re.compile(r"= \w+\[([\d,]+)\][^ ]* convert\(")
+
+    def converted(text):
+        return [ln.strip()[:120] for ln in text.splitlines()
+                if (m := pat.search(ln))
+                and tuple(map(int, m.group(1).split(","))) in shapes]
+
+    fn, args = _serve_program(model, program, slots, max_total, one_chip)
+    temp = {}
+    for side, params in (("f32", f32), ("ops", ops)):
+        exe = jax.jit(fn).lower(params, *args).compile()
+        temp[side] = exe.memory_analysis().temp_size_in_bytes
+        found = converted(exe.as_text())
+        assert bool(found) == (side == "f32"), (side, found)
+    # the held copy replaces the per-call temporary: the decode programs
+    # shed at least its size; the chunk programs 95% of it (the compiler
+    # places a few other buffers differently)
+    assert temp["f32"] - temp["ops"] >= 0.95 * copy_bytes, \
+        (temp, copy_bytes)
+
+
+def test_doc_chat_operands_are_its_params(monkeypatch):
+    """granite-4.0-h-small's layers are unrolled per-layer trees, not a
+    scanned stack: the operand tree holds no copy, even on a TPU."""
+    from repro.serving import engine
+    monkeypatch.setattr(engine, "_on_tpu", lambda p: True)
+    params = _cell_model("granite-4.0-h-small-10l").abstract_params()[0]
+    ops = engine.serve_operands(params)
+    assert all(o is p for o, p in zip(jax.tree.leaves(ops),
+                                      jax.tree.leaves(params)))
