@@ -208,7 +208,8 @@ class ModelConfig:
         """Hybrid models: which layers are (local) attention layers."""
         if self.kind != "hybrid":
             return True
-        p = self.local_attn_every or 3
+        from repro.models.layout import hybrid_period
+        p = hybrid_period(self)
         return layer % p == p - 1  # RG: 2 recurrent : 1 attention
 
     def reduced(self, *, num_layers: int = 2, d_model: int = 256,

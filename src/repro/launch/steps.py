@@ -16,6 +16,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import InputShape, ModelConfig
 from repro.dist.sharding import ShardingRules
+from repro.models import layout
 from repro.models.registry import ModelApi
 from repro.optim import make_optimizer, apply_updates
 from repro.optim.schedules import constant
@@ -154,13 +155,8 @@ def accum_steps_for(cfg: ModelConfig, shape: InputShape, mesh: Mesh,
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     repl = sizes.get("pod", 1) * sizes.get("data", 1)
     b_local = max(shape.global_batch // repl, 1)
-    n_saves = cfg.num_layers
-    if cfg.kind == "moe" and cfg.moe_every > 1:
-        n_saves = cfg.num_layers // cfg.moe_every
-    if cfg.kind == "hybrid":
-        n_saves = cfg.num_layers // (cfg.local_attn_every or 3) + 2
-    if cfg.enc_num_layers:
-        n_saves += cfg.enc_num_layers
+    n_saves = layout.depth(layout.encoder_layout(cfg)
+                           + layout.layer_layout(cfg))
     stack = n_saves * b_local * shape.seq_len * cfg.d_model * 2
     a = 1
     while stack / a > budget_bytes and a < b_local:
@@ -307,7 +303,7 @@ def build_serve_program(model: ModelApi, mesh: Mesh, *,
     """
     from repro.serving import serve_shardings
     cfg = model.cfg
-    if cfg.kind in ("vlm", "encdec", "audio"):
+    if cfg.kind in layout.FRONTEND_KINDS:
         raise ValueError(
             f"serve program is token-only; arch kind {cfg.kind!r} needs "
             "frontend inputs the request path does not carry")
@@ -382,7 +378,7 @@ def build_paged_serve_program(model: ModelApi, mesh: Mesh, *,
     """
     from repro.serving import pages_per_slot, serve_shardings
     cfg = model.cfg
-    if cfg.kind in ("vlm", "encdec", "audio"):
+    if cfg.kind in layout.FRONTEND_KINDS:
         raise ValueError(
             f"serve program is token-only; arch kind {cfg.kind!r} needs "
             "frontend inputs the request path does not carry")

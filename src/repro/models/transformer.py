@@ -14,6 +14,13 @@ Design notes
 * **Published layer orders** (``mamba_hybrid``: Mamba-2 and attention
   mixers as ``cfg.layer_types`` lists them) are a list of per-layer
   trees, unrolled: the mixers differ in kind, so no stack holds them.
+* **One layout per kind.** Which stacks a kind has, what one body of
+  each holds and in what order is described once, by
+  ``models/layout.layer_layout``. ``init_model`` builds the params from
+  it, ``forward`` walks it (``layout.run``), and so do the serving
+  engine's cache builders and its four phases. Adding a kind is one
+  branch there plus a body per new layer role in each phase; this
+  module then needs an init and a forward body per role.
 
 Every init function returns `Px(value, logical_axes)` leaves; the
 registry splits them (`split_tree`) and captures the axes tree during an
@@ -21,13 +28,13 @@ registry splits them (`split_tree`) and captures the axes tree during an
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Callable, NamedTuple, Optional
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 
 from repro.models import attention as attn
+from repro.models import layout
 from repro.models import mlp as mlpm
 from repro.models import moe as moem
 from repro.models import rglru as rgm
@@ -170,22 +177,20 @@ def _stack(init_one: Callable, key, n: int):
                         is_leaf=lambda x: not isinstance(x, (dict,)))
 
 
-def _scan_layers(body: Callable, x, stacked_params, remat: bool,
-                 with_aux: bool = False):
-    """Run ``body(layer_params, x) -> (x, aux)`` over the layer stack."""
-    fn = jax.checkpoint(body) if remat else body
-
-    def step(carry, lp):
-        y, aux = fn(lp, carry)
-        return y, aux
-
-    x, auxs = jax.lax.scan(step, x, stacked_params)
-    return (x, auxs) if with_aux else (x, None)
-
-
 # ---------------------------------------------------------------------------
 # the model: init
 # ---------------------------------------------------------------------------
+
+def _init_layer(role: str, key, cfg) -> dict:
+    if role == "ssm":
+        return init_ssm_layer(key, cfg)
+    if role == "rec":
+        return init_rec_layer(key, cfg)
+    if role in ("mamba", "attention"):
+        return init_mamba_hybrid_layer(key, cfg, role)
+    return init_dense_layer(key, cfg, use_moe=role == "moe",
+                            cross=role == "cross")
+
 
 def init_model(key, cfg, dtype=jnp.float32) -> dict:
     """Full parameter tree (Px leaves) for any arch kind."""
@@ -201,56 +206,15 @@ def init_model(key, cfg, dtype=jnp.float32) -> dict:
     if not cfg.tie_embeddings:
         p["unembed"] = embed_init(ks[2], V, cfg.d_model,
                                   ("vocab", "embed_nomodel"))
-
-    kind = cfg.kind
-    if kind in ("dense", "vlm"):
-        p["layers"] = _stack(lambda k: init_dense_layer(k, cfg),
-                             ks[3], cfg.num_layers)
-    elif kind == "moe":
-        if cfg.moe_every == 1:
-            p["layers"] = _stack(
-                lambda k: init_dense_layer(k, cfg, use_moe=True),
-                ks[3], cfg.num_layers)
-        else:
-            n_groups = cfg.num_layers // cfg.moe_every
-            def group(k):
-                kk = jax.random.split(k, cfg.moe_every)
-                g = {f"dense_{i}": init_dense_layer(kk[i], cfg)
-                     for i in range(cfg.moe_every - 1)}
-                g["moe"] = init_dense_layer(kk[-1], cfg, use_moe=True)
-                return g
-            p["groups"] = _stack(group, ks[3], n_groups)
-    elif kind == "ssm":
-        p["layers"] = _stack(lambda k: init_ssm_layer(k, cfg),
-                             ks[3], cfg.num_layers)
-    elif kind == "hybrid":
-        period = cfg.local_attn_every or 3
-        n_groups = cfg.num_layers // period
-        rem = cfg.num_layers - n_groups * period
-
-        def group(k):
-            kk = jax.random.split(k, period)
-            g = {f"rec_{i}": init_rec_layer(kk[i], cfg)
-                 for i in range(period - 1)}
-            g["attn"] = init_dense_layer(kk[-1], cfg)
-            return g
-        if n_groups:
-            p["groups"] = _stack(group, ks[3], n_groups)
-        if rem:
-            p["tail"] = _stack(lambda k: init_rec_layer(k, cfg), ks[4], rem)
-    elif kind == "mamba_hybrid":
-        keys = jax.random.split(ks[3], cfg.num_layers)
-        p["layers"] = [init_mamba_hybrid_layer(k, cfg, t)
-                       for k, t in zip(keys, cfg.layer_types)]
-    elif kind in ("encdec", "audio"):
-        p["enc_layers"] = _stack(lambda k: init_dense_layer(k, cfg),
-                                 ks[3], cfg.enc_num_layers)
+    segs = layout.encoder_layout(cfg) + layout.layer_layout(cfg)
+    # the stacks draw ks[3], ks[4] in order; a hybrid's tail draws ks[4]
+    # even where it has no groups before it
+    keys = [ks[4] if s.key == "tail" else ks[3 + i]
+            for i, s in enumerate(segs)]
+    p.update(layout.build(segs, lambda role, k: _init_layer(role, k, cfg),
+                          _stack, keys))
+    if layout.encoder_layout(cfg):
         p["enc_ln_final"] = norm_init(ks[5], cfg, cfg.d_model)
-        p["layers"] = _stack(
-            lambda k: init_dense_layer(k, cfg, cross=True),
-            ks[4], cfg.num_layers)
-    else:
-        raise ValueError(kind)
     return p
 
 
@@ -312,64 +276,49 @@ def forward(p, cfg, batch, *, dtype=jnp.bfloat16, remat: bool = True,
         def enc_body(lp, hh):
             y, _ = apply_dense_layer(lp, cfg, hh, mode="full")
             return y, None
-        h, _ = _scan_layers(enc_body, h, p["enc_layers"], remat)
+        h, _ = layout.run(layout.encoder_layout(cfg), p, h,
+                          {"enc": enc_body}, remat=remat)
         enc_out = apply_norm(cfg, p["enc_ln_final"], h)
         if not cfg.rope:
             dpos = sinusoidal_positions(T, cfg.d_model).astype(dtype)
             x = x + dpos[None]
 
-    aux = None
-    if kind in ("dense", "vlm") or (kind == "moe" and cfg.moe_every == 1):
-        def body(lp, xx):
-            return apply_dense_layer(lp, cfg, xx, mode=mode, window=window,
-                                     prefix_len=prefix_len)
-        x, aux = _scan_layers(body, x, p["layers"], remat, with_aux=True)
-    elif kind == "moe":
-        def body(lp, xx):
-            for i in range(cfg.moe_every - 1):
-                xx, _ = apply_dense_layer(lp[f"dense_{i}"], cfg, xx,
-                                          mode=mode, window=window)
-            xx, a = apply_dense_layer(lp["moe"], cfg, xx, mode=mode,
-                                      window=window)
-            return xx, a
-        x, aux = _scan_layers(body, x, p["groups"], remat, with_aux=True)
-    elif kind == "ssm":
-        def body(lp, xx):
-            return apply_ssm_layer(lp, cfg, xx, use_pallas=use_pallas), None
-        x, _ = _scan_layers(body, x, p["layers"], remat)
-    elif kind == "hybrid":
-        period = cfg.local_attn_every or 3
-        def body(lp, xx):
-            for i in range(period - 1):
-                xx = apply_rec_layer(lp[f"rec_{i}"], cfg, xx)
-            xx, _ = apply_dense_layer(lp["attn"], cfg, xx, mode="sliding",
-                                      window=cfg.attention_window)
-            return xx, None
-        if "groups" in p:
-            x, _ = _scan_layers(body, x, p["groups"], remat)
-        if "tail" in p:
-            def tail_body(lp, xx):
-                return apply_rec_layer(lp, cfg, xx), None
-            x, _ = _scan_layers(tail_body, x, p["tail"], remat)
-    elif kind == "mamba_hybrid":
-        for lp in p["layers"]:
-            x = apply_mamba_hybrid_layer(lp, cfg, x, use_pallas=use_pallas)
-    elif kind in ("encdec", "audio"):
-        def body(lp, xx):
-            return apply_dense_layer(lp, cfg, xx, mode="causal",
-                                     enc_out=enc_out)
-        x, _ = _scan_layers(body, x, p["layers"], remat)
-    else:
-        raise ValueError(kind)
+    def attn_body(lp, xx):
+        return apply_dense_layer(lp, cfg, xx, mode=mode, window=window,
+                                 prefix_len=prefix_len)
+
+    def local_attn_body(lp, xx):
+        return apply_dense_layer(lp, cfg, xx, mode="sliding",
+                                 window=cfg.attention_window)
+
+    def cross_body(lp, xx):
+        return apply_dense_layer(lp, cfg, xx, mode="causal", enc_out=enc_out)
+
+    def mamba_hybrid_body(lp, xx):
+        return apply_mamba_hybrid_layer(lp, cfg, xx,
+                                        use_pallas=use_pallas), None
+
+    bodies = {
+        "attn": attn_body, "moe": attn_body, "local_attn": local_attn_body,
+        "cross": cross_body,
+        "ssm": lambda lp, xx: (apply_ssm_layer(lp, cfg, xx,
+                                               use_pallas=use_pallas), None),
+        "rec": lambda lp, xx: (apply_rec_layer(lp, cfg, xx), None),
+        "mamba": mamba_hybrid_body, "attention": mamba_hybrid_body,
+    }
+    x, outs = layout.run(layout.layer_layout(cfg), p, x, bodies, remat=remat)
+    # the MoE layers' aux losses: the only non-None layer outputs
+    aux = jax.tree.leaves(outs, is_leaf=lambda a: isinstance(a, dict)
+                          and "load_balance" in a)
 
     x = apply_norm(cfg, p["ln_final"], x)
     if kind == "vlm":
         x = x[:, cfg.enc_seq_len:]          # predict text positions only
     logits = _unembed(p, cfg, x)
     aux_losses = {}
-    if aux is not None and isinstance(aux, dict) and "load_balance" in aux:
-        aux_losses["load_balance"] = jnp.mean(aux["load_balance"])
-        aux_losses["router_z"] = jnp.mean(aux["router_z"])
+    if aux:
+        aux_losses["load_balance"] = jnp.mean(aux[0]["load_balance"])
+        aux_losses["router_z"] = jnp.mean(aux[0]["router_z"])
     return logits, aux_losses
 
 

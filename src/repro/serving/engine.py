@@ -1,10 +1,12 @@
 """Serving runtime: KV/state caches, prefill, and single-token decode
 for every arch family.
 
-Cache layout: one pytree per model whose leaves carry a leading
-``layers`` (or ``groups``) axis, threaded through ``lax.scan`` together
-with the layer parameters — the decode step is a single compact HLO
-program regardless of depth.
+Cache layout: one pytree per model with the params' layer trees
+(``models/layout.py``): scanned stacks' leaves carry a leading
+``layers`` axis and are threaded through ``lax.scan`` together with the
+layer parameters — the decode step is a single compact HLO program
+regardless of depth. Every phase walks the layout with ``layout.run``
+and its own per-role layer bodies.
 
 Sliding-window archs (and the *sliding-window serving variant* used for
 ``long_500k`` on full-attention archs) keep a **ring buffer** of
@@ -21,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import attention as attn
+from repro.models import layout
 from repro.models import mlp as mlpm
 from repro.models import moe as moem
 from repro.models import rglru as rgm
@@ -33,10 +36,6 @@ from repro.models.transformer import (_embed_tokens, _unembed, hybrid_ffn,
 # ---------------------------------------------------------------------------
 # cache construction
 # ---------------------------------------------------------------------------
-
-def _attn_cache(cfg, batch, S, dtype):
-    return attn.init_cache(cfg, batch, S, dtype)
-
 
 def effective_window(cfg, serve_window: int = 0) -> int:
     """The serving attention window: the arch's own sliding window, the
@@ -81,102 +80,66 @@ def init_cache_tree(cfg, batch: int, seq_len: int, dtype=jnp.bfloat16,
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+def _stack_cache(body, _key, n):
+    """``n`` copies of one body's cache on a leading ``layers`` axis."""
+    return jax.tree.map(
+        lambda l: jnp.broadcast_to(l[None], (n,) + l.shape), body(None))
+
+
+def _stack_axes(body, _key, n):
+    return jax.tree.map(lambda a: ("layers",) + tuple(a), body(None),
+                        is_leaf=lambda x: isinstance(x, tuple))
+
+
+def _cache_maker(cfg, batch, dtype, attn_cache):
+    """``make(role, key)`` of a cache tree: per-slot recurrent state for
+    the recurrent roles, ``attn_cache(role)`` for the attention ones."""
+    def make(role, _key):
+        if role in ("ssm", "mamba"):
+            return ssmm.init_ssm_cache(cfg, batch, dtype)
+        if role == "rec":
+            return rgm.init_rglru_cache(cfg, batch, dtype)
+        return attn_cache(role)
+    return make
+
+
+def _axes_maker(cfg, attn_axes):
+    """The logical axes of :func:`_cache_maker`'s trees."""
+    def make(role, _key):
+        if role in ("ssm", "mamba"):
+            return ssmm.ssm_cache_logical_axes(cfg)
+        if role == "rec":
+            return rgm.rglru_cache_logical_axes(cfg)
+        return attn_axes(role)
+    return make
+
+
 def _init_cache_tree(cfg, batch: int, seq_len: int, dtype=jnp.bfloat16,
                      serve_window: int = 0):
-    kind = cfg.kind
     S = cache_len_for(cfg, seq_len, serve_window)
 
-    def stack(make_one, n):
-        one = make_one()
-        return jax.tree.map(
-            lambda l: jnp.broadcast_to(l[None], (n,) + l.shape), one)
-
-    if kind in ("dense", "vlm") or (kind == "moe" and cfg.moe_every == 1):
-        return {"layers": stack(lambda: _attn_cache(cfg, batch, S, dtype),
-                                cfg.num_layers)}
-    if kind == "moe":
-        n_groups = cfg.num_layers // cfg.moe_every
-        def group():
-            g = {f"dense_{i}": _attn_cache(cfg, batch, S, dtype)
-                 for i in range(cfg.moe_every - 1)}
-            g["moe"] = _attn_cache(cfg, batch, S, dtype)
-            return g
-        return {"groups": stack(group, n_groups)}
-    if kind == "ssm":
-        return {"layers": stack(
-            lambda: ssmm.init_ssm_cache(cfg, batch, dtype), cfg.num_layers)}
-    if kind == "mamba_hybrid":
-        return {"layers": [
-            ssmm.init_ssm_cache(cfg, batch, dtype) if t == "mamba"
-            else _attn_cache(cfg, batch, S, dtype) for t in cfg.layer_types]}
-    if kind == "hybrid":
-        period = cfg.local_attn_every or 3
-        n_groups = cfg.num_layers // period
-        rem = cfg.num_layers - n_groups * period
-        def group():
-            g = {f"rec_{i}": rgm.init_rglru_cache(cfg, batch, dtype)
-                 for i in range(period - 1)}
-            g["attn"] = _attn_cache(cfg, batch, S, dtype)
-            return g
-        out = {}
-        if n_groups:
-            out["groups"] = stack(group, n_groups)
-        if rem:
-            out["tail"] = stack(
-                lambda: rgm.init_rglru_cache(cfg, batch, dtype), rem)
-        return out
-    if kind in ("encdec", "audio"):
-        def dec_layer():
-            c = _attn_cache(cfg, batch, S, dtype)
+    def attn_cache(role):
+        c = attn.init_cache(cfg, batch, S, dtype)
+        if role == "cross":
             K, hd = cfg.num_kv_heads, cfg.head_dim
             c["cross_k"] = jnp.zeros((batch, cfg.enc_seq_len, K, hd), dtype)
             c["cross_v"] = jnp.zeros((batch, cfg.enc_seq_len, K, hd), dtype)
-            return c
-        return {"layers": stack(dec_layer, cfg.num_layers)}
-    raise ValueError(kind)
+        return c
+    return layout.build(layout.layer_layout(cfg),
+                        _cache_maker(cfg, batch, dtype, attn_cache),
+                        _stack_cache)
 
 
 def cache_logical_axes_tree(cfg, long_context: bool = False):
     """Logical axes matching init_cache_tree's structure."""
-    kv = ("layers",) + attn.cache_logical_axes()["k"]
-    kv_leaf = {"k": kv, "v": kv}
-
-    def with_layers(d):
-        return jax.tree.map(lambda a: ("layers",) + tuple(a), d,
-                            is_leaf=lambda x: isinstance(x, tuple))
-
-    kind = cfg.kind
-    if kind in ("dense", "vlm") or (kind == "moe" and cfg.moe_every == 1):
-        return {"layers": with_layers(attn.cache_logical_axes())}
-    if kind == "moe":
-        g = {f"dense_{i}": attn.cache_logical_axes()
-             for i in range(cfg.moe_every - 1)}
-        g["moe"] = attn.cache_logical_axes()
-        return {"groups": with_layers(g)}
-    if kind == "ssm":
-        return {"layers": with_layers(ssmm.ssm_cache_logical_axes(cfg))}
-    if kind == "mamba_hybrid":
-        return {"layers": [
-            ssmm.ssm_cache_logical_axes(cfg) if t == "mamba"
-            else attn.cache_logical_axes() for t in cfg.layer_types]}
-    if kind == "hybrid":
-        period = cfg.local_attn_every or 3
-        rem = cfg.num_layers - (cfg.num_layers // period) * period
-        g = {f"rec_{i}": rgm.rglru_cache_logical_axes(cfg)
-             for i in range(period - 1)}
-        g["attn"] = attn.cache_logical_axes()
-        out = {}
-        if (cfg.num_layers // period):
-            out["groups"] = with_layers(g)
-        if rem:
-            out["tail"] = with_layers(rgm.rglru_cache_logical_axes(cfg))
-        return out
-    if kind in ("encdec", "audio"):
+    def attn_axes(role):
         d = attn.cache_logical_axes()
-        d["cross_k"] = ("cache_batch", None, "cache_kv_heads", "head_dim")
-        d["cross_v"] = ("cache_batch", None, "cache_kv_heads", "head_dim")
-        return {"layers": with_layers(d)}
-    raise ValueError(kind)
+        if role == "cross":
+            d["cross_k"] = ("cache_batch", None, "cache_kv_heads", "head_dim")
+            d["cross_v"] = ("cache_batch", None, "cache_kv_heads", "head_dim")
+        return d
+    return layout.build(layout.layer_layout(cfg),
+                        _axes_maker(cfg, attn_axes), _stack_axes)
 
 
 # ---------------------------------------------------------------------------
@@ -416,14 +379,13 @@ def prefill(p, cfg, batch, *, dtype=jnp.bfloat16, cache_dtype=jnp.bfloat16,
     if kind == "vlm":
         patches = batch["patches"].astype(dtype)
         x = jnp.concatenate([patches, x], axis=1)
-        mode = "prefix"
-        prefix = cfg.enc_seq_len
+        mode, window, prefix = "prefix", 0, cfg.enc_seq_len
     if kind in ("encdec", "audio"):
         frames = batch["frames"].astype(dtype)
         pos_e = sinusoidal_positions(frames.shape[1],
                                      cfg.d_model).astype(dtype)
         h = frames + pos_e[None]
-        def enc_body(hh, lp):
+        def enc_body(lp, hh):
             y = attn.attention_block(lp["attn"], cfg,
                                      apply_norm(cfg, lp["ln_attn"], hh),
                                      mode="full")
@@ -431,7 +393,8 @@ def prefill(p, cfg, batch, *, dtype=jnp.bfloat16, cache_dtype=jnp.bfloat16,
             hh = hh + mlpm.apply_mlp(lp["mlp"], cfg,
                                      apply_norm(cfg, lp["ln_mlp"], hh))
             return hh, None
-        h, _ = jax.lax.scan(lambda c, lp: enc_body(c, lp), h, p["enc_layers"])
+        h, _ = layout.run(layout.encoder_layout(cfg), p, h,
+                          {"enc": enc_body})
         enc_out = apply_norm(cfg, p["enc_ln_final"], h)
         if not cfg.rope:
             dpos = sinusoidal_positions(T, cfg.d_model).astype(dtype)
@@ -444,91 +407,45 @@ def prefill(p, cfg, batch, *, dtype=jnp.bfloat16, cache_dtype=jnp.bfloat16,
     if lengths is not None:
         lens_x = lengths + (cfg.enc_seq_len if kind == "vlm" else 0)
 
-    def run_stack(x, stacked, body):
-        fn = jax.checkpoint(body) if remat else body
-        return jax.lax.scan(lambda c, lp: fn(lp, c), x, stacked)
+    def attn_body(lp, xx):
+        return _prefill_attn_layer(
+            lp, cfg, xx, mode=mode, window=window, S=S,
+            cache_dtype=cache_dtype, prefix_len=prefix, lengths=lens_x)
 
-    if kind in ("dense", "vlm") or (kind == "moe" and cfg.moe_every == 1):
-        def body(lp, xx):
-            m = "prefix" if kind == "vlm" else mode
-            return _prefill_attn_layer(
-                lp, cfg, xx, mode=m, window=window, S=S,
-                cache_dtype=cache_dtype, lengths=lens_x)
-        # prefix mode needs prefix_len plumbed through _mask_block;
-        # handled via functools.partial on _mask defaults:
-        if kind == "vlm":
-            def body(lp, xx):  # noqa: F811 — vlm specialization
-                return _prefill_vlm_layer(lp, cfg, xx, prefix, S,
-                                          cache_dtype, lens_x)
-        x, cache = run_stack(x, p["layers"], body)
-        cache = {"layers": cache}
-    elif kind == "moe":
-        def body(lp, xx):
-            caches = {}
-            for i in range(cfg.moe_every - 1):
-                xx, caches[f"dense_{i}"] = _prefill_attn_layer(
-                    lp[f"dense_{i}"], cfg, xx, mode=mode, window=window,
-                    S=S, cache_dtype=cache_dtype, lengths=lens_x)
-            xx, caches["moe"] = _prefill_attn_layer(
-                lp["moe"], cfg, xx, mode=mode, window=window, S=S,
-                cache_dtype=cache_dtype, lengths=lens_x)
-            return xx, caches
-        x, cache = run_stack(x, p["groups"], body)
-        cache = {"groups": cache}
-    elif kind == "ssm":
-        def body(lp, xx):
-            return _prefill_ssm_layer(lp, cfg, xx, lens_x)
-        x, cache = run_stack(x, p["layers"], body)
-        cache = {"layers": cache}
-    elif kind == "hybrid":
-        period = cfg.local_attn_every or 3
-        def body(lp, xx):
-            caches = {}
-            for i in range(period - 1):
-                xx, caches[f"rec_{i}"] = _prefill_rec_layer(
-                    lp[f"rec_{i}"], cfg, xx, lens_x)
-            xx, caches["attn"] = _prefill_attn_layer(
-                lp["attn"], cfg, xx, mode="sliding",
-                window=cfg.attention_window, S=S, cache_dtype=cache_dtype,
+    def local_attn_body(lp, xx):
+        return _prefill_attn_layer(
+            lp, cfg, xx, mode="sliding", window=cfg.attention_window, S=S,
+            cache_dtype=cache_dtype, lengths=lens_x)
+
+    def cross_body(lp, xx):
+        return _prefill_attn_layer(lp, cfg, xx, mode="causal", window=0,
+                                   S=S, cache_dtype=cache_dtype,
+                                   enc_out=enc_out, lengths=lens_x)
+
+    def mamba_body(lp, xx):
+        with jax.named_scope("ssm_mixer"):
+            xx, c = _prefill_ssm_mixer(
+                lp["ssm"], cfg, apply_norm(cfg, lp["ln"], xx), lens_x,
+                residual=functools.partial(hybrid_residual, cfg, xx))
+        return hybrid_ffn(lp, cfg, xx), c
+
+    def attention_body(lp, xx):
+        with jax.named_scope("attn_mixer"):
+            y, c = _prefill_attn_mixer(
+                lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], xx),
+                mode=mode, window=window, S=S, cache_dtype=cache_dtype,
                 lengths=lens_x)
-            return xx, caches
-        cache = {}
-        if "groups" in p:
-            x, gcache = run_stack(x, p["groups"], body)
-            cache["groups"] = gcache
-        if "tail" in p:
-            def tail_body(lp, xx):
-                return _prefill_rec_layer(lp, cfg, xx, lens_x)
-            x, tail_cache = run_stack(x, p["tail"], tail_body)
-            cache["tail"] = tail_cache
-    elif kind == "mamba_hybrid":
-        layers = []
-        for lp in p["layers"]:
-            residual = functools.partial(hybrid_residual, cfg, x)
-            if "ssm" in lp:
-                with jax.named_scope("ssm_mixer"):
-                    x, c = _prefill_ssm_mixer(
-                        lp["ssm"], cfg, apply_norm(cfg, lp["ln"], x), lens_x,
-                        residual=residual)
-            else:
-                with jax.named_scope("attn_mixer"):
-                    y, c = _prefill_attn_mixer(
-                        lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], x),
-                        mode=mode, window=window, S=S,
-                        cache_dtype=cache_dtype, lengths=lens_x)
-                x = residual(y)
-            x = hybrid_ffn(lp, cfg, x)
-            layers.append(c)
-        cache = {"layers": layers}
-    elif kind in ("encdec", "audio"):
-        def body(lp, xx):
-            return _prefill_attn_layer(lp, cfg, xx, mode="causal", window=0,
-                                       S=S, cache_dtype=cache_dtype,
-                                       enc_out=enc_out, lengths=lens_x)
-        x, cache = run_stack(x, p["layers"], body)
-        cache = {"layers": cache}
-    else:
-        raise ValueError(kind)
+        return hybrid_ffn(lp, cfg, hybrid_residual(cfg, xx, y)), c
+
+    bodies = {
+        "attn": attn_body, "moe": attn_body, "local_attn": local_attn_body,
+        "cross": cross_body,
+        "ssm": lambda lp, xx: _prefill_ssm_layer(lp, cfg, xx, lens_x),
+        "rec": lambda lp, xx: _prefill_rec_layer(lp, cfg, xx, lens_x),
+        "mamba": mamba_body, "attention": attention_body,
+    }
+    x, cache = layout.run(layout.layer_layout(cfg), p, x, bodies,
+                          remat=remat)
 
     x = apply_norm(cfg, p["ln_final"], x)
     if lens_x is None:
@@ -540,12 +457,6 @@ def prefill(p, cfg, batch, *, dtype=jnp.bfloat16, cache_dtype=jnp.bfloat16,
     x_last = jnp.take_along_axis(x, last, axis=1)           # (B, 1, d)
     logits = _unembed(p, cfg, x_last)
     return logits, cache, lens_x
-
-
-def _prefill_vlm_layer(lp, cfg, x, prefix, S, cache_dtype, lengths=None):
-    return _prefill_attn_layer(lp, cfg, x, mode="prefix", window=0, S=S,
-                               cache_dtype=cache_dtype, prefix_len=prefix,
-                               lengths=lengths)
 
 
 # ---------------------------------------------------------------------------
@@ -614,80 +525,28 @@ def decode_step(p, cfg, token, cache, pos, *, dtype=jnp.bfloat16,
                                  apply_norm(cfg, lp["ln_mlp"], xx))
         return xx, c_new
 
-    if kind in ("dense", "vlm") or (kind == "moe" and cfg.moe_every == 1):
-        def body(xx, scanned):
-            lp, c = scanned
-            return attn_decode(lp, xx, c)
-        x, new_cache = jax.lax.scan(
-            lambda c, s: body(c, s), x, (p["layers"], cache["layers"]))
-        new_cache = {"layers": new_cache}
-    elif kind == "moe":
-        def body(xx, scanned):
-            lp, c = scanned
-            new = {}
-            for i in range(cfg.moe_every - 1):
-                xx, new[f"dense_{i}"] = attn_decode(
-                    lp[f"dense_{i}"], xx, c[f"dense_{i}"])
-            xx, new["moe"] = attn_decode(lp["moe"], xx, c["moe"])
-            return xx, new
-        x, new_cache = jax.lax.scan(
-            lambda c, s: body(c, s), x, (p["groups"], cache["groups"]))
-        new_cache = {"groups": new_cache}
-    elif kind == "ssm":
-        def body(xx, scanned):
-            lp, c = scanned
-            return ssm_decode(lp, xx, c)
-        x, new_cache = jax.lax.scan(
-            lambda c, s: body(c, s), x, (p["layers"], cache["layers"]))
-        new_cache = {"layers": new_cache}
-    elif kind == "hybrid":
-        period = cfg.local_attn_every or 3
-        def body(xx, scanned):
-            lp, c = scanned
-            new = {}
-            for i in range(period - 1):
-                xx, new[f"rec_{i}"] = rec_decode(
-                    lp[f"rec_{i}"], xx, c[f"rec_{i}"])
-            xx, new["attn"] = attn_decode(lp["attn"], xx, c["attn"])
-            return xx, new
-        new_cache = {}
-        if "groups" in p:
-            x, gnew = jax.lax.scan(
-                lambda c, s: body(c, s), x, (p["groups"], cache["groups"]))
-            new_cache["groups"] = gnew
-        if "tail" in p:
-            def tail_body(xx, scanned):
-                lp, c = scanned
-                return rec_decode(lp, xx, c)
-            x, tail_new = jax.lax.scan(
-                lambda c, s: tail_body(c, s), x,
-                (p["tail"], cache["tail"]))
-            new_cache["tail"] = tail_new
-    elif kind == "mamba_hybrid":
-        layers = []
-        for lp, c in zip(p["layers"], cache["layers"]):
-            if "ssm" in lp:
-                with jax.named_scope("ssm_mixer"):
-                    y, c = ssmm.decode_ssm(
-                        lp["ssm"], cfg, apply_norm(cfg, lp["ln"], x), c)
-            else:
-                with jax.named_scope("attn_mixer"):
-                    ring = w if (c["k"].shape[1] == w and w) else 0
-                    y, c = attn.decode_attention(
-                        lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], x),
-                        c, pos, window=ring)
-            x = hybrid_ffn(lp, cfg, hybrid_residual(cfg, x, y))
-            layers.append(c)
-        new_cache = {"layers": layers}
-    elif kind in ("encdec", "audio"):
-        def body(xx, scanned):
-            lp, c = scanned
-            return attn_decode(lp, xx, c, cross=True)
-        x, new_cache = jax.lax.scan(
-            lambda c, s: body(c, s), x, (p["layers"], cache["layers"]))
-        new_cache = {"layers": new_cache}
-    else:
-        raise ValueError(kind)
+    def mamba_dec(lp, xx, c):
+        with jax.named_scope("ssm_mixer"):
+            y, c = ssmm.decode_ssm(
+                lp["ssm"], cfg, apply_norm(cfg, lp["ln"], xx), c)
+        return hybrid_ffn(lp, cfg, hybrid_residual(cfg, xx, y)), c
+
+    def attention_dec(lp, xx, c):
+        with jax.named_scope("attn_mixer"):
+            ring = w if (c["k"].shape[1] == w and w) else 0
+            y, c = attn.decode_attention(
+                lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], xx),
+                c, pos, window=ring)
+        return hybrid_ffn(lp, cfg, hybrid_residual(cfg, xx, y)), c
+
+    bodies = {
+        "attn": attn_decode, "moe": attn_decode, "local_attn": attn_decode,
+        "cross": functools.partial(attn_decode, cross=True),
+        "ssm": ssm_decode, "rec": rec_decode,
+        "mamba": mamba_dec, "attention": attention_dec,
+    }
+    x, new_cache = layout.run(layout.layer_layout(cfg), p, x, bodies,
+                              cache=cache)
 
     x = apply_norm(cfg, p["ln_final"], x)
     logits = _unembed(p, cfg, x)
@@ -754,36 +613,26 @@ def write_cache_slot(cfg, cache, one_cache, slot, *, pos=None,
 # recurrent state per-slot; chunked prefill + page-map decode
 # ---------------------------------------------------------------------------
 
-PAGED_KINDS = ("dense", "moe", "ssm", "hybrid", "mamba_hybrid")
-# kinds whose attention layers keep K/V in the page pool
-PAGE_POOL_KINDS = ("dense", "moe", "hybrid", "mamba_hybrid")
-# kinds whose whole per-request state is K/V, so a prompt prefix's pages
-# can be shared; a recurrent layer's state cannot be borrowed
-PREFIX_SHARING_KINDS = ("dense", "moe")
-
-
 # ---------------------------------------------------------------------------
 # matmul operands: a bf16 copy of the stacked layers' matmul weights
 # ---------------------------------------------------------------------------
 
-# the stacked layer trees, which lax.scan consumes (a list under "layers"
-# holds unrolled per-layer trees: mamba_hybrid)
-STACKED_TREES = ("layers", "groups", "tail")
 # leaves that the layer code uses only as a matmul's right operand,
 # ``x @ w.astype(x.dtype)`` (or the experts' einsums)
 OPERAND_WEIGHTS = frozenset({"wq", "wk", "wv", "wo", "w_up", "w_down",
                              "w_gate", "w_in", "w_out", "w_gelu", "w_rec"})
 
 
-def map_operands(fn, params):
+def map_operands(cfg, fn, params):
     """``params`` with ``fn`` applied to the matmul weights of the
-    stacked layer trees; every other leaf is the same object."""
+    stacked layer trees (those ``lax.scan`` consumes; unrolled layers
+    are left alone); every other leaf is the same object."""
     def walk(tree):
         return {k: fn(v) if k in OPERAND_WEIGHTS and not isinstance(v, dict)
                 else walk(v) if isinstance(v, dict) else v
                 for k, v in tree.items()}
-    return {k: walk(v) if k in STACKED_TREES and isinstance(v, dict) else v
-            for k, v in params.items()}
+    stacked = layout.stacked_keys(layout.layer_layout(cfg))
+    return {k: walk(v) if k in stacked else v for k, v in params.items()}
 
 
 def bf16_operands_exact(params) -> bool:
@@ -804,18 +653,18 @@ def _on_tpu(params) -> bool:
     return bool(devices) and all(d.platform == "tpu" for d in devices)
 
 
-def serve_operands(params):
+def serve_operands(cfg, params):
     """The tree the serving programs compute with: ``params`` whose
     stacked matmul weights are cast to bf16 once, where that is exact
     (:func:`bf16_operands_exact`), else ``params`` itself (also where no
     layer tree is stacked). A scan over f32 stacks would convert each
     whole stack on every call.
     """
-    stacks = [params[k] for k in STACKED_TREES
-              if isinstance(params.get(k), dict)]
+    stacks = [params[k]
+              for k in layout.stacked_keys(layout.layer_layout(cfg))]
     if not stacks or not bf16_operands_exact(stacks):
         return params
-    return map_operands(lambda w: w.astype(jnp.bfloat16)
+    return map_operands(cfg, lambda w: w.astype(jnp.bfloat16)
                         if w.dtype == jnp.float32 else w, params)
 
 
@@ -826,7 +675,7 @@ def init_paged_cache_tree(cfg, slots: int, num_pages: int, page_size: int,
     0 reserved as the dummy sink); SSM/RG-LRU/conv state is O(1) per
     request and stays per-slot, identical to the ring layout.
     """
-    if cfg.kind not in PAGED_KINDS:
+    if cfg.kind in layout.FRONTEND_KINDS:
         raise ValueError(
             f"paged serving is token-only; arch kind {cfg.kind!r} is "
             "not served by the request schedulers")
@@ -848,85 +697,18 @@ def init_paged_cache_tree(cfg, slots: int, num_pages: int, page_size: int,
 
 
 def _init_paged_cache_tree(cfg, slots, num_pages, page_size, dtype):
-    kind = cfg.kind
-
-    def stack(make_one, n):
-        one = make_one()
-        return jax.tree.map(
-            lambda l: jnp.broadcast_to(l[None], (n,) + l.shape), one)
-
-    pool = lambda: attn.init_paged_cache(cfg, num_pages, page_size,  # noqa: E731
-                                         dtype)
-    if kind == "dense" or (kind == "moe" and cfg.moe_every == 1):
-        return {"layers": stack(pool, cfg.num_layers)}
-    if kind == "moe":
-        n_groups = cfg.num_layers // cfg.moe_every
-        def group():
-            g = {f"dense_{i}": pool() for i in range(cfg.moe_every - 1)}
-            g["moe"] = pool()
-            return g
-        return {"groups": stack(group, n_groups)}
-    if kind == "ssm":
-        return {"layers": stack(
-            lambda: ssmm.init_ssm_cache(cfg, slots, dtype), cfg.num_layers)}
-    if kind == "mamba_hybrid":
-        # per-slot SSM state for the Mamba layers, a page pool for each
-        # attention layer; one entry per layer, in the published order
-        return {"layers": [
-            ssmm.init_ssm_cache(cfg, slots, dtype) if t == "mamba"
-            else pool() for t in cfg.layer_types]}
-    if kind == "hybrid":
-        period = cfg.local_attn_every or 3
-        n_groups = cfg.num_layers // period
-        rem = cfg.num_layers - n_groups * period
-        def group():
-            g = {f"rec_{i}": rgm.init_rglru_cache(cfg, slots, dtype)
-                 for i in range(period - 1)}
-            g["attn"] = pool()
-            return g
-        out = {}
-        if n_groups:
-            out["groups"] = stack(group, n_groups)
-        if rem:
-            out["tail"] = stack(
-                lambda: rgm.init_rglru_cache(cfg, slots, dtype), rem)
-        return out
-    raise ValueError(kind)
+    def pool(role):
+        return attn.init_paged_cache(cfg, num_pages, page_size, dtype)
+    return layout.build(layout.layer_layout(cfg),
+                        _cache_maker(cfg, slots, dtype, pool), _stack_cache)
 
 
 def paged_cache_logical_axes_tree(cfg):
     """Logical axes matching init_paged_cache_tree's structure."""
-    def with_layers(d):
-        return jax.tree.map(lambda a: ("layers",) + tuple(a), d,
-                            is_leaf=lambda x: isinstance(x, tuple))
-
-    kind = cfg.kind
-    pool = attn.paged_cache_logical_axes
-    if kind == "dense" or (kind == "moe" and cfg.moe_every == 1):
-        return {"layers": with_layers(pool())}
-    if kind == "moe":
-        g = {f"dense_{i}": pool() for i in range(cfg.moe_every - 1)}
-        g["moe"] = pool()
-        return {"groups": with_layers(g)}
-    if kind == "ssm":
-        return {"layers": with_layers(ssmm.ssm_cache_logical_axes(cfg))}
-    if kind == "mamba_hybrid":
-        return {"layers": [
-            ssmm.ssm_cache_logical_axes(cfg) if t == "mamba" else pool()
-            for t in cfg.layer_types]}
-    if kind == "hybrid":
-        period = cfg.local_attn_every or 3
-        rem = cfg.num_layers - (cfg.num_layers // period) * period
-        g = {f"rec_{i}": rgm.rglru_cache_logical_axes(cfg)
-             for i in range(period - 1)}
-        g["attn"] = pool()
-        out = {}
-        if cfg.num_layers // period:
-            out["groups"] = with_layers(g)
-        if rem:
-            out["tail"] = with_layers(rgm.rglru_cache_logical_axes(cfg))
-        return out
-    raise ValueError(kind)
+    return layout.build(
+        layout.layer_layout(cfg),
+        _axes_maker(cfg, lambda role: attn.paged_cache_logical_axes()),
+        _stack_axes)
 
 
 def _slot_slice(leaf, slot):
@@ -1119,7 +901,7 @@ def prefill_chunk(p, cfg, cache, tokens, start, valid, page_row, slot,
     decode ticks cannot observe a half-written prefix.
     """
     kind = cfg.kind
-    if kind not in PAGED_KINDS:
+    if kind in layout.FRONTEND_KINDS:
         raise ValueError(kind)
     B, C = tokens.shape
     start = jnp.asarray(start, jnp.int32).reshape(())
@@ -1138,78 +920,38 @@ def prefill_chunk(p, cfg, cache, tokens, start, valid, page_row, slot,
                                  start=start, valid=valid,
                                  page_row=page_row)
 
-    def scan(x, stacked_p, stacked_c, body):
-        def f(xx, sc):
-            lp, c = sc
-            return body(lp, xx, c)
-        return jax.lax.scan(f, x, (stacked_p, stacked_c))
+    def local_attn_body(lp, xx, c):
+        return _chunk_attn_layer(lp, cfg, xx, c, mode="sliding",
+                                 window=cfg.attention_window, start=start,
+                                 valid=valid, page_row=page_row)
 
-    if kind == "dense" or (kind == "moe" and cfg.moe_every == 1):
-        x, new_cache = scan(x, p["layers"], cache["layers"], attn_body)
-        new_cache = {"layers": new_cache}
-    elif kind == "moe":
-        def body(lp, xx, c):
-            new = {}
-            for i in range(cfg.moe_every - 1):
-                xx, new[f"dense_{i}"] = attn_body(
-                    lp[f"dense_{i}"], xx, c[f"dense_{i}"])
-            xx, new["moe"] = attn_body(lp["moe"], xx, c["moe"])
-            return xx, new
-        x, new_cache = scan(x, p["groups"], cache["groups"], body)
-        new_cache = {"groups": new_cache}
-    elif kind == "ssm":
-        def body(lp, xx, c):
-            return _chunk_ssm_layer(lp, cfg, xx, c, slot=slot,
-                                    start=start, valid=valid)
-        x, new_cache = scan(x, p["layers"], cache["layers"], body)
-        new_cache = {"layers": new_cache}
-    elif kind == "hybrid":
-        period = cfg.local_attn_every or 3
-        def body(lp, xx, c):
-            new = {}
-            for i in range(period - 1):
-                xx, new[f"rec_{i}"] = _chunk_rec_layer(
-                    lp[f"rec_{i}"], cfg, xx, c[f"rec_{i}"],
-                    slot=slot, start=start, valid=valid)
-            xx, new["attn"] = _chunk_attn_layer(
-                lp["attn"], cfg, xx, c["attn"], mode="sliding",
-                window=cfg.attention_window, start=start, valid=valid,
+    # pad rows (j >= valid) run through the experts like the others:
+    # the expert layer is row-independent, so they touch no real row
+    def mamba_body(lp, xx, c):
+        with jax.named_scope("ssm_mixer"):
+            xx, c = _chunk_ssm_mixer(
+                lp["ssm"], cfg, apply_norm(cfg, lp["ln"], xx), c,
+                slot=slot, start=start, valid=valid,
+                residual=functools.partial(hybrid_residual, cfg, xx))
+        return hybrid_ffn(lp, cfg, xx), c
+
+    def attention_body(lp, xx, c):
+        with jax.named_scope("attn_mixer"):
+            y, c = _chunk_attn_mixer(
+                lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], xx), c,
+                mode=mode, window=window, start=start, valid=valid,
                 page_row=page_row)
-            return xx, new
-        new_cache = {}
-        if "groups" in p:
-            x, gnew = scan(x, p["groups"], cache["groups"], body)
-            new_cache["groups"] = gnew
-        if "tail" in p:
-            def tail_body(lp, xx, c):
-                return _chunk_rec_layer(lp, cfg, xx, c, slot=slot,
-                                        start=start, valid=valid)
-            x, tnew = scan(x, p["tail"], cache["tail"], tail_body)
-            new_cache["tail"] = tnew
-    elif kind == "mamba_hybrid":
-        # pad rows (j >= valid) run through the experts like the others:
-        # the expert layer is row-independent, so they touch no real row
-        layers = []
-        for lp, c in zip(p["layers"], cache["layers"]):
-            residual = functools.partial(hybrid_residual, cfg, x)
-            if "ssm" in lp:
-                with jax.named_scope("ssm_mixer"):
-                    x, c = _chunk_ssm_mixer(
-                        lp["ssm"], cfg, apply_norm(cfg, lp["ln"], x), c,
-                        slot=slot, start=start, valid=valid,
-                        residual=residual)
-            else:
-                with jax.named_scope("attn_mixer"):
-                    y, c = _chunk_attn_mixer(
-                        lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], x),
-                        c, mode=mode, window=window, start=start,
-                        valid=valid, page_row=page_row)
-                x = residual(y)
-            x = hybrid_ffn(lp, cfg, x)
-            layers.append(c)
-        new_cache = {"layers": layers}
-    else:
-        raise ValueError(kind)
+        return hybrid_ffn(lp, cfg, hybrid_residual(cfg, xx, y)), c
+
+    state = dict(slot=slot, start=start, valid=valid)
+    bodies = {
+        "attn": attn_body, "moe": attn_body, "local_attn": local_attn_body,
+        "ssm": lambda lp, xx, c: _chunk_ssm_layer(lp, cfg, xx, c, **state),
+        "rec": lambda lp, xx, c: _chunk_rec_layer(lp, cfg, xx, c, **state),
+        "mamba": mamba_body, "attention": attention_body,
+    }
+    x, new_cache = layout.run(layout.layer_layout(cfg), p, x, bodies,
+                              cache=cache)
 
     x = apply_norm(cfg, p["ln_final"], x)
     x_last = jax.lax.dynamic_slice_in_dim(
@@ -1239,7 +981,7 @@ def decode_step_paged(p, cfg, token, cache, pos, page_map, live, *,
     page via the page map. Returns (logits, new_cache).
     """
     kind = cfg.kind
-    if kind not in PAGED_KINDS:
+    if kind in layout.FRONTEND_KINDS:
         raise ValueError(kind)
     B = token.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
@@ -1275,71 +1017,29 @@ def decode_step_paged(p, cfg, token, cache, pos, page_map, live, *,
                                  apply_norm(cfg, lp["ln_mlp"], xx))
         return xx, c_new
 
-    if kind == "dense" or (kind == "moe" and cfg.moe_every == 1):
-        def body(xx, sc):
-            lp, c = sc
-            return attn_dec(lp, xx, c)
-        x, new_cache = jax.lax.scan(body, x, (p["layers"], cache["layers"]))
-        new_cache = {"layers": new_cache}
-    elif kind == "moe":
-        def body(xx, sc):
-            lp, c = sc
-            new = {}
-            for i in range(cfg.moe_every - 1):
-                xx, new[f"dense_{i}"] = attn_dec(
-                    lp[f"dense_{i}"], xx, c[f"dense_{i}"])
-            xx, new["moe"] = attn_dec(lp["moe"], xx, c["moe"])
-            return xx, new
-        x, new_cache = jax.lax.scan(body, x, (p["groups"], cache["groups"]))
-        new_cache = {"groups": new_cache}
-    elif kind == "ssm":
-        def body(xx, sc):
-            lp, c = sc
-            return ssm_dec(lp, xx, c)
-        x, new_cache = jax.lax.scan(body, x, (p["layers"], cache["layers"]))
-        new_cache = {"layers": new_cache}
-    elif kind == "hybrid":
-        period = cfg.local_attn_every or 3
-        def body(xx, sc):
-            lp, c = sc
-            new = {}
-            for i in range(period - 1):
-                xx, new[f"rec_{i}"] = rec_dec(
-                    lp[f"rec_{i}"], xx, c[f"rec_{i}"])
-            xx, new["attn"] = attn_dec(lp["attn"], xx, c["attn"])
-            return xx, new
-        new_cache = {}
-        if "groups" in p:
-            x, gnew = jax.lax.scan(body, x,
-                                   (p["groups"], cache["groups"]))
-            new_cache["groups"] = gnew
-        if "tail" in p:
-            def tail_body(xx, sc):
-                lp, c = sc
-                return rec_dec(lp, xx, c)
-            x, tnew = jax.lax.scan(tail_body, x,
-                                   (p["tail"], cache["tail"]))
-            new_cache["tail"] = tnew
-    elif kind == "mamba_hybrid":
-        layers = []
-        for lp, c in zip(p["layers"], cache["layers"]):
-            if "ssm" in lp:
-                with jax.named_scope("ssm_mixer"):
-                    y, c_new = ssmm.decode_ssm(
-                        lp["ssm"], cfg, apply_norm(cfg, lp["ln"], x), c)
-                    c_new = jax.tree.map(
-                        lambda n, o: _gate_live(n, o, live), c_new, c)
-            else:
-                with jax.named_scope("attn_mixer"):
-                    y, c_new = attn.paged_decode_attention(
-                        lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], x),
-                        c, pos, page_map, window=w, live=live,
-                        use_kernel=use_kernel)
-            x = hybrid_ffn(lp, cfg, hybrid_residual(cfg, x, y))
-            layers.append(c_new)
-        new_cache = {"layers": layers}
-    else:
-        raise ValueError(kind)
+    def mamba_dec(lp, xx, c):
+        with jax.named_scope("ssm_mixer"):
+            y, c_new = ssmm.decode_ssm(
+                lp["ssm"], cfg, apply_norm(cfg, lp["ln"], xx), c)
+            c_new = jax.tree.map(
+                lambda n, o: _gate_live(n, o, live), c_new, c)
+        return hybrid_ffn(lp, cfg, hybrid_residual(cfg, xx, y)), c_new
+
+    def attention_dec(lp, xx, c):
+        with jax.named_scope("attn_mixer"):
+            y, c_new = attn.paged_decode_attention(
+                lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], xx),
+                c, pos, page_map, window=w, live=live,
+                use_kernel=use_kernel)
+        return hybrid_ffn(lp, cfg, hybrid_residual(cfg, xx, y)), c_new
+
+    bodies = {
+        "attn": attn_dec, "moe": attn_dec, "local_attn": attn_dec,
+        "ssm": ssm_dec, "rec": rec_dec,
+        "mamba": mamba_dec, "attention": attention_dec,
+    }
+    x, new_cache = layout.run(layout.layer_layout(cfg), p, x, bodies,
+                              cache=cache)
 
     x = apply_norm(cfg, p["ln_final"], x)
     logits = _unembed(p, cfg, x)

@@ -130,7 +130,8 @@ class _SchedulerBase:
                  cache_dtype=jnp.float32, obs=NULL_OBS, mesh=None,
                  rules=None, cache_rules=None, **shard_kw):
         assert max_prompt <= max_total
-        if model.cfg.kind in ("vlm", "encdec", "audio"):
+        from repro.models.layout import FRONTEND_KINDS
+        if model.cfg.kind in FRONTEND_KINDS:
             raise ValueError(
                 f"{type(self).__name__} serves token-only requests; "
                 f"arch kind {model.cfg.kind!r} needs frontend inputs "
@@ -315,7 +316,7 @@ class _SchedulerBase:
                 return held["ops"]
             held.clear()                    # free the old copy first
         from repro.serving.engine import serve_operands
-        ops = serve_operands(params)
+        ops = serve_operands(self.model.cfg, params)
         self.operand_bytes = 0
         if ops is params:
             return params
@@ -574,10 +575,9 @@ class PagedContinuousScheduler(_SchedulerBase):
         self.paged_kernel = paged_kernel
         # page pools only exist for attention-bearing families; pure-SSM
         # archs carry O(1) per-slot state and need zero pages
-        from repro.serving.engine import (PAGE_POOL_KINDS,
-                                          PREFIX_SHARING_KINDS)
-        self._has_pages = cfg.kind in PAGE_POOL_KINDS
-        self._shareable = cfg.kind in PREFIX_SHARING_KINDS
+        from repro.models import layout
+        self._has_pages = layout.has_page_pools(cfg)
+        self._shareable = layout.shares_prefix(cfg)
         self._dummy = DUMMY_PAGE
         self.table = PageTable(cache_pages, page_size)
         self.trie = PrefixTrie(page_size)

@@ -11,6 +11,16 @@ from repro.configs import ARCHS, get_arch
 from repro.models import build_model
 
 ALL_ARCHS = sorted(ARCHS)
+# recurrentgemma-9b at 5 layers: one (rec, rec, attn) group, then a
+# 2-layer recurrent tail stack (the published 38 end the same way)
+HYBRID_TAIL = "recurrentgemma-9b@5"
+
+
+def reduced(name):
+    """``name``'s reduced config; ``arch@n`` asks for n layers."""
+    arch, _, layers = name.partition("@")
+    return get_arch(arch).reduced(**({"num_layers": int(layers)}
+                                     if layers else {}))
 
 
 def make_batch(cfg, B=2, T=32, key=None):
@@ -26,10 +36,11 @@ def make_batch(cfg, B=2, T=32, key=None):
     return batch
 
 
-@pytest.mark.parametrize("name", ALL_ARCHS)
+@pytest.mark.parametrize("name", ALL_ARCHS + [HYBRID_TAIL])
 def test_arch_smoke_forward_and_train_step(name):
-    cfg = get_arch(name).reduced()
-    assert cfg.d_model <= 512 and cfg.num_layers <= 3
+    cfg = reduced(name)
+    assert cfg.d_model <= 512
+    assert cfg.num_layers <= (5 if name == HYBRID_TAIL else 3)
     if cfg.moe_num_experts:
         assert cfg.moe_num_experts <= 4
     model = build_model(cfg)

@@ -13,10 +13,15 @@ from repro.models import build_model
 from repro.serving import engine
 
 ALL_ARCHS = sorted(ARCHS)
+# recurrentgemma-9b at 5 layers: one (rec, rec, attn) group, then a
+# 2-layer recurrent tail stack (the published 38 end the same way)
+HYBRID_TAIL = "recurrentgemma-9b@5"
 
 
 def _prep(name, serve_window=0, T=24):
-    cfg = get_arch(name).reduced()
+    arch, _, layers = name.partition("@")
+    cfg = get_arch(arch).reduced(**({"num_layers": int(layers)}
+                                    if layers else {}))
     if cfg.kind == "hybrid":
         cfg = dataclasses.replace(cfg, attention_window=16)
     if cfg.moe_num_experts:
@@ -36,7 +41,7 @@ def _prep(name, serve_window=0, T=24):
     return cfg, model, params, batch, tokens
 
 
-@pytest.mark.parametrize("name", ALL_ARCHS)
+@pytest.mark.parametrize("name", ALL_ARCHS + [HYBRID_TAIL])
 def test_prefill_decode_matches_forward(name):
     T, n_dec = 24, 3
     cfg, model, params, batch, tokens = _prep(name, T=T)

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_arch
-from repro.models import build_model
+from repro.models import build_model, layout
 from repro.serving import (
     ContinuousScheduler, DUMMY_PAGE, PagedContinuousScheduler, PageTable,
     PrefixTrie, Request, engine, pages_per_slot, run_trace,
@@ -96,6 +96,9 @@ FAMILIES = {
     "moe": ("llama4-scout-17b-a16e", 0, {"moe_capacity_factor": 8.0}),
     "ssm": ("mamba2-370m", 0, {}),
     "hybrid": ("recurrentgemma-9b", 0, {"attention_window": 8}),
+    # 1 (rec, rec, attn) group + a 2-layer recurrent tail stack
+    "hybrid_tail": ("recurrentgemma-9b", 0,
+                    {"attention_window": 8, "num_layers": 5}),
 }
 
 
@@ -386,17 +389,18 @@ def _leaves_with_path(tree):
     return jax.tree_util.tree_flatten_with_path(tree)[0]
 
 
-def _is_operand(path) -> bool:
+def _is_operand(cfg, path) -> bool:
     keys = [getattr(k, "key", None) for k in path]
-    return (keys[0] in engine.STACKED_TREES and all(
+    stacked = layout.stacked_keys(layout.layer_layout(cfg))
+    return (keys[0] in stacked and all(
         isinstance(getattr(k, "key", None), str) for k in path)
         and keys[-1] in engine.OPERAND_WEIGHTS)
 
 
-def _bf16_rounded(params):
+def _bf16_rounded(cfg, params):
     """``params`` whose stacked matmul weights went to bf16 and back."""
     return engine.map_operands(
-        lambda w: w.astype(jnp.bfloat16).astype(jnp.float32), params)
+        cfg, lambda w: w.astype(jnp.bfloat16).astype(jnp.float32), params)
 
 
 def _granite_tiny():
@@ -406,15 +410,16 @@ def _granite_tiny():
 
 
 def test_operand_tree_is_params_on_cpu_and_at_highest(monkeypatch):
-    _, _, params = _tiny("qwen1.5-0.5b")
-    assert engine.serve_operands(params) is params          # CPU
+    cfg, _, params = _tiny("qwen1.5-0.5b")
+    assert engine.serve_operands(cfg, params) is params      # CPU
     monkeypatch.setattr(engine, "_on_tpu", lambda p: True)
-    assert engine.serve_operands(params) is not params      # TPU, DEFAULT
+    assert engine.serve_operands(cfg, params) is not params  # TPU, DEFAULT
     for prec, exact in (("bfloat16", True), ("highest", False),
                         ("float32", False), ("tensorfloat32", False)):
         with jax.default_matmul_precision(prec):
             assert engine.bf16_operands_exact(params) is exact, prec
-            assert (engine.serve_operands(params) is params) is not exact
+            assert (engine.serve_operands(cfg, params)
+                    is params) is not exact
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES) + ["mamba_hybrid"])
@@ -426,13 +431,13 @@ def test_forced_operands_cast_exactly_the_stacked_matmul_weights(
     else:
         arch, _, over = FAMILIES[family]
         cfg, _, params = _tiny(arch, **over)
-    ops = engine.serve_operands(params)
+    ops = engine.serve_operands(cfg, params)
     assert (jax.tree_util.tree_structure(ops)
             == jax.tree_util.tree_structure(params))
     cast = 0
     for (path, o), (_, p) in zip(_leaves_with_path(ops),
                                  _leaves_with_path(params)):
-        if _is_operand(path):
+        if _is_operand(cfg, path):
             cast += 1
             assert o.dtype == jnp.bfloat16 and o.shape == p.shape
             assert o.shape[0] == jax.tree.leaves(params[path[0].key])[0] \
@@ -458,7 +463,7 @@ def test_scheduler_builds_operands_once_per_params(tpu, monkeypatch):
     assert sched.stats.decode_steps > 4
     with monkeypatch.context() as m:
         m.setattr(engine, "_on_tpu", lambda p: True)
-        held = engine.serve_operands(params)
+        held = engine.serve_operands(cfg, params)
     nbytes = sum(o.nbytes for o, p in zip(jax.tree.leaves(held),
                                           jax.tree.leaves(params))
                  if o is not p)
@@ -490,7 +495,7 @@ def test_forced_operands_serve_like_bf16_rounded_weights(family,
     arch, _, over = FAMILIES[family]
     cfg, model, params = _tiny(arch, **over)
     runs = {}
-    for force, p in ((True, params), (False, _bf16_rounded(params))):
+    for force, p in ((True, params), (False, _bf16_rounded(cfg, params))):
         monkeypatch.setattr(engine, "_on_tpu", lambda _, on=force: on)
         sched = PagedContinuousScheduler(
             model, slots=3, max_prompt=14, max_total=20, page_size=4,

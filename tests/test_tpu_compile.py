@@ -208,8 +208,8 @@ def test_serving_operands_take_the_weight_converts_out(one_chip, cell,
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         model.abstract_params()[0])
     ops = engine.map_operands(
-        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
-                                       sharding=one_chip), f32)
+        model.cfg, lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                                  sharding=one_chip), f32)
     stacks = [a.shape for a, b in zip(jax.tree.leaves(ops),
                                       jax.tree.leaves(f32)) if a is not b]
     copy_bytes = sum(2 * int(np.prod(s)) for s in stacks)
@@ -242,7 +242,8 @@ def test_doc_chat_operands_are_its_params(monkeypatch):
     scanned stack: the operand tree holds no copy, even on a TPU."""
     from repro.serving import engine
     monkeypatch.setattr(engine, "_on_tpu", lambda p: True)
-    params = _cell_model("granite-4.0-h-small-10l").abstract_params()[0]
-    ops = engine.serve_operands(params)
+    model = _cell_model("granite-4.0-h-small-10l")
+    params = model.abstract_params()[0]
+    ops = engine.serve_operands(model.cfg, params)
     assert all(o is p for o, p in zip(jax.tree.leaves(ops),
                                       jax.tree.leaves(params)))
