@@ -234,8 +234,8 @@ def kernel(clock, dev):
     ks = jax.random.split(jax.random.PRNGKey(SEED), 4)
     p, _ = split_tree(init_attention(ks[0], cfg))
     num_pages = B * P + 1
-    cache = {"k": jax.random.normal(ks[1], (num_pages, ps, K, hd)),
-             "v": jax.random.normal(ks[2], (num_pages, ps, K, hd))}
+    cache = {"k": jax.random.normal(ks[1], (1, num_pages, ps, K, hd)),
+             "v": jax.random.normal(ks[2], (1, num_pages, ps, K, hd))}
     perm = np.random.default_rng(SEED).permutation(num_pages - 1) + 1
     page_map = jnp.asarray(perm.reshape(B, P), jnp.int32)
     pos = jnp.asarray([37, 100, P * ps - 1, 5], jnp.int32)
@@ -247,7 +247,7 @@ def kernel(clock, dev):
         # runs both at HIGHEST, the DEFAULT gap is reported alongside
         with jax.default_matmul_precision(precision):
             f = jax.jit(lambda x, c, pos, pm: paged_decode_attention(
-                p, cfg, x, c, pos, pm, use_kernel=use_kernel)[0])
+                p, cfg, x, c, pos, pm, use_kernel=use_kernel, layer=0)[0])
             return np.asarray(f(x, cache, pos, page_map))
 
     c0 = clock.total

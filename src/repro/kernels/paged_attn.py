@@ -10,7 +10,11 @@ hold the slot's band of live positions
 ``[max(0, pos - window + 1), pos]`` (:func:`block_band`). A lane that is
 not live (mid-prefill or retired) walks nothing.
 
-The pools stay in HBM (``memory_space=pl.ANY``); the kernel copies a
+The pools stay in HBM (``memory_space=pl.ANY``): the whole stack
+``(layers, num_pages, page_size, K, hd)`` that the serving step carries
+through its layer loop, with the layer index scalar-prefetched beside
+the page map, so no layer's pool is sliced out of the stack (one
+layer's pool is read as a stack of one). The kernel copies a
 block's pages by hand, one async copy per page, into a VMEM buffer, and
 double-buffers: the copies of the next live block (which may be the
 next live slot's first) start before the current block is computed. So
@@ -90,10 +94,11 @@ def block_band(pos, live, *, window: int, block_tokens: int,
     return first, count
 
 
-def _paged_decode_kernel(pm_ref, pos_ref, live_ref, q_ref, k_hbm, v_hbm,
-                         o_ref, k_buf, v_buf, sem, band_ref, m_ref, l_ref,
-                         acc_ref, *, page_size: int, pages_per_slot: int,
-                         pages_per_block: int, window: int, group: int):
+def _paged_decode_kernel(pm_ref, pos_ref, live_ref, layer_ref, q_ref, k_hbm,
+                         v_hbm, o_ref, k_buf, v_buf, sem, band_ref, m_ref,
+                         l_ref, acc_ref, *, page_size: int,
+                         pages_per_slot: int, pages_per_block: int,
+                         window: int, group: int):
     B, rows, hd = q_ref.shape                        # rows = K * G
     K = rows // group
     P, ppb = pages_per_slot, pages_per_block
@@ -117,8 +122,8 @@ def _paged_decode_kernel(pm_ref, pos_ref, live_ref, q_ref, k_hbm, v_hbm,
         """Page ``page`` of K (``kv`` 0) or V into page ``j`` of a block
         buffer; ``half`` picks one of the two buffers."""
         hbm, buf = (k_hbm, k_buf) if kv == 0 else (v_hbm, v_buf)
-        return pltpu.make_async_copy(hbm.at[page], buf.at[half, j],
-                                     sem.at[kv, half])
+        return pltpu.make_async_copy(hbm.at[layer_ref[0], page],
+                                     buf.at[half, j], sem.at[kv, half])
 
     def start(b, i, half):
         """One async copy per page of block ``i`` of slot ``b``."""
@@ -194,9 +199,9 @@ def _paged_decode_kernel(pm_ref, pos_ref, live_ref, q_ref, k_hbm, v_hbm,
     jax.lax.fori_loop(0, B, slot_body, 0)
 
 
-def _page_grid_kernel(pm_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
-                      m_ref, l_ref, *, page_size: int, pages_per_slot: int,
-                      window: int):
+def _page_grid_kernel(pm_ref, pos_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
+                      acc_ref, m_ref, l_ref, *, page_size: int,
+                      pages_per_slot: int, window: int):
     """One page of one slot per grid step (no band, no ``live``)."""
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -238,25 +243,23 @@ def _page_grid_kernel(pm_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
 
 
 def _page_grid_decode(q, k_pages, v_pages, page_map, pos, window: int,
-                      interpret: bool):
-    """Grid (slot, page): the pipeline fetches one page per step."""
+                      interpret: bool, layer=None):
+    """Grid (slot, page): the pipeline fetches one page of layer
+    ``layer`` per step."""
+    k_pages, v_pages, layer = _as_stack(k_pages, v_pages, layer)
     B, K, G, hd = q.shape
-    _, ps = k_pages.shape[:2]
+    ps = k_pages.shape[2]
     P = page_map.shape[1]
     kern = functools.partial(_page_grid_kernel, page_size=ps,
                              pages_per_slot=P, window=window)
+    page = pl.BlockSpec((None, 1, ps, K, hd), lambda b, j, pm, pos, ly:
+                        (ly[0], pm[b, j], 0, 0, 0))
+    slot = lambda b, j, pm, pos, ly: (b, 0, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                       # page_map, pos
+        num_scalar_prefetch=3,                       # page_map, pos, layer
         grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, K, G, hd), lambda b, j, pm, pos: (b, 0, 0, 0)),
-            pl.BlockSpec((1, ps, K, hd),
-                         lambda b, j, pm, pos: (pm[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, ps, K, hd),
-                         lambda b, j, pm, pos: (pm[b, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, K, G, hd),
-                               lambda b, j, pm, pos: (b, 0, 0, 0)),
+        in_specs=[pl.BlockSpec((1, K, G, hd), slot), page, page],
+        out_specs=pl.BlockSpec((1, K, G, hd), slot),
         scratch_shapes=[
             pltpu.VMEM((K, G, hd), jnp.float32),
             pltpu.VMEM((K, G), jnp.float32),
@@ -267,16 +270,28 @@ def _page_grid_decode(q, k_pages, v_pages, page_map, pos, window: int,
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), jnp.float32),
         interpret=interpret, name="paged_decode")
-    return fn(page_map.astype(jnp.int32), pos.astype(jnp.int32),
+    return fn(page_map.astype(jnp.int32), pos.astype(jnp.int32), layer,
               q.astype(jnp.float32), k_pages, v_pages)
 
 
+def _as_stack(k_pages, v_pages, layer):
+    """The pools as a stack, and the scalar-prefetched layer index: one
+    layer's pool is a stack of one (a leading unit axis is a bitcast)."""
+    if k_pages.ndim == 4:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    assert layer is not None, "stacked pools need their layer index"
+    return k_pages, v_pages, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def paged_decode(q, k_pages, v_pages, page_map, pos, *, window: int = 0,
-                 live=None, pages_per_block: Optional[int] = None,
+                 live=None, layer=None, pages_per_block: Optional[int] = None,
                  interpret: Optional[bool] = None):
     """Paged single-token attention.
 
-    q: (B, K, G, hd); k_pages/v_pages: (num_pages, page_size, K, hd);
+    q: (B, K, G, hd); k_pages/v_pages: stacked (layers, num_pages,
+    page_size, K, hd) pools read at layer ``layer`` (an int or traced
+    int32 scalar), or one layer's (num_pages, page_size, K, hd) pool,
+    read as a stack of one (a leading unit axis is a bitcast);
     page_map: (B, pages_per_slot) int32; pos: (B,) int32; live: (B,)
     bool or None (every lane live) — in the block walk a lane that is
     not live walks no page and returns zeros; its output is meant to be
@@ -287,13 +302,14 @@ def paged_decode(q, k_pages, v_pages, page_map, pos, *, window: int = 0,
     fp32 (caller projects).
     """
     interpret = resolve_interpret(interpret)
-    B, K, G, hd = q.shape
-    _, ps = k_pages.shape[:2]
-    P = page_map.shape[1]
     window = int(window)
-    if page_grid(hd, interpret):
+    if page_grid(q.shape[-1], interpret):
         return _page_grid_decode(q, k_pages, v_pages, page_map, pos,
-                                 window, interpret)
+                                 window, interpret, layer)
+    k_pages, v_pages, layer = _as_stack(k_pages, v_pages, layer)
+    B, K, G, hd = q.shape
+    ps = k_pages.shape[2]
+    P = page_map.shape[1]
     ppb = pages_per_block or block_pages(
         ps, P, K, hd, jnp.dtype(k_pages.dtype).itemsize, window)
     live = (jnp.ones((B,), jnp.int32) if live is None
@@ -303,7 +319,7 @@ def paged_decode(q, k_pages, v_pages, page_map, pos, *, window: int = 0,
                              window=window, group=G)
     whole = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,                       # page_map, pos, live
+        num_scalar_prefetch=4,             # page_map, pos, live, layer
         grid=(),
         in_specs=[whole(), pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
@@ -323,7 +339,8 @@ def paged_decode(q, k_pages, v_pages, page_map, pos, *, window: int = 0,
         out_shape=jax.ShapeDtypeStruct((B, K * G, hd), jnp.float32),
         interpret=interpret, name="paged_decode")
     out = fn(page_map.astype(jnp.int32), pos.astype(jnp.int32), live,
-             q.astype(jnp.float32).reshape(B, K * G, hd), k_pages, v_pages)
+             layer, q.astype(jnp.float32).reshape(B, K * G, hd), k_pages,
+             v_pages)
     return out.reshape(B, K, G, hd)
 
 

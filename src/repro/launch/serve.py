@@ -226,7 +226,7 @@ def main(argv=None):
         logits, cache, pos = prefill(params, batch)
     t_prefill = time.time() - t0
     decode = jax.jit(lambda p, t, c, s: model.decode_step(
-        p, t, c, s, dtype=jnp.float32), **jit_kw_dec)
+        p, t, c, s, dtype=jnp.float32), donate_argnums=(2,), **jit_kw_dec)
 
     out_tokens = []
     t0 = time.time()

@@ -414,7 +414,7 @@ def build_paged_serve_program(model: ModelApi, mesh: Mesh, *,
                       sh.replicated, sh.replicated, sh.replicated,
                       sh.replicated, sh.replicated),
         out_shardings=(sh.paged_cache, sh.logits),
-        donate_argnums=(1,),
+        donate_argnums=(1, 2),
     )
     adm_args = (params_abs, cache_abs, logits_abs,
                 jax.ShapeDtypeStruct((1, prefill_chunk), i32),

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import Px, dense_init, zeros_init, rope
+from repro.models.layout import read, read_pages, write_rows
 
 NEG_INF = -1e30
 
@@ -589,24 +590,24 @@ def paged_cache_logical_axes():
     return {"k": ax, "v": ax}
 
 
-def _paged_scatter(kv, k_new, v_new, flat):
-    """Write per-row K/V (B, K, hd) at flat page offsets (B,) into the
-    (num_pages, page_size, K, hd) pool; returns the updated pool pair.
-    Rows routed to the dummy page may collide — nobody reads page 0
-    unmasked, so last-writer-wins is fine."""
-    N, ps = kv["k"].shape[:2]
-    kf = kv["k"].reshape((N * ps,) + kv["k"].shape[2:])
-    vf = kv["v"].reshape((N * ps,) + kv["v"].shape[2:])
-    kf = kf.at[flat].set(k_new.astype(kf.dtype))
-    vf = vf.at[flat].set(v_new.astype(vf.dtype))
-    return kf.reshape(kv["k"].shape), vf.reshape(kv["v"].shape)
+def _paged_scatter(kv, k_new, v_new, flat, layer):
+    """Write per-row K/V (B, K, hd) at flat page offsets (B,) into layer
+    ``layer`` of the stacked (layers, num_pages, page_size, K, hd)
+    pools; returns the updated pool pair. Rows routed to the dummy page
+    may collide — nobody reads page 0 unmasked, so last-writer-wins is
+    fine."""
+    return (write_rows(kv["k"], layer, flat, k_new),
+            write_rows(kv["v"], layer, flat, v_new))
 
 
 def paged_decode_attention(p, cfg, x, cache, pos, page_map, *, window=0,
-                           live=None, use_kernel=False, interpret=None):
+                           live=None, use_kernel=False, interpret=None,
+                           layer):
     """One-token attention step against a PAGED cache.
 
-    x: (B, 1, d); cache: {'k','v'} (num_pages, page_size, K, hd);
+    x: (B, 1, d); cache: {'k','v'} stacked (layers, num_pages,
+    page_size, K, hd) pools, of which ``layer`` (an int or traced
+    scalar) is this layer's;
     pos: (B,) absolute positions; page_map: (B, pages_per_slot) int32 —
     each slot's logical pages in position order (dummy page 0 for
     unallocated entries). Unlike the ring path, the paged cache stores
@@ -633,7 +634,7 @@ def paged_decode_attention(p, cfg, x, cache, pos, page_map, *, window=0,
     k_new = hint(k_new, ("pod", "data"), None, "model", None)
     v_new = hint(v_new, ("pod", "data"), None, "model", None)
 
-    N, ps = cache["k"].shape[:2]
+    ps = cache["k"].shape[-3]
     P = page_map.shape[1]
     # the new token's page: slots mid-prefill / retired carry an
     # all-dummy page-map row, so their write lands in the page-0 sink
@@ -642,16 +643,18 @@ def paged_decode_attention(p, cfg, x, cache, pos, page_map, *, window=0,
                              axis=1)[:, 0]
     flat = pg * ps + pos % ps                        # (B,)
     k_pages, v_pages = _paged_scatter(cache, k_new[:, 0], v_new[:, 0],
-                                      flat)
+                                      flat, layer)
 
     if use_kernel:
         from repro.kernels.paged_attn import paged_decode
         out = paged_decode(q[:, 0], k_pages, v_pages, page_map, pos,
-                           window=window, live=live, interpret=interpret)
+                           window=window, live=live, layer=layer,
+                           interpret=interpret)
         out = out[:, None].astype(x.dtype)           # (B, 1, K, G, hd)
     else:
-        kg = k_pages[page_map].reshape(B, P * ps, *k_pages.shape[2:])
-        vg = v_pages[page_map].reshape(B, P * ps, *v_pages.shape[2:])
+        kv_shape = (B, P * ps) + k_pages.shape[-2:]
+        kg = read_pages(k_pages, layer, page_map).reshape(kv_shape)
+        vg = read_pages(v_pages, layer, page_map).reshape(kv_shape)
         scale = cfg.head_dim ** -0.5
         s = _gqa_scores(q * scale, kg.astype(q.dtype))   # (B,K,G,1,S)
         k_pos = jnp.arange(P * ps)
@@ -666,10 +669,14 @@ def paged_decode_attention(p, cfg, x, cache, pos, page_map, *, window=0,
 
 
 def decode_attention(p, cfg, x, cache, pos, *, window=0,
-                     kv_source_cache=None):
+                     kv_source_cache=None, layer=None):
     """One-token attention step.
 
-    x: (B, 1, d); cache: {'k','v'} (B, S, K, hd); pos: int32 scalar or
+    x: (B, 1, d); cache: {'k','v'} the stacked (layers, B, S, K, hd)
+    ring, of which ``layer`` (an int or traced scalar) is this layer's
+    (the new row is written there, the layer read in place);
+    ``kv_source_cache`` (B, S, K, hd) is read as this layer's own and
+    takes no ``layer``; pos: int32 scalar or
     ``(B,)`` vector — the absolute position of each slot's new token
     (a scalar broadcasts to all slots). Returns (out, new_cache).
 
@@ -707,19 +714,19 @@ def decode_attention(p, cfg, x, cache, pos, *, window=0,
     k_new = hint(k_new, ("pod", "data"), None, "model", None)
     v_new = hint(v_new, ("pod", "data"), None, "model", None)
 
-    S = cache["k"].shape[1]
+    S = cache["k"].shape[-3]
     slot = jnp.where(window > 0, pos % jnp.maximum(S, 1), pos)
     slot = jnp.minimum(slot, S - 1)                  # (B,)
+    rows = {"k": k_new, "v": v_new}
     if per_slot:
-        bi = jnp.arange(B)
-        k = cache["k"].at[bi, slot].set(k_new[:, 0].astype(cache["k"].dtype))
-        v = cache["v"].at[bi, slot].set(v_new[:, 0].astype(cache["v"].dtype))
+        new = {n: cache[n].at[layer, jnp.arange(B), slot].set(
+            r[:, 0].astype(cache[n].dtype)) for n, r in rows.items()}
     else:
         # aligned batch: one contiguous slice update beats a scatter
-        k = jax.lax.dynamic_update_slice(
-            cache["k"], k_new.astype(cache["k"].dtype), (0, slot[0], 0, 0))
-        v = jax.lax.dynamic_update_slice(
-            cache["v"], v_new.astype(cache["v"].dtype), (0, slot[0], 0, 0))
+        new = {n: jax.lax.dynamic_update_slice(
+            cache[n], r[None].astype(cache[n].dtype),
+            (layer, 0, slot[0], 0, 0)) for n, r in rows.items()}
+    k, v = read(new["k"], layer), read(new["v"], layer)
 
     scale = cfg.head_dim ** -0.5
     s = _gqa_scores(q * scale, k.astype(q.dtype))    # (B,K,G,1,S)
@@ -733,4 +740,4 @@ def decode_attention(p, cfg, x, cache, pos, *, window=0,
     w = jax.nn.softmax(s, axis=-1)
     out = _gqa_out(w, v.astype(q.dtype)).astype(x.dtype)
     out = out.reshape(B, 1, cfg.num_heads * cfg.head_dim)
-    return out @ p["wo"].astype(x.dtype), {"k": k, "v": v}
+    return out @ p["wo"].astype(x.dtype), new

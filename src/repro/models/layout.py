@@ -31,6 +31,7 @@ import functools
 from typing import Callable, NamedTuple, Optional
 
 import jax
+import jax.numpy as jnp
 
 # kinds whose requests carry frontend inputs (patch or frame embeddings):
 # the request schedulers and the paged cache serve every other kind
@@ -158,13 +159,22 @@ def _make_body(roles, make, key):
 def run(segs, params, x, bodies: dict, cache: Optional[dict] = None,
         remat: bool = False):
     """Walk the segments ``segs`` over ``x``: one ``jax.lax.scan`` per scanned
-    segment over ``params[key]`` (or ``(params[key], cache[key])``), the
-    unrolled segment in a Python loop, one ``bodies[role](lp, x)`` (or
-    ``(lp, x, c)``) call per layer, each returning ``(x, out)``.
-    ``remat`` wraps each scanned body in ``jax.checkpoint``.
+    segment over ``params[key]``, the unrolled segment in a Python loop,
+    one ``bodies[role](lp, x)`` call per layer, each returning
+    ``(x, out)``. ``remat`` wraps each scanned body in ``jax.checkpoint``.
 
-    Returns ``(x, {key: outs})``: the per-layer outputs (new cache, MoE
-    aux or None) in the tree structure of the segment's params.
+    With ``cache``, a scanned segment carries its stacked cache tree
+    ``cache[key]`` through the scan next to ``x``, so a caller that
+    donates the cache has it updated in place: each call is
+    ``bodies[role](lp, x, c, i)`` with the whole stacked tree ``c`` and
+    the layer index ``i``, and returns ``(x, c)`` with only layer ``i``
+    rewritten (:func:`read`, :func:`write`, :func:`read_pages`,
+    :func:`write_rows`). An unrolled layer gets its own tree as a stack
+    of one, with ``i`` 0.
+
+    Returns ``(x, {key: outs})``: without a cache the per-layer outputs
+    (MoE aux or None) in the tree structure of the segment's params;
+    with one, the new cache.
     """
     outs = {}
     for seg in segs:
@@ -172,30 +182,85 @@ def run(segs, params, x, bodies: dict, cache: Optional[dict] = None,
         c = None if cache is None else cache[seg.key]
         if not seg.scanned:
             outs[seg.key] = []
-            for i, role in seg.roles:
-                x, out = _call(bodies[role], p[i], x,
-                               None if c is None else c[i])
+            for j, role in seg.roles:
+                if c is None:
+                    x, out = bodies[role](p[j], x)
+                else:
+                    one = jax.tree.map(lambda a: a[None], c[j])
+                    x, out = bodies[role](p[j], x, one, 0)
+                    out = jax.tree.map(lambda a: a[0], out)
                 outs[seg.key].append(out)
             continue
         body = functools.partial(_run_body, seg.roles, bodies)
         fn = jax.checkpoint(body) if remat else body
         if c is None:
             x, outs[seg.key] = jax.lax.scan(lambda xx, lp: fn(lp, xx), x, p)
-        else:
-            x, outs[seg.key] = jax.lax.scan(
-                lambda xx, pc: fn(pc[0], xx, pc[1]), x, (p, c))
+            continue
+
+        def step(carry, lp_i, fn=fn):
+            return fn(lp_i[0], *carry, lp_i[1]), None
+        (x, outs[seg.key]), _ = jax.lax.scan(
+            step, (x, c), (p, jnp.arange(seg.n, dtype=jnp.int32)))
     return x, outs
 
 
-def _call(body, lp, x, c):
-    return body(lp, x) if c is None else body(lp, x, c)
+def _call(body, lp, x, c, i):
+    return body(lp, x) if c is None else body(lp, x, c, i)
 
 
-def _run_body(roles, bodies, lp, x, c=None):
+def _run_body(roles, bodies, lp, x, c=None, i=None):
     if roles[0][0] is None:
-        return _call(bodies[roles[0][1]], lp, x, c)
+        return _call(bodies[roles[0][1]], lp, x, c, i)
     outs = {}
     for sub, role in roles:
         x, outs[sub] = _call(bodies[role], lp[sub], x,
-                             None if c is None else c[sub])
+                             None if c is None else c[sub], i)
     return x, outs
+
+
+# ---------------------------------------------------------------------------
+# one layer of a carried cache
+# ---------------------------------------------------------------------------
+
+def _start(leaf, i, slot):
+    lead = (i,) if slot is None else (i, slot)
+    return lead, lead + (0,) * (leaf.ndim - len(lead))
+
+
+def read(tree, i, slot=None):
+    """Layer ``i`` of a stacked cache tree: each leaf's slab, or with
+    ``slot`` its lane ``slot`` (kept as an axis of 1). One dynamic slice
+    per leaf."""
+    def one(leaf):
+        lead, start = _start(leaf, i, slot)
+        return jax.lax.dynamic_slice(
+            leaf, start, (1,) * len(lead) + leaf.shape[len(lead):])[0]
+    return jax.tree.map(one, tree)
+
+
+def write(tree, i, new, slot=None):
+    """``tree`` with ``new`` written where :func:`read` reads: one
+    dynamic-update-slice per leaf, in place when the tree is a donated
+    scan carry (a ``where`` that makes ``new`` fuses into it)."""
+    def one(leaf, val):
+        _, start = _start(leaf, i, slot)
+        return jax.lax.dynamic_update_slice(
+            leaf, val.astype(leaf.dtype)[None], start)
+    return jax.tree.map(one, tree, new)
+
+
+def read_pages(pool, i, index):
+    """Pages ``index`` of layer ``i`` of a stacked page pool
+    ``(layers, num_pages, ...)``: one gather, ``pool[i, index]``. Slicing
+    the layer out first would copy its whole pool."""
+    return pool[i, index]
+
+
+def write_rows(pool, i, flat, rows):
+    """``pool`` with ``rows`` (B, ...) written at the flat page offsets
+    ``flat`` (B,) (``page * page_size + offset``) of layer ``i``: one
+    scatter of B rows."""
+    L, n, ps = pool.shape[:3]
+    f = pool.reshape((L, n * ps) + pool.shape[3:])
+    f = f.at[i, flat].set(rows.astype(f.dtype))
+    return f.reshape(pool.shape)

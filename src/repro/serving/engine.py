@@ -3,10 +3,13 @@ for every arch family.
 
 Cache layout: one pytree per model with the params' layer trees
 (``models/layout.py``): scanned stacks' leaves carry a leading
-``layers`` axis and are threaded through ``lax.scan`` together with the
-layer parameters — the decode step is a single compact HLO program
-regardless of depth. Every phase walks the layout with ``layout.run``
-and its own per-role layer bodies.
+``layers`` axis. Prefill builds them as the scan's per-layer outputs;
+decode and chunked prefill carry the stacked tree through the scan
+beside the activations, each layer reading and writing only its own
+rows (``layout.read``/``write``/``read_pages``/``write_rows``), so a
+caller that donates the cache has it updated in place. The decode step
+is a single compact HLO program regardless of depth. Every phase walks
+the layout with ``layout.run`` and its own per-role layer bodies.
 
 Sliding-window archs (and the *sliding-window serving variant* used for
 ``long_500k`` on full-attention archs) keep a **ring buffer** of
@@ -490,16 +493,16 @@ def decode_step(p, cfg, token, cache, pos, *, dtype=jnp.bfloat16,
 
     w = effective_window(cfg, serve_window)
 
-    def attn_decode(lp, xx, c, *, cross=False):
+    def attn_decode(lp, xx, c, i, *, cross=False):
         h = apply_norm(cfg, lp["ln_attn"], xx)
-        ring = w if (c["k"].shape[1] == w and w) else 0
+        ring = w if (c["k"].shape[-3] == w and w) else 0
         out, c_new = attn.decode_attention(lp["attn"], cfg, h,
                                            {"k": c["k"], "v": c["v"]},
-                                           pos, window=ring)
+                                           pos, window=ring, layer=i)
         xx = xx + out
         if cross and "cross" in lp:
             h = apply_norm(cfg, lp["ln_cross"], xx)
-            kv = {"k": c["cross_k"], "v": c["cross_v"]}
+            kv = layout.read({"k": c["cross_k"], "v": c["cross_v"]}, i)
             out, _ = attn.decode_attention(lp["cross"], cfg, h, {},
                                            pos, kv_source_cache=kv)
             xx = xx + out
@@ -512,31 +515,33 @@ def decode_step(p, cfg, token, cache, pos, *, dtype=jnp.bfloat16,
         new["k"], new["v"] = c_new["k"], c_new["v"]
         return xx + h, new
 
-    def ssm_decode(lp, xx, c):
+    def ssm_decode(lp, xx, c, i):
         h = apply_norm(cfg, lp["ln"], xx)
-        y, c_new = ssmm.decode_ssm(lp["ssm"], cfg, h, c)
-        return xx + y, c_new
+        y, c_new = ssmm.decode_ssm(lp["ssm"], cfg, h, layout.read(c, i))
+        return xx + y, layout.write(c, i, c_new)
 
-    def rec_decode(lp, xx, c):
+    def rec_decode(lp, xx, c, i):
         h = apply_norm(cfg, lp["ln_rec"], xx)
-        y, c_new = rgm.decode_rglru(lp["rec"], cfg, h, c)
+        y, c_new = rgm.decode_rglru(lp["rec"], cfg, h, layout.read(c, i))
         xx = xx + y
         xx = xx + mlpm.apply_mlp(lp["mlp"], cfg,
                                  apply_norm(cfg, lp["ln_mlp"], xx))
-        return xx, c_new
+        return xx, layout.write(c, i, c_new)
 
-    def mamba_dec(lp, xx, c):
+    def mamba_dec(lp, xx, c, i):
         with jax.named_scope("ssm_mixer"):
-            y, c = ssmm.decode_ssm(
-                lp["ssm"], cfg, apply_norm(cfg, lp["ln"], xx), c)
+            y, c_new = ssmm.decode_ssm(
+                lp["ssm"], cfg, apply_norm(cfg, lp["ln"], xx),
+                layout.read(c, i))
+            c = layout.write(c, i, c_new)
         return hybrid_ffn(lp, cfg, hybrid_residual(cfg, xx, y)), c
 
-    def attention_dec(lp, xx, c):
+    def attention_dec(lp, xx, c, i):
         with jax.named_scope("attn_mixer"):
-            ring = w if (c["k"].shape[1] == w and w) else 0
+            ring = w if (c["k"].shape[-3] == w and w) else 0
             y, c = attn.decode_attention(
                 lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], xx),
-                c, pos, window=ring)
+                c, pos, window=ring, layer=i)
         return hybrid_ffn(lp, cfg, hybrid_residual(cfg, xx, y)), c
 
     bodies = {
@@ -711,20 +716,12 @@ def paged_cache_logical_axes_tree(cfg):
         _stack_axes)
 
 
-def _slot_slice(leaf, slot):
-    return jax.lax.dynamic_slice_in_dim(leaf, slot, 1, axis=0)
-
-
-def _slot_write(leaf, val, slot):
-    return jax.lax.dynamic_update_slice_in_dim(
-        leaf, val.astype(leaf.dtype), slot, axis=0)
-
-
 def _chunk_attn_layer(lp, cfg, x, kv, *, mode, window, start, valid,
-                      page_row):
+                      page_row, layer):
     """One attn layer over a prefill chunk, writing K/V into pages.
 
-    x: (1, C, d); kv: {'k','v'} page pools; start/valid: traced scalars
+    x: (1, C, d); kv: {'k','v'} stacked page pools, of which ``layer``
+    is this layer's; start/valid: traced scalars
     (chunk offset, #real tokens in the chunk); page_row:
     (pages_per_slot,) this slot's pages. Rows j >= valid are padding:
     their writes go to the dummy page, their queries never feed the
@@ -733,7 +730,7 @@ def _chunk_attn_layer(lp, cfg, x, kv, *, mode, window, start, valid,
     h = apply_norm(cfg, lp["ln_attn"], x)
     out, new_kv = _chunk_attn_mixer(lp["attn"], cfg, h, kv, mode=mode,
                                     window=window, start=start, valid=valid,
-                                    page_row=page_row)
+                                    page_row=page_row, layer=layer)
     x = x + out
 
     h = apply_norm(cfg, lp["ln_mlp"], x)
@@ -746,7 +743,7 @@ def _chunk_attn_layer(lp, cfg, x, kv, *, mode, window, start, valid,
 
 
 def _chunk_attn_mixer(pa, cfg, h, kv, *, mode, window, start, valid,
-                      page_row):
+                      page_row, layer):
     """The attention of ``_chunk_attn_layer`` on its normed input h:
     returns (output projection, updated page pools)."""
     from repro.dist.sharding import hint
@@ -766,16 +763,17 @@ def _chunk_attn_mixer(pa, cfg, h, kv, *, mode, window, start, valid,
     k = hint(k, ("pod", "data"), None, "model", None)
     v = hint(v, ("pod", "data"), None, "model", None)
 
-    N, ps = kv["k"].shape[:2]
+    ps = kv["k"].shape[-3]
     P = page_row.shape[0]
     j = jnp.arange(C)
     tgt = start + j                                  # absolute positions
     pg = page_row[jnp.clip(tgt // ps, 0, P - 1)]
     flat = jnp.where(j < valid, pg * ps + tgt % ps, j % ps)
-    k_pages, v_pages = attn._paged_scatter(kv, k[0], v[0], flat)
+    k_pages, v_pages = attn._paged_scatter(kv, k[0], v[0], flat, layer)
 
-    kg = k_pages[page_row].reshape(1, P * ps, *k_pages.shape[2:])
-    vg = v_pages[page_row].reshape(1, P * ps, *v_pages.shape[2:])
+    kv_shape = (1, P * ps) + k_pages.shape[-2:]
+    kg = layout.read_pages(k_pages, layer, page_row).reshape(kv_shape)
+    vg = layout.read_pages(v_pages, layer, page_row).reshape(kv_shape)
     out = attn.simple_attention(q, kg.astype(q.dtype), vg.astype(q.dtype),
                                 mode=mode, window=window, q_offset=start,
                                 k_len=start + valid)
@@ -783,28 +781,29 @@ def _chunk_attn_mixer(pa, cfg, h, kv, *, mode, window, start, valid,
     return out @ pa["wo"].astype(h.dtype), {"k": k_pages, "v": v_pages}
 
 
-def _chunk_ssm_layer(lp, cfg, x, c, *, slot, start, valid):
+def _chunk_ssm_layer(lp, cfg, x, c, *, slot, start, valid, layer):
     """One SSM layer over a prefill chunk, carrying slot state across
     chunks: conv context + SSD ``h0`` are read from (and written back
-    to) the per-slot cache leaves; ``start == 0`` starts fresh."""
+    to) the slot's lane of layer ``layer`` of the stacked cache leaves;
+    ``start == 0`` starts fresh."""
     return _chunk_ssm_mixer(lp["ssm"], cfg, apply_norm(cfg, lp["ln"], x),
                             c, slot=slot, start=start, valid=valid,
-                            residual=lambda y: x + y)
+                            residual=lambda y: x + y, layer=layer)
 
 
-def _chunk_ssm_mixer(ps, cfg, h, c, *, slot, start, valid, residual):
+def _chunk_ssm_mixer(ps, cfg, h, c, *, slot, start, valid, residual,
+                     layer):
     """The Mamba-2 block of ``_chunk_ssm_layer`` on its normed input h:
     returns (``residual(output projection)``, updated per-slot state).
     The residual add stays before the state writes, where the ``ssm``
-    kind's program has always had it, so that program is unchanged."""
+    kind's program has always had it."""
     b, C, _ = h.shape
     d_in, H, P, S = ssmm._dims(cfg)
     K = cfg.ssm_conv_width
     fresh = start == 0
-    h0 = jnp.where(fresh, 0.0, _slot_slice(c["h"], slot))
-    cx0 = jnp.where(fresh, 0.0, _slot_slice(c["conv_x"], slot))
-    cB0 = jnp.where(fresh, 0.0, _slot_slice(c["conv_B"], slot))
-    cC0 = jnp.where(fresh, 0.0, _slot_slice(c["conv_C"], slot))
+    old = layout.read(c, layer, slot)
+    h0, cx0, cB0, cC0 = (jnp.where(fresh, 0.0, old[n])
+                         for n in ("h", "conv_x", "conv_B", "conv_C"))
 
     proj = h @ ps["w_in"].astype(h.dtype)
     z, xs, Bm, Cm, dt_raw = ssmm._split_proj(cfg, proj)
@@ -831,17 +830,12 @@ def _chunk_ssm_mixer(ps, cfg, h, c, *, slot, start, valid, residual):
         xp = jnp.concatenate([state0.astype(pre.dtype), pre], axis=1)
         return jax.lax.dynamic_slice_in_dim(xp, valid, K - 1, axis=1)
 
-    new = {"h": _slot_write(c["h"], h_fin, slot),
-           "conv_x": _slot_write(c["conv_x"],
-                                 conv_next(cx0, xs_pre), slot),
-           "conv_B": _slot_write(c["conv_B"],
-                                 conv_next(cB0, Bm_pre), slot),
-           "conv_C": _slot_write(c["conv_C"],
-                                 conv_next(cC0, Cm_pre), slot)}
-    return x, new
+    new = {"h": h_fin, "conv_x": conv_next(cx0, xs_pre),
+           "conv_B": conv_next(cB0, Bm_pre), "conv_C": conv_next(cC0, Cm_pre)}
+    return x, layout.write(c, layer, new, slot)
 
 
-def _chunk_rec_layer(lp, cfg, x, c, *, slot, start, valid):
+def _chunk_rec_layer(lp, cfg, x, c, *, slot, start, valid, layer):
     """One RG-LRU layer over a prefill chunk with carried (h, conv)
     state: the inbound hidden state is folded into the first scan
     element (h_0 = a_0 h_in + b_0), which continues the recurrence
@@ -853,8 +847,9 @@ def _chunk_rec_layer(lp, cfg, x, c, *, slot, start, valid):
     xb = h @ lp["rec"]["w_rec"].astype(dt)
     xb_pre = xb
     fresh = start == 0
-    h0 = jnp.where(fresh, 0.0, _slot_slice(c["h"], slot))   # (1, w)
-    conv0 = jnp.where(fresh, 0.0, _slot_slice(c["conv"], slot))
+    old = layout.read(c, layer, slot)
+    h0 = jnp.where(fresh, 0.0, old["h"])                    # (1, w)
+    conv0 = jnp.where(fresh, 0.0, old["conv"])
     xb, _ = rgm._causal_conv(xb, lp["rec"]["conv"], conv0)
     a, beta = rgm._gates(lp["rec"], xb)
     b = beta * xb.astype(jnp.float32)
@@ -878,9 +873,7 @@ def _chunk_rec_layer(lp, cfg, x, c, *, slot, start, valid):
         conv1 = jax.lax.dynamic_slice_in_dim(xp, valid, K - 1, axis=1)
     else:
         conv1 = conv0
-    new = {"h": _slot_write(c["h"], h_last, slot),
-           "conv": _slot_write(c["conv"], conv1, slot)}
-    return x, new
+    return x, layout.write(c, layer, {"h": h_last, "conv": conv1}, slot)
 
 
 def prefill_chunk(p, cfg, cache, tokens, start, valid, page_row, slot,
@@ -915,39 +908,42 @@ def prefill_chunk(p, cfg, cache, tokens, start, valid, page_row, slot,
     elif serve_window and kind not in ("ssm", "hybrid"):
         mode, window = "sliding", serve_window
 
-    def attn_body(lp, xx, c):
+    def attn_body(lp, xx, c, i):
         return _chunk_attn_layer(lp, cfg, xx, c, mode=mode, window=window,
                                  start=start, valid=valid,
-                                 page_row=page_row)
+                                 page_row=page_row, layer=i)
 
-    def local_attn_body(lp, xx, c):
+    def local_attn_body(lp, xx, c, i):
         return _chunk_attn_layer(lp, cfg, xx, c, mode="sliding",
                                  window=cfg.attention_window, start=start,
-                                 valid=valid, page_row=page_row)
+                                 valid=valid, page_row=page_row, layer=i)
 
     # pad rows (j >= valid) run through the experts like the others:
     # the expert layer is row-independent, so they touch no real row
-    def mamba_body(lp, xx, c):
+    def mamba_body(lp, xx, c, i):
         with jax.named_scope("ssm_mixer"):
             xx, c = _chunk_ssm_mixer(
                 lp["ssm"], cfg, apply_norm(cfg, lp["ln"], xx), c,
                 slot=slot, start=start, valid=valid,
-                residual=functools.partial(hybrid_residual, cfg, xx))
+                residual=functools.partial(hybrid_residual, cfg, xx),
+                layer=i)
         return hybrid_ffn(lp, cfg, xx), c
 
-    def attention_body(lp, xx, c):
+    def attention_body(lp, xx, c, i):
         with jax.named_scope("attn_mixer"):
             y, c = _chunk_attn_mixer(
                 lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], xx), c,
                 mode=mode, window=window, start=start, valid=valid,
-                page_row=page_row)
+                page_row=page_row, layer=i)
         return hybrid_ffn(lp, cfg, hybrid_residual(cfg, xx, y)), c
 
     state = dict(slot=slot, start=start, valid=valid)
     bodies = {
         "attn": attn_body, "moe": attn_body, "local_attn": local_attn_body,
-        "ssm": lambda lp, xx, c: _chunk_ssm_layer(lp, cfg, xx, c, **state),
-        "rec": lambda lp, xx, c: _chunk_rec_layer(lp, cfg, xx, c, **state),
+        "ssm": lambda lp, xx, c, i: _chunk_ssm_layer(lp, cfg, xx, c,
+                                                     layer=i, **state),
+        "rec": lambda lp, xx, c, i: _chunk_rec_layer(lp, cfg, xx, c,
+                                                     layer=i, **state),
         "mamba": mamba_body, "attention": attention_body,
     }
     x, new_cache = layout.run(layout.layer_layout(cfg), p, x, bodies,
@@ -960,13 +956,15 @@ def prefill_chunk(p, cfg, cache, tokens, start, valid, page_row, slot,
     return new_cache, logits
 
 
-def _gate_live(new, old, live):
-    """Keep ``old`` on non-live lanes (mid-prefill / retired slots must
-    not have their carried recurrent state trampled by decode ticks).
-    Leaves with a leading slots axis only — page pools self-protect via
-    the dummy page."""
-    m = live.reshape((-1,) + (1,) * (new.ndim - 1))
-    return jnp.where(m, new, old)
+def _write_live(c, i, new, old, live):
+    """``c`` with layer ``i``'s recurrent state set to ``new`` on live
+    lanes and kept ``old`` on the others (mid-prefill / retired slots
+    must not have their carried state trampled by decode ticks): the
+    ``where`` fuses into the layer's write. Leaves with a leading slots
+    axis only — page pools self-protect via the dummy page."""
+    def gate(n, o):
+        return jnp.where(live.reshape((-1,) + (1,) * (n.ndim - 1)), n, o)
+    return layout.write(c, i, jax.tree.map(gate, new, old))
 
 
 def decode_step_paged(p, cfg, token, cache, pos, page_map, live, *,
@@ -989,11 +987,11 @@ def decode_step_paged(p, cfg, token, cache, pos, page_map, live, *,
     x = _embed_tokens(p, cfg, token, dtype)
     w = effective_window(cfg, serve_window)
 
-    def attn_dec(lp, xx, c):
+    def attn_dec(lp, xx, c, i):
         h = apply_norm(cfg, lp["ln_attn"], xx)
         out, c_new = attn.paged_decode_attention(
             lp["attn"], cfg, h, c, pos, page_map, window=w, live=live,
-            use_kernel=use_kernel)
+            use_kernel=use_kernel, layer=i)
         xx = xx + out
         h = apply_norm(cfg, lp["ln_mlp"], xx)
         if "moe" in lp:
@@ -1002,35 +1000,35 @@ def decode_step_paged(p, cfg, token, cache, pos, page_map, live, *,
             h = mlpm.apply_mlp(lp["mlp"], cfg, h)
         return xx + h, c_new
 
-    def ssm_dec(lp, xx, c):
+    def ssm_dec(lp, xx, c, i):
         h = apply_norm(cfg, lp["ln"], xx)
-        y, c_new = ssmm.decode_ssm(lp["ssm"], cfg, h, c)
-        c_new = jax.tree.map(lambda n, o: _gate_live(n, o, live), c_new, c)
-        return xx + y, c_new
+        old = layout.read(c, i)
+        y, c_new = ssmm.decode_ssm(lp["ssm"], cfg, h, old)
+        return xx + y, _write_live(c, i, c_new, old, live)
 
-    def rec_dec(lp, xx, c):
+    def rec_dec(lp, xx, c, i):
         h = apply_norm(cfg, lp["ln_rec"], xx)
-        y, c_new = rgm.decode_rglru(lp["rec"], cfg, h, c)
-        c_new = jax.tree.map(lambda n, o: _gate_live(n, o, live), c_new, c)
+        old = layout.read(c, i)
+        y, c_new = rgm.decode_rglru(lp["rec"], cfg, h, old)
         xx = xx + y
         xx = xx + mlpm.apply_mlp(lp["mlp"], cfg,
                                  apply_norm(cfg, lp["ln_mlp"], xx))
-        return xx, c_new
+        return xx, _write_live(c, i, c_new, old, live)
 
-    def mamba_dec(lp, xx, c):
+    def mamba_dec(lp, xx, c, i):
         with jax.named_scope("ssm_mixer"):
+            old = layout.read(c, i)
             y, c_new = ssmm.decode_ssm(
-                lp["ssm"], cfg, apply_norm(cfg, lp["ln"], xx), c)
-            c_new = jax.tree.map(
-                lambda n, o: _gate_live(n, o, live), c_new, c)
-        return hybrid_ffn(lp, cfg, hybrid_residual(cfg, xx, y)), c_new
+                lp["ssm"], cfg, apply_norm(cfg, lp["ln"], xx), old)
+            c = _write_live(c, i, c_new, old, live)
+        return hybrid_ffn(lp, cfg, hybrid_residual(cfg, xx, y)), c
 
-    def attention_dec(lp, xx, c):
+    def attention_dec(lp, xx, c, i):
         with jax.named_scope("attn_mixer"):
             y, c_new = attn.paged_decode_attention(
                 lp["attn"], cfg, apply_norm(cfg, lp["ln_attn"], xx),
                 c, pos, page_map, window=w, live=live,
-                use_kernel=use_kernel)
+                use_kernel=use_kernel, layer=i)
         return hybrid_ffn(lp, cfg, hybrid_residual(cfg, xx, y)), c_new
 
     bodies = {

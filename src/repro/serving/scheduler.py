@@ -19,6 +19,11 @@ Both right-pad prompts to ``max_prompt`` and pass per-request
 ``lengths`` to prefill, so padded prefixes never enter attention and
 per-request generation budgets are enforced without any per-step
 host sync.
+
+A scheduler owns its cache: every jitted program that takes the cache
+and returns the new one donates it (and the held logits where it
+returns those), so the layer loop updates it in place, and the arrays
+passed in are consumed (DESIGN.md §15).
 """
 from __future__ import annotations
 
@@ -168,6 +173,8 @@ class _SchedulerBase:
         self._held = {}
         self.operand_casts = 0
         self.operand_bytes = 0
+        # program calls that consumed the cache passed in (donated)
+        self.cache_donated = 0
         temperature = self.temperature
 
         def serve_sample(logits, key):
@@ -282,8 +289,10 @@ class _SchedulerBase:
             return emitted
         with obs.span("sched.decode_step", step=self.clock):
             with self._mesh_ctx():
+                cache = self._cache
                 self._last_logits, self._cache = self._decode(
-                    params, tok, self._cache, self._pos)
+                    params, tok, cache, self._pos)
+                self._count_donated(cache)
         self._pos = self._pos + 1
         self.stats.decode_steps += 1
         self.stats.slot_steps += self.slots
@@ -292,6 +301,11 @@ class _SchedulerBase:
             for i, r in enumerate(self.active))
         return emitted
 
+    def _count_donated(self, cache) -> None:
+        """Count a program call after which ``cache``, passed in, was
+        consumed: how often donation lets the cache update in place."""
+        self.cache_donated += jax.tree.leaves(cache)[0].is_deleted()
+
     def counters(self) -> dict:
         """The ``sched.step`` span's counters, read as the step ends."""
         return {"queue_depth": len(self.queue),
@@ -299,7 +313,8 @@ class _SchedulerBase:
                 "decode_steps": self.stats.decode_steps,
                 "prefills": self.stats.prefills,
                 "operand_casts": self.operand_casts,
-                "operand_bytes": self.operand_bytes}
+                "operand_bytes": self.operand_bytes,
+                "cache_donated": self.cache_donated}
 
     def _operands(self, params):
         """The tree the programs compute with: ``params`` with its
@@ -380,7 +395,8 @@ class BatchScheduler(_SchedulerBase):
                                  cache_len=max_total, lengths=l)
 
         self._prefill = jax.jit(serve_prefill, **jit_kw_pf)
-        self._decode = jax.jit(_serve_decode(model), **jit_kw_dec)
+        self._decode = jax.jit(_serve_decode(model), donate_argnums=(2,),
+                               **jit_kw_dec)
         self._cache = None
         self._pos = None            # (slots,) per-slot absolute position
         self._last_logits = None
@@ -474,8 +490,10 @@ class ContinuousScheduler(_SchedulerBase):
             "out_shardings": (sh.cache, sh.pos, sh.logits)}
         jit_kw_dec = {} if sh is None else {
             "out_shardings": (sh.logits, sh.cache)}
-        self._admit_one = jax.jit(serve_admit, **jit_kw_adm)
-        self._decode = jax.jit(_serve_decode(model), **jit_kw_dec)
+        self._admit_one = jax.jit(serve_admit, donate_argnums=(1, 3),
+                                  **jit_kw_adm)
+        self._decode = jax.jit(_serve_decode(model), donate_argnums=(2,),
+                               **jit_kw_dec)
 
     # ------------------------------------------------------------------
     def _admit(self, params) -> int:
@@ -493,12 +511,14 @@ class ContinuousScheduler(_SchedulerBase):
             toks[0, : len(req.prompt)] = req.prompt
             with self.obs.span("sched.prefill", slot=i, rid=req.rid):
                 with self._mesh_ctx():
+                    cache = self._cache
                     self._cache, self._pos, self._last_logits = \
                         self._admit_one(
-                            params, self._cache, self._pos,
+                            params, cache, self._pos,
                             self._last_logits, jnp.asarray(toks),
                             jnp.asarray([len(req.prompt)], jnp.int32),
                             jnp.asarray(i, jnp.int32))
+                    self._count_donated(cache)
             self.stats.prefills += 1
             admitted += 1
         return admitted
@@ -633,8 +653,12 @@ class PagedContinuousScheduler(_SchedulerBase):
                                            dtype=jnp.float32,
                                            use_kernel=use_kernel)
 
-        self._chunk_jit = jax.jit(serve_prefill_chunk, **jit_kw_ch)
-        self._decode_jit = jax.jit(serve_decode, **jit_kw_dec)
+        # the cache (and the chunk's logits) are donated: the layer loop
+        # writes each layer's new rows into them in place
+        self._chunk_jit = jax.jit(serve_prefill_chunk, donate_argnums=(1, 2),
+                                  **jit_kw_ch)
+        self._decode_jit = jax.jit(serve_decode, donate_argnums=(2,),
+                                   **jit_kw_dec)
 
     def _kernel_walk(self, cfg):
         """``(window, block_tokens, num_blocks, banded)`` of the kernel's
@@ -757,13 +781,15 @@ class PagedContinuousScheduler(_SchedulerBase):
                 with self.obs.span("sched.prefill_chunk", slot=slot,
                                    rid=req.rid, start=start, valid=valid):
                     with self._mesh_ctx():
+                        cache = self._cache
                         self._cache, self._last_logits = self._chunk_jit(
-                            params, self._cache, self._last_logits,
+                            params, cache, self._last_logits,
                             jnp.asarray(toks),
                             jnp.asarray(start, jnp.int32),
                             jnp.asarray(valid, jnp.int32),
                             jnp.asarray(row),
                             jnp.asarray(slot, jnp.int32))
+                        self._count_donated(cache)
                 req.prefill_chunks += 1
                 self.prefill_chunks += 1
                 job["start"] = start + valid
@@ -813,7 +839,8 @@ class PagedContinuousScheduler(_SchedulerBase):
                 "kv_blocks_walked": self.kv_blocks_walked,
                 "kv_blocks_full": self.kv_blocks_full,
                 "operand_casts": self.operand_casts,
-                "operand_bytes": self.operand_bytes}
+                "operand_bytes": self.operand_bytes,
+                "cache_donated": self.cache_donated}
 
     def _step(self, params) -> int:
         """Admit + advance chunked prefills, then one decode step for

@@ -100,10 +100,11 @@ def test_run_visits_every_layer_once(name, with_cache):
             lambda l: jnp.zeros((n,) + l.shape), body(None)))
 
     def count(role):
-        i = roles.index(role)
-        if with_cache:
-            return lambda lp, x, c: (x.at[i].add(1), c)
-        return lambda lp, x: (x.at[i].add(1), None)
+        r = roles.index(role)
+        if with_cache:   # each layer adds one to its own slab of the cache
+            return lambda lp, x, c, i: (
+                x.at[r].add(1), layout.write(c, i, layout.read(c, i) + 1))
+        return lambda lp, x: (x.at[r].add(1), None)
 
     x, outs = layout.run(segs, tree, jnp.zeros(len(roles), jnp.int32),
                          {r: count(r) for r in roles},
@@ -111,9 +112,11 @@ def test_run_visits_every_layer_once(name, with_cache):
     seen = dict(zip(roles, x.tolist()))
     assert sum(seen.values()) == cfg.num_layers + (
         cfg.enc_num_layers if layout.encoder_layout(cfg) else 0)
-    if with_cache:   # the new cache comes back in the tree's structure
+    if with_cache:   # the new cache comes back in the tree's structure,
+        # each layer's slab written once, by that layer
         assert (jax.tree_util.tree_structure(outs)
                 == jax.tree_util.tree_structure(tree))
+        assert all(bool((leaf == 1).all()) for leaf in jax.tree.leaves(outs))
     if cfg.kind == "hybrid":
         assert seen["local_attn"] == cfg.num_layers // 3
     if cfg.kind == "mamba_hybrid":

@@ -269,6 +269,44 @@ def test_paged_kernel_matches_jnp_gather(case):
                               np.asarray(every)[live])
 
 
+@pytest.mark.parametrize("dead", [False, True])
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("walk", ["block", "page_grid"])
+def test_paged_kernel_reads_one_layer_of_a_stack(walk, layer, dead):
+    """On stacked pools ``(layers, num_pages, ...)`` with a traced
+    ``layer`` the kernel (interpret mode) returns exactly what it returns
+    on that layer's own pool: the block walk and the page grid (head dim
+    64), with dead lanes beside live ones."""
+    from repro.kernels.paged_attn import _page_grid_decode, paged_decode
+    L, P, ps, K, G = 3, 5, 4, 2, 2
+    hd = 64 if walk == "page_grid" else 8
+    pos = jnp.asarray([13, 19, 7, 4], jnp.int32)
+    B = pos.shape[0]
+    live = np.asarray([True, not dead, True, not dead])
+    rng = np.random.default_rng(4)
+    num_pages = B * P + 1
+    q = jnp.asarray(rng.normal(size=(B, K, G, hd)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(L, num_pages, ps, K, hd)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(L, num_pages, ps, K, hd)), jnp.float32)
+    page_map = rng.permutation(num_pages - 1).reshape(B, P) + 1
+    page_map = jnp.asarray(np.where(live[:, None], page_map, DUMMY_PAGE),
+                           jnp.int32)
+
+    def attend(kp, vp, ly):
+        if walk == "page_grid":
+            return _page_grid_decode(q, kp, vp, page_map, pos, 6,
+                                     interpret=True, layer=ly)
+        return paged_decode(q, kp, vp, page_map, pos, window=6,
+                            live=jnp.asarray(live), layer=ly,
+                            pages_per_block=2, interpret=True)
+
+    stacked = jax.jit(attend)(k, v, jnp.asarray(layer, jnp.int32))
+    own = attend(k[layer], v[layer], None)
+    assert np.array_equal(np.asarray(stacked), np.asarray(own))
+    ref = _gather_attention(q, k[layer], v[layer], page_map, pos, 6)
+    assert float(jnp.abs(stacked - ref)[live].max()) <= 1e-5
+
+
 def test_block_band_bounds_the_walk():
     from repro.kernels.paged_attn import block_band, block_pages
     pos = np.asarray([0, 15, 16, 100, 4095, 4095])
@@ -345,6 +383,75 @@ def test_paged_scheduler_matches_continuous():
     assert len(sched.trie) == 0
     # chunk=8 over up-to-14-token prompts -> some prompts take 2 chunks
     assert any(r.prefill_chunks >= 2 for r in s_paged.records)
+
+
+# every kind the paged scheduler serves, by its layer layout
+SERVED_KINDS = {"dense": "dense", "moe": "moe", "ssm": "ssm",
+                "hybrid": "hybrid_tail", "mamba_hybrid": None}
+FORWARD_TOL = 2e-5
+
+
+def _served_model(kind):
+    if SERVED_KINDS[kind] is None:
+        cfg, params = _granite_tiny()
+        return cfg, build_model(cfg), params
+    arch, _, over = FAMILIES[SERVED_KINDS[kind]]
+    return _tiny(arch, **over)
+
+
+@pytest.mark.parametrize("kind", sorted(SERVED_KINDS))
+def test_paged_scheduler_serves_the_forward_walk(kind):
+    """Each served kind, with requests streaming in chunk by chunk (so
+    lanes sit mid-prefill, not live, while others decode) and the cache
+    donated to every call: each served token and the logits it was
+    sampled from are those of the plain forward walk over the same
+    weights (no cache), and every call consumed the cache it was
+    handed."""
+    cfg, model, params = _served_model(kind)
+    max_total = 24
+    sched = PagedContinuousScheduler(
+        model, slots=3, max_prompt=14, max_total=max_total, page_size=4,
+        prefill_chunk=4, temperature=0.0)
+    served = {}                        # rid -> logits rows sampled from
+    sample = sched._sample
+
+    def recording(logits):
+        rows = np.asarray(logits)[:, 0]
+        for i, r in enumerate(sched.active):
+            if r is not None and sched._slot_ready(i):
+                served.setdefault(r.rid, []).append(rows[i])
+        return sample(logits)
+
+    sched._sample = recording
+    arrivals = _trace(cfg, np.random.default_rng(8), 6)
+    pending, step, both = list(arrivals), 0, 0
+    while pending or sched.outstanding:
+        while pending and pending[0][0] <= step:
+            sched.submit(pending.pop(0)[1])
+        cache = sched._cache
+        before = (sched.stats.decode_steps, sched.prefill_chunks)
+        sched.step(params)
+        if (sched.stats.decode_steps > before[0]
+                and sched.prefill_chunks > before[1]):
+            # a chunk and a decode ran: the cache handed in is consumed
+            assert all(a.is_deleted() for a in jax.tree.leaves(cache))
+            both += 1
+        step += 1
+    assert both and sched.stats.requests_done == 6
+    c = sched.counters()
+    assert c["cache_donated"] == c["decode_steps"] + c["prefill_chunks"]
+
+    fwd = jax.jit(lambda t: model.forward(params, {"tokens": t},
+                                          dtype=jnp.float32, remat=False)[0])
+    for _, r in arrivals:
+        seq = np.zeros((1, max_total), np.int32)
+        full = np.concatenate([r.prompt, r.out_tokens[:-1]])
+        seq[0, :len(full)] = full
+        ref = np.asarray(fwd(jnp.asarray(seq)))[0, len(r.prompt) - 1:
+                                                  len(full)]
+        assert r.out_tokens == ref.argmax(-1).tolist(), f"rid {r.rid}"
+        err = float(np.abs(np.stack(served[r.rid]) - ref).max())
+        assert err <= FORWARD_TOL, f"rid {r.rid}: {err}"
 
 
 @pytest.mark.parametrize("family", ["dense", "sliding"])

@@ -168,8 +168,9 @@ def _cell_model(name):
 
 
 def _serve_program(model, program, slots, max_total, sharding):
-    """(fn, abstract non-param args) of the paged scheduler's jitted
-    ``serve_decode`` or ``serve_prefill_chunk`` at a cell's shapes."""
+    """(jitted fn, abstract non-param args) of the paged scheduler's
+    ``serve_decode`` or ``serve_prefill_chunk`` at a cell's shapes,
+    donating the cache (and the chunk's logits) as the scheduler does."""
     from repro.serving import pages_per_slot
     ps, C = 16, 256
     P = pages_per_slot(max_total, ps)
@@ -182,22 +183,33 @@ def _serve_program(model, program, slots, max_total, sharding):
             return model.decode_step_paged(p, t, c, s, pm, lv,
                                            dtype=jnp.float32,
                                            use_kernel=True)
-        return fn, (arg(jnp.int32, slots, 1), cache, arg(jnp.int32, slots),
-                    arg(jnp.int32, slots, P), arg(bool, slots))
+        return jax.jit(fn, donate_argnums=(2,)), (
+            arg(jnp.int32, slots, 1), cache, arg(jnp.int32, slots),
+            arg(jnp.int32, slots, P), arg(bool, slots))
 
     def fn(p, c, lg, tokens, start, valid, row, slot):
         c1, lg1 = model.prefill_chunk(p, c, tokens, start, valid, row, slot,
                                       dtype=jnp.float32)
         return c1, jax.lax.dynamic_update_slice(lg, lg1, (slot, 0, 0))
-    return fn, (cache, arg(jnp.float32, slots, 1, model.cfg.padded_vocab),
-                arg(jnp.int32, 1, C), arg(jnp.int32), arg(jnp.int32),
-                arg(jnp.int32, P), arg(jnp.int32))
+    return jax.jit(fn, donate_argnums=(1, 2)), (
+        cache, arg(jnp.float32, slots, 1, model.cfg.padded_vocab),
+        arg(jnp.int32, 1, C), arg(jnp.int32), arg(jnp.int32),
+        arg(jnp.int32, P), arg(jnp.int32))
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Lower the serving programs with the Mosaic paged-decode kernel, as
+    on the chip: off-TPU the kernel defaults to interpret mode, whose
+    loop converts the whole f32 pool it reads to bf16."""
+    from repro.kernels import runtime
+    monkeypatch.setattr(runtime, "default_interpret", lambda: False)
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 @pytest.mark.parametrize("cell", sorted(SERVE_CELLS))
-def test_serving_operands_take_the_weight_converts_out(one_chip, cell,
-                                                       program):
+def test_serving_operands_take_the_weight_converts_out(one_chip, mosaic,
+                                                       cell, program):
     """With the stacked matmul weights handed in as bf16 (the serving
     operand tree), no program converts a weight stack on each call, and
     the temporary that held the converted stacks goes."""
@@ -226,7 +238,7 @@ def test_serving_operands_take_the_weight_converts_out(one_chip, cell,
     fn, args = _serve_program(model, program, slots, max_total, one_chip)
     temp = {}
     for side, params in (("f32", f32), ("ops", ops)):
-        exe = jax.jit(fn).lower(params, *args).compile()
+        exe = fn.lower(params, *args).compile()
         temp[side] = exe.memory_analysis().temp_size_in_bytes
         found = converted(exe.as_text())
         assert bool(found) == (side == "f32"), (side, found)
@@ -235,6 +247,80 @@ def test_serving_operands_take_the_weight_converts_out(one_chip, cell,
     # places a few other buffers differently)
     assert temp["f32"] - temp["ops"] >= 0.95 * copy_bytes, \
         (temp, copy_bytes)
+
+
+# temp_size_in_bytes of each program before the cache was carried
+# through the layer loop (undonated), compiled as here: the code decode
+# with the Mosaic kernel
+UNCARRIED_TEMP = {("code", "decode"): 67_544_576,
+                  ("code", "chunk"): 202_553_856,
+                  ("chat", "decode"): 67_528_704,
+                  ("chat", "chunk"): 1_813_577_728}
+_INSTR = re.compile(r"\s+(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                    r"([\w\-]+)\(")
+
+
+def _outside_fusions(text):
+    """``(name, opcode, dtype, dims)`` of each array-valued instruction
+    of an HLO module that is not in a fusion's body."""
+    fused = set(re.findall(r" fusion\(.*calls=%([\w.\-]+)", text))
+    out, comp = [], None
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", ln)
+        if head:
+            comp = head.group(1)
+        elif comp not in fused and (m := _INSTR.match(ln)):
+            name, dt, dims, op = m.groups()
+            dims = tuple(int(d) for d in dims.split(",") if d)
+            out.append((name, op, dt, dims))
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("cell", sorted(SERVE_CELLS))
+def test_serving_cache_stays_in_place(one_chip, mosaic, cell, program):
+    """The scheduler's programs, compiled with the cache donated, update
+    it in place: no instruction outside a fusion's body moves a whole
+    layer's page pool or state, or a whole stack (a copy, slice or
+    convert), the cache's bytes are aliased to the output, and the
+    temporaries are no larger than before the cache was carried. (A
+    layer's slab under 1 MiB, chat's conv contexts at 0.79 MB, may be
+    sliced out whole.)"""
+    from repro.serving import engine
+    name, slots, max_total = SERVE_CELLS[cell]
+    model = _cell_model(name)
+    ops = engine.map_operands(
+        model.cfg, lambda a: a.update(dtype=jnp.bfloat16),
+        jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                    sharding=one_chip),
+                     model.abstract_params()[0]))
+    fn, args = _serve_program(model, program, slots, max_total, one_chip)
+    exe = fn.lower(ops, *args).compile()
+    text = exe.as_text()
+    # the code decode reads the stacked pools through the Mosaic kernel
+    kernel = (cell, program) == ("code", "decode")
+    assert ("tpu_custom_call" in text) == kernel
+    cache = args[1] if program == "decode" else args[0]
+    leaves = jax.tree.leaves(cache)
+    # element counts of a layer's slab and of a stack, in any shape
+    slab = {int(np.prod(a.shape[1:])) for a in leaves
+            if np.prod(a.shape[1:]) * a.dtype.itemsize >= 1 << 20}
+    stack = {int(np.prod(a.shape)) for a in leaves}
+    moved = []
+    for name_, op, dt, dims in _outside_fusions(text):
+        n = int(np.prod(dims))
+        if op in ("parameter", "get-tuple-element", "bitcast"):
+            continue
+        if n in slab or n in stack and (
+                op in ("copy", "copy-done", "dynamic-slice", "convert")
+                or any(w in name_ for w in ("copy", "dynamic-slice",
+                                            "convert"))):
+            moved.append(f"{name_} = {dt}{list(dims)} {op}")
+    assert not moved, moved
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize for a in leaves), mem
+    assert mem.temp_size_in_bytes <= UNCARRIED_TEMP[cell, program], mem
 
 
 def test_doc_chat_operands_are_its_params(monkeypatch):
